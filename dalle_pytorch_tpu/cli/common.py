@@ -1,7 +1,30 @@
 """Shared CLI helpers."""
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Optional
+
+import jax
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+    Called once at the top of every entry point that jits (the CLIs,
+    bench.py, chip_smoke.py's children, tools/), BEFORE the first compile.
+
+    Where `JAX_COMPILATION_CACHE_DIR` is set, jax reads it itself and this
+    sets nothing.  Otherwise the cache lives at ONE fixed path inside the
+    checkout (`<repo>/.jax_cache`, gitignored) — the path is part of the
+    cache key, so a temporary name, a pid or a time would never hit.  Tests
+    keep the cache off (`JAX_ENABLE_COMPILATION_CACHE=false`,
+    tests/conftest.py)."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def load_dalle_bundle(path, allow_legacy_pickle: bool = False,
@@ -11,8 +34,6 @@ def load_dalle_bundle(path, allow_legacy_pickle: bool = False,
     (dalle_cfg, params, vae_cfg, vae_params).  Shared by cli/generate.py and
     cli/serve.py so the batch CLI and the long-lived service consume the
     exact same loading/migration path."""
-    from pathlib import Path
-
     from dalle_pytorch_tpu.models import vae_registry
     from dalle_pytorch_tpu.models.dalle import DALLEConfig
     from dalle_pytorch_tpu.models.torch_port import (
@@ -78,7 +99,11 @@ def load_dalle_bundle(path, allow_legacy_pickle: bool = False,
 
         params = dalle_mod.migrate_param_layout(trees["weights"], dalle_cfg)
         vae_params = trees["vae_weights"]
-    return dalle_cfg, params, vae_cfg, vae_params
+    # checkpoints load as HOST numpy arrays: place the weights on the device
+    # ONCE here, or every jitted call of the sampler / the engine's decode
+    # step uploads them again (2.3 GB per step at dim 2048 — invisible on the
+    # CPU, where host and device memory are the same)
+    return dalle_cfg, jax.device_put(params), vae_cfg, jax.device_put(vae_params)
 
 
 def warn_vocab_mismatch(num_text_tokens: int, tokenizer, is_root: bool = True) -> None:

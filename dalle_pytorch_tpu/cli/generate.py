@@ -84,6 +84,9 @@ def get_tokenizer(args):
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    from dalle_pytorch_tpu.cli.common import enable_compile_cache
+
+    enable_compile_cache()
 
     path = Path(args.dalle_path)
     from dalle_pytorch_tpu.cli.common import load_dalle_bundle

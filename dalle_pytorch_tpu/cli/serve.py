@@ -253,6 +253,9 @@ def _build_model(args):
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    from dalle_pytorch_tpu.cli.common import enable_compile_cache
+
+    enable_compile_cache()
     from dalle_pytorch_tpu.serving.engine import EngineConfig, GenerationEngine
 
     tele = None

@@ -56,6 +56,9 @@ def build_parser():
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    from dalle_pytorch_tpu.cli.common import enable_compile_cache
+
+    enable_compile_cache()
     be = backend_mod.set_backend_from_args(args)
     be.initialize()
     is_root = be.is_root_worker()

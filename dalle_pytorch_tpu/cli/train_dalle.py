@@ -518,6 +518,9 @@ def _apply_dummy_run_defaults(args):
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    from dalle_pytorch_tpu.cli.common import enable_compile_cache
+
+    enable_compile_cache()
     if args.debug_nans:
         jax.config.update("jax_debug_nans", True)
     if args.dummy_run is not None:
@@ -1346,12 +1349,14 @@ def main(argv=None):
                                 print(f"[telemetry] compiled/analytic FLOPs ratio: "
                                       f"{ratio:.3f}")
                             if is_root and ledger_bytes:
+                                roof = ledger["roofline"]  # None on CPU: no peaks
                                 print("[fleet] comms ledger: "
                                       + ", ".join(
                                           f"{r['axis']}={r['bytes_per_step'] / 1e6:.2f}MB"
                                           for r in ledger["per_axis"])
-                                      + f" per step ({ledger['roofline']['bound']}-bound "
-                                        "at peak)")
+                                      + " per step"
+                                      + (f" ({roof['bound']}-bound at peak)"
+                                         if roof else ""))
                             # HBM ledger refreshed from the LIVE trees (the
                             # pre-distribution pricing estimated the
                             # optimizer moments), cross-checked against the

@@ -138,6 +138,9 @@ def save_model(path: str, params, cfg: DiscreteVAEConfig, health_state=None,
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    from dalle_pytorch_tpu.cli.common import enable_compile_cache
+
+    enable_compile_cache()
 
     be = backend_mod.set_backend_from_args(args)
     be.initialize()

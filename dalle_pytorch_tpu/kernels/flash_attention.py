@@ -13,7 +13,8 @@ accumulating over key tiles) and a dk/dv pass (grid over key tiles,
 accumulating over query tiles), both recomputing probabilities from the saved
 logsumexp.
 
-On CPU (tests) kernels run in interpret mode automatically.
+On CPU (tests) kernels run in interpret mode; any platform other than cpu or
+tpu is an error, never an interpreter.
 """
 from __future__ import annotations
 
@@ -36,7 +37,13 @@ _NEG = -1e30
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"flash attention kernels run on tpu (compiled) or cpu "
+            f"(interpret mode, tests); backend {backend!r} is neither"
+        )
+    return backend == "cpu"
 
 
 def resolve_block(n: int, block: int) -> int:
@@ -94,7 +101,7 @@ def _masked_scores(q32, k32, mask_ref, kmask_ref, i, j, *, causal, block_q,
         s = jnp.where(m, s, _NEG)
     if use_kmask:
         # per-batch key-padding row (1, block_k) broadcast over query rows
-        s = jnp.where(kmask_ref[:] > 0, s, _NEG)
+        s = jnp.where(kmask_ref[0] > 0, s, _NEG)
     return s
 
 
@@ -194,19 +201,22 @@ def _dummy_specs_args(use_mask, mask, live, nq, nk, block_q, block_k,
 def _kmask_spec_arg(use_kmask, kmask, h, block_k, kv_grid=False):
     """Per-batch key-padding row: the grid batch index is b*h-flattened, so
     the index map divides by the (static) head count.  kv_grid swaps the
-    (i, j) program-id order for the dk/dv pass."""
+    (i, j) program-id order for the dk/dv pass.  kmask is (b, 1, n): the
+    chip's tiling wants a block's last two dims to be (8, 128)-multiples or
+    the array's own, and a (1, block_k) block of a (b, n) array is neither —
+    the unit middle axis makes it the array's own."""
     if use_kmask:
         if kv_grid:
-            spec = pl.BlockSpec((1, block_k), lambda bh, j, i: (bh // h, j))
+            spec = pl.BlockSpec((1, 1, block_k), lambda bh, j, i: (bh // h, 0, j))
         else:
-            spec = pl.BlockSpec((1, block_k), lambda bh, i, j: (bh // h, j))
+            spec = pl.BlockSpec((1, 1, block_k), lambda bh, i, j: (bh // h, 0, j))
         return [spec], (kmask,)
     return [pl.BlockSpec(memory_space=pltpu.SMEM)], (jnp.zeros((1,), jnp.int32),)
 
 
 @jax.named_scope("flash_attn_fwd")
 def _flash_fwd(q, k, v, mask, live, kmask, h, causal, scale, block_q, block_k):
-    """q, k, v: (bh, n, d); kmask: optional (b, n) int32 key-padding rows.
+    """q, k, v: (bh, n, d); kmask: optional (b, 1, n) int32 key-padding rows.
     Returns (out (bh, n, d), lse (bh, n, LANES)).  The named scope makes the
     kernel a labelled row in xprof traces (telemetry span mirroring)."""
     bh, n, d = q.shape
@@ -258,6 +268,7 @@ def _flash_fwd(q, k, v, mask, live, kmask, h, causal, scale, block_q, block_k):
             flops=int(flops), bytes_accessed=int(3 * bh * n * d * 4),
             transcendentals=int(bh * n * n),
         ),
+        name="flash_fwd",
         interpret=_interpret(),
     )(q, k, v, *margs, *kargs)
     if health_mod.taps_active():
@@ -382,6 +393,7 @@ def _flash_bwd(q, k, v, do, out, lse, mask, live, kmask, h, causal, scale, block
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, n, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_dq",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta, *margs, *kargs)
 
@@ -419,6 +431,7 @@ def _flash_bwd(q, k, v, do, out, lse, mask, live, kmask, h, causal, scale, block
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        name="flash_dkv",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta, *margs, *kargs)
     return dq, dk, dv
@@ -486,7 +499,8 @@ def _compact_in_specs(d, block_q, block_k, h, H, mask, use_kmask):
         mask_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     if use_kmask:
         kmask_spec = pl.BlockSpec(
-            (1, block_k), lambda b, t, qr, kc, fr, la, va: (b // h, kc[hid(b), t]))
+            (1, 1, block_k),
+            lambda b, t, qr, kc, fr, la, va: (b // h, 0, kc[hid(b), t]))
     else:
         kmask_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     return (q_spec, k_spec, v_spec), mask_spec, kmask_spec
@@ -674,6 +688,7 @@ def _flash_fwd_compact(q, k, v, mask, kmask, tabs, h, causal, scale, block_q,
                 scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32)],
             ),
             out_shape=jax.ShapeDtypeStruct((bh, n, _LANES), jnp.float32),
+            name="flash_compact_max",
             interpret=_interpret(),
         )(qr, kc, fr, la, va, q, k, *_mask_args(mask, use_kmask, kmask))
         gargs = (gmax,)
@@ -713,6 +728,7 @@ def _flash_fwd_compact(q, k, v, mask, kmask, tabs, h, causal, scale, block_q,
             jax.ShapeDtypeStruct((bh, n, _LANES), jnp.float32),
         ),
         cost_estimate=cost,
+        name="flash_compact_fwd",
         interpret=_interpret(),
     )(*args, *gargs)
     if health_mod.taps_active():
@@ -834,6 +850,7 @@ def _flash_bwd_compact(q, k, v, do, out, lse, mask, kmask, tabs, h, causal,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((bh, n, d), q.dtype),
+        name="flash_compact_dq",
         interpret=_interpret(),
     )(qr, kc, fr, la, va, q, k, v, do, lse, delta, *margs)
 
@@ -863,6 +880,7 @@ def _flash_bwd_compact(q, k, v, do, out, lse, mask, kmask, tabs, h, causal,
             jax.ShapeDtypeStruct((bh, n, d), k.dtype),
             jax.ShapeDtypeStruct((bh, n, d), v.dtype),
         ),
+        name="flash_compact_dkv",
         interpret=_interpret(),
     )(qrT, kcT, frT, laT, vaT, q, k, v, do, lse, delta, *margs)
     return dq, dk, dv
@@ -893,7 +911,7 @@ def _dense_recompute_grads(q, k, v, mask, kmask, h, causal, scale, lse, do):
         else:
             s = jnp.where(mask[None], s, _NEG)
     if kmask is not None:
-        s = jnp.where(jnp.repeat(kmask > 0, h, axis=0)[:, None, :], s, _NEG)
+        s = jnp.where(jnp.repeat(kmask > 0, h, axis=0), s, _NEG)  # (bh, 1, n)
     p = jnp.exp(s - lse[:, :, :1])
     do32 = do.astype(f32)
     dv = jnp.einsum("bij,bid->bjd", p, do32)
@@ -975,6 +993,7 @@ def flash_attention(
     grid: str = "auto",
     tables=None,
     vfa: bool = False,
+    mesh=None,
 ) -> jnp.ndarray:
     """(b, h, n, d) attention.  `mask`: optional static (n, n) — or
     per-head (h, n, n) — bool pattern (True = may attend), combined with
@@ -985,7 +1004,7 @@ def flash_attention(
     `key_mask`: optional (b, n) per-batch key-padding rows (True/nonzero =
     attend) — traced, applied inside the kernels, so padded text (CLIP
     encoding, masked prefill) keeps the O(n)-memory path instead of falling
-    back to dense XLA attention (VERDICT r4 weak #7).  q is expected UNSCALED
+    back to dense XLA attention.  q is expected UNSCALED
     (scale defaults to d^-1/2), unlike ops.attention.attend.
 
     `grid`: 'dense' schedules the full (bh, nq, nk) tile grid and
@@ -999,7 +1018,9 @@ def flash_attention(
     on the compacted grid, precompute global row maxima in a first max-only
     pass and skip the per-tile accumulator rescale (allclose, not
     bit-identical, to the online-softmax forward); ignored on the dense
-    grid."""
+    grid.  `mesh`: the multi-device mesh the enclosing jit is partitioned
+    over, if any — the kernels then run under `jax.shard_map`, batch split
+    over the data axes and heads over `tp` (see `_shard_over_mesh`)."""
     b, h, n, d = q.shape
     if scale is None:
         scale = d ** -0.5
@@ -1010,8 +1031,8 @@ def flash_attention(
     if live is not None:
         # a caller-supplied liveness table must match the RESOLVED grid, not
         # the requested blocks (silent mismatch = out-of-bounds tile skipping)
-        grid = (n // block_q, n // block_k)
-        want = (mask.shape[0], *grid) if (mask is not None and mask.ndim == 3) else grid
+        tiles = (n // block_q, n // block_k)
+        want = (mask.shape[0], *tiles) if (mask is not None and mask.ndim == 3) else tiles
         assert live.shape == want, (
             f"live table {live.shape} != grid {want}; "
             f"build it at resolve_block() granularity"
@@ -1037,14 +1058,52 @@ def flash_attention(
             live = None  # traced mask without explicit live: no tile skipping
 
     tabs = _resolve_tables(grid, tables, mask, h, n, causal, block_q, block_k)
+    km = None if key_mask is None else key_mask.astype(jnp.int32)[:, None, :]
 
-    qf = q.reshape(b * h, n, d)
-    kf = k.reshape(b * h, n, d)
-    vf = v.reshape(b * h, n, d)
-    km = None if key_mask is None else key_mask.astype(jnp.int32)
-    out = _flash(qf, kf, vf, mask, live, km, tabs, h, causal, scale, block_q,
-                 block_k, bwd_impl, vfa)
-    return out.reshape(b, h, n, d)
+    def run(q, k, v, mask, live, km, tabs):  # local (b, h) under shard_map
+        b, h = q.shape[:2]
+        out = _flash(q.reshape(b * h, n, d), k.reshape(b * h, n, d),
+                     v.reshape(b * h, n, d), mask, live, km, tabs, h, causal,
+                     scale, block_q, block_k, bwd_impl, vfa)
+        return out.reshape(b, h, n, d)
+
+    if mesh is not None and mesh.size > 1:
+        run = _shard_over_mesh(run, mesh, b, h, mask, live, km, tabs)
+    return run(q, k, v, mask, live, km, tabs)
+
+
+def _shard_over_mesh(run, mesh, b, h, mask, live, km, tabs):
+    """`run` under `jax.shard_map` over `mesh`.  The chip's compiler refuses
+    a Mosaic kernel inside a GSPMD-partitioned program ("cannot be
+    automatically partitioned"), so on a multi-device mesh the kernel call
+    is made manual: batch over the data axes, heads over `tp` (attention is
+    independent per batch row and head, so no collective is needed), each
+    left whole where it does not divide.  Per-head masks/tables follow the
+    heads; shared ones are replicated.  The same wrap runs in interpret mode
+    on a CPU mesh, so tests cover the path the chip takes."""
+    from jax.sharding import PartitionSpec as P
+
+    from dalle_pytorch_tpu.parallel.mesh import AXIS_TP, BATCH_AXES
+
+    data = tuple(a for a in BATCH_AXES if mesh.shape.get(a, 1) > 1)
+    if b % int(np.prod([mesh.shape[a] for a in data])):
+        data = ()
+    tp = AXIS_TP if mesh.shape.get(AXIS_TP, 1) > 1 and h % mesh.shape[AXIS_TP] == 0 else None
+
+    def by_head(x):  # (h, ...) per-head operands follow the heads
+        return P(tp) if x.shape[0] == h and x.ndim == 3 else P()
+
+    qkv = P(data or None, tp)
+    specs = (
+        qkv, qkv, qkv,
+        None if mask is None else by_head(mask),
+        None if live is None else by_head(live),
+        None if km is None else P(data or None),
+        None if tabs is None else tuple(
+            P(tp) if t.shape[0] == h and h > 1 else P() for t in tabs),
+    )
+    return jax.shard_map(run, mesh=mesh, in_specs=specs, out_specs=qkv,
+                         check_vma=False)
 
 
 def _resolve_tables(grid, tables, mask, h, n, causal, block_q, block_k):

@@ -40,18 +40,6 @@ from typing import Any, Dict, List, Mapping, Optional, Union
 from dalle_pytorch_tpu.observability import metrics as metrics_mod
 from dalle_pytorch_tpu.observability.xla import FlopsCrosscheck
 
-# approximate aggregate per-chip ICI bandwidth (bytes/s, bidirectional sum
-# over links) — roofline pricing only, not a guarantee
-ICI_BYTES_PER_S = {
-    "v4": 300e9,
-    "v5e": 200e9,
-    "v5litepod": 200e9,
-    "v5p": 600e9,
-    "v6e": 450e9,
-}
-_DEFAULT_ICI = 200e9
-
-
 # ---------------------------------------------------------------------------
 # collective wire-cost primitives (per-chip bytes, ring algorithms)
 # ---------------------------------------------------------------------------
@@ -347,24 +335,29 @@ def publish_gauges(ledger: Mapping[str, Any], registry=None) -> None:
 def comms_roofline(total_bytes: float, step_flops: float,
                    peak_flops: Optional[float] = None,
                    ici_bytes_per_s: Optional[float] = None,
-                   n_chips: int = 1) -> Dict[str, Any]:
+                   n_chips: int = 1) -> Optional[Dict[str, Any]]:
     """Comms-vs-compute roofline for one step: time each side would take at
     its peak, and which one bounds the step.  Overlap is the best case —
-    `bound` says which resource the step CANNOT go faster than.
+    `bound` says which resource the step CANNOT go faster than.  Peaks not
+    passed in come from the chip table (core/chips.py); on CPU there are
+    none and the roofline is None — nothing is priced against a guess.
 
     BOTH sides are per-chip: `total_bytes` is the ledger's per-chip wire
     bytes, so `step_flops` (the analytic WHOLE-step model, all chips) is
     divided by `n_chips` — comparing fleet FLOPs against one chip's traffic
     would bias every verdict toward compute-bound."""
-    if peak_flops is None:
-        from dalle_pytorch_tpu.training.profiling import chip_peak_flops
+    if peak_flops is None or ici_bytes_per_s is None:
+        from dalle_pytorch_tpu.core.chips import chip_spec
 
-        peak_flops = chip_peak_flops()
-    if ici_bytes_per_s is None:
-        ici_bytes_per_s = _chip_ici_bytes_per_s()
+        spec = chip_spec()
+        if spec is None:
+            return None
+        peak_flops = peak_flops if peak_flops is not None else spec.bf16_flops
+        ici_bytes_per_s = (ici_bytes_per_s if ici_bytes_per_s is not None
+                           else spec.ici_bytes_per_s)
     flops_per_chip = step_flops / max(n_chips, 1)
-    compute_s = flops_per_chip / peak_flops if peak_flops else 0.0
-    comms_s = total_bytes / ici_bytes_per_s if ici_bytes_per_s else 0.0
+    compute_s = flops_per_chip / peak_flops
+    comms_s = total_bytes / ici_bytes_per_s
     return {
         "comms_s_at_peak": comms_s + 0.0,
         "compute_s_at_peak": compute_s + 0.0,
@@ -374,19 +367,6 @@ def comms_roofline(total_bytes: float, step_flops: float,
         "ici_bytes_per_s": ici_bytes_per_s + 0.0,
         "peak_flops": peak_flops + 0.0,
     }
-
-
-def _chip_ici_bytes_per_s(default: float = _DEFAULT_ICI) -> float:
-    try:
-        import jax
-
-        kind = jax.devices()[0].device_kind.lower().replace(" ", "")
-    except Exception:
-        return default
-    for key, val in ICI_BYTES_PER_S.items():
-        if key in kind:
-            return val
-    return default
 
 
 class CommsCrosscheck(FlopsCrosscheck):

@@ -51,39 +51,21 @@ from dalle_pytorch_tpu.observability import metrics as metrics_mod
 from dalle_pytorch_tpu.observability.comms import tree_float_bytes
 from dalle_pytorch_tpu.observability.xla import FlopsCrosscheck
 
-# per-chip HBM (bytes) by device generation — the fits/doesn't-fit verdict
-# when the backend exposes no bytes_limit (capacity pricing only)
-HBM_BYTES = {
-    "v4": 32e9,
-    "v5e": 16e9,
-    "v5litepod": 16e9,
-    "v5p": 95e9,
-    "v6e": 32e9,
-}
-_DEFAULT_HBM = 16e9
-
-
-def device_hbm_capacity(device=None, default: Optional[float] = None) -> Optional[float]:
+def device_hbm_capacity(device=None) -> Optional[float]:
     """Per-device HBM capacity in bytes: the allocator's own `bytes_limit`
-    when exposed, else the generation table, else `default` (None on CPU —
-    there is no meaningful capacity to verdict against)."""
-    try:
-        import jax
+    when exposed, else the chip table (core/chips.py).  None on CPU — there
+    is no meaningful capacity to verdict against; an accelerator the table
+    does not know raises."""
+    import jax
 
-        device = device if device is not None else jax.local_devices()[0]
-    except Exception:
-        return default
-    try:
-        stats = device.memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return stats["bytes_limit"] * 1.0
-    except Exception:
-        pass
-    kind = str(getattr(device, "device_kind", "")).lower().replace(" ", "")
-    for key, val in HBM_BYTES.items():
-        if key in kind:
-            return val
-    return default
+    from dalle_pytorch_tpu.core.chips import chip_spec
+
+    device = device if device is not None else jax.local_devices()[0]
+    stats = device.memory_stats()
+    if stats and stats.get("bytes_limit"):
+        return stats["bytes_limit"] * 1.0
+    spec = chip_spec(device)
+    return None if spec is None else spec.hbm_bytes
 
 
 # ---------------------------------------------------------------------------
@@ -922,7 +904,7 @@ def provoke_oom(simulate_reason: str = "injected") -> None:
         try:
             import jax.numpy as jnp
 
-            cap = device_hbm_capacity(default=_DEFAULT_HBM) or _DEFAULT_HBM
+            cap = device_hbm_capacity()
             chunk = int(cap // 8 // 4)  # f32 elements, 1/8th of HBM per grab
             for _ in range(64):
                 hold.append(jax.block_until_ready(  # host-sync-ok: chaos hook
@@ -931,14 +913,7 @@ def provoke_oom(simulate_reason: str = "injected") -> None:
         finally:
             del hold
         # the allocator somehow satisfied 8x HBM — fall through to simulate
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError  # noqa: PLC0415
-
-        raise XlaRuntimeError(
-            f"RESOURCE_EXHAUSTED: [chaos] {simulate_reason} OOM: simulated "
-            "out-of-memory while allocating device buffer"
-        )
-    except ImportError:  # pragma: no cover - ancient jaxlib layout
-        raise RuntimeError(
-            f"RESOURCE_EXHAUSTED: [chaos] {simulate_reason} OOM (simulated)"
-        )
+    raise jax.errors.JaxRuntimeError(
+        f"RESOURCE_EXHAUSTED: [chaos] {simulate_reason} OOM: simulated "
+        "out-of-memory while allocating device buffer"
+    )

@@ -165,7 +165,10 @@ class JaxBackend(DistributedBackend):
                     "(or both omitted for TPU-pod auto-detection)"
                 )
             jax.distributed.initialize(coord, num, pid)
-        elif jax.process_count() == 1 and _tpu_pod_env():
+        elif _tpu_pod_env() and not jax.distributed.is_initialized():
+            # NB: nothing here may touch a device or ask jax.process_count()
+            # first — that initialises the backend, after which
+            # jax.distributed.initialize() refuses to run
             jax.distributed.initialize()
 
     def _get_world_size(self) -> int:

@@ -109,31 +109,15 @@ class ContextMesh(Mesh):
 
 def active_mesh() -> Optional[Mesh]:
     """The innermost entered ContextMesh, or — for users driving jax's own
-    mesh plumbing — the mesh installed via `jax.sharding.set_mesh`, or (a
-    best-effort fallback) a plain `jax.sharding.Mesh` entered via a bare
-    `with mesh:` that didn't go through make_mesh/mesh_context.  The
-    fallback reads jax's deprecated thread-resources re-export; it keeps
-    that pre-existing user idiom working and disappears gracefully when jax
-    removes the re-export."""
+    mesh plumbing — the (abstract) mesh installed via `jax.sharding.set_mesh`;
+    both answer inside a jit trace, where model code asks.  A plain
+    `jax.sharding.Mesh` entered with a bare `with mesh:` publishes itself to
+    neither; enter it through `mesh_context()` instead."""
     mesh = _ACTIVE_MESH.get()
     if mesh is not None:
         return mesh
-    get_mesh = getattr(jax.sharding, "get_mesh", None)
-    if get_mesh is not None:  # jax >= 0.5; older jaxlibs use the fallback below
-        mesh = get_mesh()
-        if not mesh.empty:
-            return mesh
-    try:
-        import warnings
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters import pxla
-
-            mesh = pxla.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        return None
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 @contextlib.contextmanager

@@ -65,7 +65,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
-from dalle_pytorch_tpu.parallel.compat import pcast, shard_map
 from dalle_pytorch_tpu.parallel.mesh import AXIS_PP
 
 P = PartitionSpec
@@ -276,7 +275,7 @@ def pipeline_scan(
                 h = jax.lax.ppermute(h, axis, fwd_perm)
             return (h, outs, saved, ring), None
 
-        var = lambda z: pcast(z, (axis,), to="varying")
+        var = lambda z: jax.lax.pcast(z, (axis,), to="varying")
         h0 = var(jnp.zeros_like(xm_in[0]))
         outs0 = var(jnp.zeros_like(xm_in))
         ring0 = outs0 if v > 1 else h0  # dummy when not interleaved
@@ -295,7 +294,7 @@ def pipeline_scan(
         return out
 
     def fwd_only(fl_, il_, xm_):
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda a, b, c: per_stage_fwd(a, b, c, with_saved=False),
             mesh=mesh,
             in_specs=(specs_like(fl_), specs_like(il_), P()),
@@ -305,7 +304,7 @@ def pipeline_scan(
         return fn(fl_, il_, xm_)
 
     def fwd_saving(fl_, il_, xm_):
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda a, b, c: per_stage_fwd(a, b, c, with_saved=True),
             mesh=mesh,
             in_specs=(specs_like(fl_), specs_like(il_), P()),
@@ -392,7 +391,7 @@ def pipeline_scan(
                 dh = jax.lax.ppermute(dh, axis, bwd_perm)
             return (dh, dfl, dx, dring), None
 
-        var = lambda z: pcast(z, (axis,), to="varying")
+        var = lambda z: jax.lax.pcast(z, (axis,), to="varying")
         dh0 = var(jnp.zeros_like(g[0]))
         # fl_local arrives P(axis)-sharded, i.e. already pp-varying — its
         # zeros need no pcast (g is replicated, so its derivatives do)
@@ -417,7 +416,7 @@ def pipeline_scan(
 
     def run_bwd(res, g):
         fl_, il_, saved = res
-        fn = shard_map(
+        fn = jax.shard_map(
             per_stage_bwd,
             mesh=mesh,
             in_specs=(specs_like(fl_), specs_like(il_), P(axis), P()),

@@ -18,9 +18,8 @@ fit (`ReshardPreflightError`) — a dp8 → dp2 shrink of a model that only
 fit because it was 8-way sharded must fail with a ledger, not with a
 RESOURCE_EXHAUSTED after minutes of compilation.
 
-Works on both sides of the jax 0.4.37 / >=0.5 `parallel/compat.py` seam:
-everything here is `device_put` + the registry's host-side rule table — no
-shard_map, no version-gated API.
+Everything here is `device_put` + the registry's host-side rule table — no
+shard_map.
 
 Host-side by design (this module runs BETWEEN steps, never inside a jit
 trace); covered by tools/lint_host_sync.py with the deliberate host work

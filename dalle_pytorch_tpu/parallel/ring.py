@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
-from dalle_pytorch_tpu.parallel.compat import shard_map
 from dalle_pytorch_tpu.parallel.mesh import AXIS_SP
 
 P = PartitionSpec
@@ -215,7 +214,7 @@ def ring_attention(
         scale = q.shape[-1] ** -0.5
     spec = P(None, None, axis_name, None)
     if mask is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(_ring_attention_local, mask_rows=None, mask_cols=None,
                     axis_name=axis_name, causal=causal, scale=scale),
             mesh=mesh,
@@ -224,7 +223,7 @@ def ring_attention(
         )
         return fn(q, k, v)
     mask = jnp.asarray(mask, bool)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_attention_local, axis_name=axis_name, causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec, P(axis_name, None), P(None, axis_name)),

@@ -278,8 +278,7 @@ class GenerationEngine:
                     degraded_filter_thres=engine_cfg.degraded_filter_thres,
                 ))
 
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        self._decode_fn = jax.jit(self._decode_step_impl, donate_argnums=donate)
+        self._decode_fn = jax.jit(self._decode_step_impl, donate_argnums=(1,))
         self._admit_fns: Dict[Any, Any] = {}
         self._vae_decode = None
         if vae_params is not None:
@@ -402,8 +401,7 @@ class GenerationEngine:
             return self._ingest_impl(
                 state, cache_layers, code, bt_rows, lane_idx, lanes)
 
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        fn = jax.jit(admit, donate_argnums=donate)
+        fn = jax.jit(admit, donate_argnums=(1,))
         self._admit_fns[key] = fn
         return fn
 
@@ -419,8 +417,7 @@ class GenerationEngine:
             return self._ingest_impl(
                 state, cache_layers, code, bt_rows, lane_idx, lanes)
 
-        donate = (0,) if jax.default_backend() != "cpu" else ()
-        fn = jax.jit(ingest, donate_argnums=donate)
+        fn = jax.jit(ingest, donate_argnums=(0,))
         self._admit_fns[key] = fn
         return fn
 

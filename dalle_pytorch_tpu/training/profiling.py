@@ -18,25 +18,14 @@ from typing import Any, Dict, Iterator, Optional
 
 import jax
 
-PEAK_BF16_FLOPS = {
-    "v5e": 197e12,
-    "v5litepod": 197e12,
-    "v5 lite": 197e12,
-    "v5p": 459e12,
-    "v4": 275e12,
-    "v6e": 918e12,
-}
 
+def chip_peak_flops() -> Optional[float]:
+    """Dense bf16 peak FLOP/s of the local chip (core/chips.py); None on CPU
+    — there is no MFU to report there — and an error for an unknown TPU."""
+    from dalle_pytorch_tpu.core.chips import chip_spec
 
-def chip_peak_flops(default: float = 197e12) -> float:
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return default
-    for key, val in PEAK_BF16_FLOPS.items():
-        if key.replace(" ", "") in kind.replace(" ", ""):
-            return val
-    return default
+    spec = chip_spec()
+    return None if spec is None else spec.bf16_flops
 
 
 _LOOKUP_TABLES = ("text_emb", "image_emb", "text_pos", "image_pos", "codebook", "visual_pos")
@@ -151,8 +140,9 @@ def dalle_step_flops(cfg, batch: int, n_matmul_params: int, with_backward: bool 
     return (3.0 if with_backward else 1.0) * fwd
 
 
-def mfu(step_flops: float, step_time_s: float, n_chips: int = 1) -> float:
-    return step_flops / step_time_s / (chip_peak_flops() * n_chips)
+def mfu(step_flops: float, step_time_s: float, n_chips: int = 1) -> Optional[float]:
+    peak = chip_peak_flops()
+    return None if peak is None else step_flops / step_time_s / (peak * n_chips)
 
 
 @contextlib.contextmanager

@@ -1,0 +1,134 @@
+"""The kernels of the main path, compiled for the chip without the chip.
+
+The TPU compiler is installed here and compiles for a DESCRIBED `v5e:2x2`
+topology (on-chip-measurement guide §2, rehearsal 3): interpret mode cannot
+see what it refuses — a block not aligned to the tiling, a kernel over its
+VMEM budget.  Shapes are chip_smoke.py's real ones (b4, h16, n1280, d128,
+bf16, 256-tiles).  Nothing runs, so nothing here is a result or a time; a
+compile that passes is not a chip run.  The whole file skips where the
+topology cannot be described.  Plus: the hardware table finds the kind the
+v5e reports and refuses a kind it does not know."""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dalle_pytorch_tpu.core import chips
+from dalle_pytorch_tpu.kernels import flash_attention as fa
+from dalle_pytorch_tpu.models.transformer import TransformerConfig, _pattern_for
+
+B, H, N, D = 4, 16, 1280, 128  # the smoke's attention shape (fmap 32, text 256)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """SingleDeviceSharding on a described v5e chip; kernels forced off
+    interpret mode for the module (steering `_interpret` belongs in the test,
+    not in an option of the program)."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / no compiler for a described chip here
+        pytest.skip(f"cannot describe a v5e topology: {e!r}")
+    mp = pytest.MonkeyPatch()
+    mp.setattr(fa, "_interpret", lambda: False)
+    yield SingleDeviceSharding(topo.devices[0])
+    mp.undo()
+
+
+def _pattern(kind, heads=H, per_head=False):
+    cfg = TransformerConfig(dim=heads * D, depth=1, seq_len=N, heads=heads,
+                            dim_head=D, image_fmap_size=32,
+                            sparse_per_head=per_head)
+    return np.asarray(_pattern_for(cfg, kind), bool)
+
+
+def _compile(fn, sharding, *shapes_dtypes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes_dtypes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _grad_of(**kw):
+    """fwd + dq + dk/dv in one program: the gradient of a scalar of the output."""
+    def loss(q, k, v, key_mask=None):
+        out = fa.flash_attention(q, k, v, key_mask=key_mask, **kw)
+        return out.astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+QKV = [((B, H, N, D), jnp.bfloat16)] * 3
+
+CASES = {
+    # dense grid, fwd + dq + dk/dv: the `full` layers and one axial pattern
+    "dense_full": (lambda: _grad_of(grid="dense"), QKV, 3),
+    "dense_axial_row": (
+        lambda: _grad_of(mask=_pattern("axial_row"), grid="dense"), QKV, 3),
+    # compacted (scalar-prefetch) grid, fwd + bwd
+    "compact_conv_like": (
+        lambda: _grad_of(mask=_pattern("conv_like"), grid="compact"), QKV, 3),
+    "compact_conv_like_vfa_fwd": (
+        lambda: lambda q, k, v: fa.flash_attention(
+            q, k, v, mask=_pattern("conv_like"), grid="compact", vfa=True),
+        QKV, 2),
+    # the CLIP path: per-batch key-padding rows, not causal, f32, dim_head 64
+    "key_mask_f32_d64": (
+        lambda: _grad_of(causal=False),
+        [((B, 8, 256, 64), jnp.float32)] * 3 + [((B, 256), jnp.bool_)], 3),
+    # per-head block-sparse layouts need per-head tables
+    "compact_per_head_sparse": (
+        lambda: _grad_of(mask=_pattern("sparse", heads=4, per_head=True),
+                         grid="compact"),
+        [((2, 4, N, D), jnp.bfloat16)] * 3, 3),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    build, shapes, n_kernels = CASES[name]
+    text = _compile(build(), one_chip, *shapes)
+    assert text.count("tpu_custom_call") >= n_kernels, (
+        f"{name}: expected >= {n_kernels} Pallas custom calls in the compiled program")
+
+
+@pytest.mark.parametrize("lookup", ["flops", "hbm", "ici"])
+def test_chip_table_knows_v5e_and_refuses_unknown(lookup):
+    """Every consumer of the one hardware table (MFU peak, HBM capacity, ICI
+    roofline) finds the kind a v5e reports and raises on a kind it does not
+    know; on the CPU it gets None — never a default."""
+    from dalle_pytorch_tpu.observability import comms, memory
+    from dalle_pytorch_tpu.training import profiling
+
+    class Dev:
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+
+        def memory_stats(self):
+            return None
+
+    v5e = chips.chip_spec(Dev("TPU v5 lite"))
+    assert (v5e.bf16_flops, v5e.hbm_bytes, v5e.ici_bytes_per_s) == (197e12, 16e9, 200e9)
+    with pytest.raises(ValueError, match="TPU v99"):
+        chips.chip_spec(Dev("TPU v99"))
+    assert chips.chip_spec() is None  # tests run on the CPU
+    if lookup == "flops":
+        assert profiling.chip_peak_flops() is None
+        assert profiling.mfu(1e12, 1.0) is None
+    elif lookup == "hbm":
+        assert memory.device_hbm_capacity(Dev("TPU v5 lite")) == 16e9
+        with pytest.raises(ValueError):
+            memory.device_hbm_capacity(Dev("TPU v99"))
+        assert memory.device_hbm_capacity() is None
+    else:
+        assert comms.comms_roofline(1e9, 1e12) is None
+        roof = comms.comms_roofline(1e10, 1e12, peak_flops=v5e.bf16_flops,
+                                    ici_bytes_per_s=v5e.ici_bytes_per_s)
+        assert roof["bound"] == "comms"

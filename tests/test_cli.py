@@ -236,6 +236,21 @@ def test_keep_n_checkpoints_rotates(workspace, trained_vae):
     assert left == ["dalle_rot_step2.npz"]
 
 
+def test_loaded_inference_weights_are_on_the_device(trained_dalle):
+    """generate.py and the serve CLI load weights through load_dalle_bundle;
+    they must come back as device arrays, placed once.  Host numpy weights
+    are re-uploaded by EVERY jitted call — the engine's decode step moved
+    2.3 GB per token on the chip at dim 2048 (PR 21) and the CPU cannot see
+    it."""
+    import jax
+
+    from dalle_pytorch_tpu.cli.common import load_dalle_bundle
+
+    _, params, _, vae_params = load_dalle_bundle(trained_dalle)
+    leaves = jax.tree_util.tree_leaves((params, vae_params))
+    assert leaves and all(isinstance(x, jax.Array) for x in leaves)
+
+
 def test_generate_cli(workspace, trained_dalle):
     paths = generate_cli.main([
         "--dalle_path", str(trained_dalle),

@@ -305,11 +305,9 @@ def test_telemetry_crosscheck_memory_events_and_donation_alarm(tmp_path):
     ("fsdp_z3", MeshConfig(dp=1, fsdp=8), dict(dim=128),
      StepSettings(zero_stage=3)),
     ("tp", MeshConfig(dp=4, tp=2), {}, StepSettings()),
-    # pure pp (2 devices): the composed dp x fsdp x pp mesh needs jax >= 0.5
-    # partial-manual shard_map (parallel/compat.py) — same constraint as
-    # test_parallel's slow-marked composed-pipeline coverage.  tier-1
-    # budget: slow-marked — the ledger-vs-XLA agreement stays fast via the
-    # dp / fsdp_z3 / tp params; this leg only adds the pipeline layout
+    # pure pp (2 devices).  tier-1 budget: slow-marked — the ledger-vs-XLA
+    # agreement stays fast via the dp / fsdp_z3 / tp params; this leg only
+    # adds the pipeline layout
     pytest.param("pp", MeshConfig(dp=1, pp=2),
                  dict(dim=128, depth=4, execution="remat", scan_layers=True,
                       pipeline_axis="pp"),

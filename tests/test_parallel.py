@@ -816,10 +816,13 @@ def test_loss_scale_with_grad_accum_and_bf16_storage():
     assert np.isfinite(float(m["loss"])) and int(m["skipped"]) == 0
 
 
-def test_bare_with_mesh_plain_mesh_still_discovered():
-    """A plain jax.sharding.Mesh entered with a bare `with mesh:` (no
-    make_mesh / mesh_context) must still be visible to active_mesh() — the
-    pre-round-5 user idiom for engaging the pipeline / ring attention."""
+def test_jax_set_mesh_discovered_inside_jit():
+    """A plain jax.sharding.Mesh installed through jax's own plumbing
+    (`jax.sharding.set_mesh`) is visible to active_mesh() — outside a trace
+    and INSIDE one, where model code (ring attention, the flash shard_map
+    wrap, pipeline engagement) asks.  A bare `with mesh:` on a plain Mesh is
+    no longer discovered: that read jax's deprecated thread-resources state
+    (removed in PR 21) — enter such a mesh through mesh_context()."""
     import numpy as _np
     from jax.sharding import Mesh as PlainMesh
 
@@ -828,9 +831,14 @@ def test_bare_with_mesh_plain_mesh_still_discovered():
     devs = _np.asarray(jax.devices()).reshape(2, 2, 1, 1, 2)
     plain = PlainMesh(devs, MESH_AXES)
     assert active_mesh() is None
-    with plain:
+    with jax.sharding.set_mesh(plain):
         got = active_mesh()
         assert got is not None and dict(got.shape) == dict(plain.shape)
+        seen = []
+        jax.jit(lambda x: seen.append(active_mesh()) or x)(1.0)
+        assert dict(seen[0].shape) == dict(plain.shape)
+    with plain:
+        assert active_mesh() is None
     assert active_mesh() is None
 
 
@@ -944,3 +952,44 @@ def test_loss_scale_on_sharded_mesh():
     state, m_m = step_m(state, batch, jax.random.PRNGKey(0))
     np.testing.assert_allclose(float(m_s["loss"]), float(m_m["loss"]), rtol=2e-4)
     assert float(m_m["loss_scale"]) == 2.0 ** 15 and int(m_m["skipped"]) == 0
+
+
+@pytest.mark.multichip
+def test_flash_kernels_shard_mapped_on_mesh(monkeypatch):
+    """On a multi-device mesh the flash kernels run under shard_map (batch
+    over dp/fsdp, heads over tp): the chip's compiler refuses a Mosaic
+    kernel inside a GSPMD-partitioned program, and the CPU mesh takes the
+    same wrap in interpret mode.  One train step on fsdp2 x tp2 (ZeRO-3) —
+    a shared pattern on the dense grid and a per-head pattern with per-head
+    compacted tables — must match the single-device step."""
+    from dalle_pytorch_tpu.kernels import flash_attention as fa
+
+    wraps = []
+    wrap = fa._shard_over_mesh
+    monkeypatch.setattr(fa, "_shard_over_mesh",
+                        lambda *a: wraps.append(a[1]) or wrap(*a))
+    cfg = tiny_cfg(
+        text_seq_len=64, image_fmap_size=8, attn_kernel="flash",
+        attn_types=("axial_row", "sparse"), sparse_per_head=True,
+        sparse_block_size=16, rotary_emb=True,
+    )
+    assert cfg.total_seq_len == 128
+    params = jax.tree_util.tree_map(
+        np.asarray, dalle_mod.init_dalle(jax.random.PRNGKey(0), cfg))
+    batch = batch_for(cfg, b=4)
+
+    init_1, step_1 = make_train_step(dalle_loss(cfg), optax.sgd(1e-2))
+    s1, m1 = step_1(init_1(params), batch, jax.random.PRNGKey(1))
+    assert not wraps  # no mesh, no wrap
+
+    mesh = make_mesh(MeshConfig(dp=1, fsdp=2, tp=2), devices=jax.devices()[:4])
+    init_m, step_m = make_train_step(
+        dalle_loss(cfg), optax.sgd(1e-2), mesh=mesh,
+        settings=StepSettings(zero_stage=3))
+    sm, mm = step_m(init_m(params), batch, jax.random.PRNGKey(1))
+    assert wraps and all(m is mesh for m in wraps)
+
+    np.testing.assert_allclose(float(mm["loss"]), float(m1["loss"]), rtol=1e-5)
+    for a, b_ in zip(jax.tree_util.tree_leaves(s1.params),
+                     jax.tree_util.tree_leaves(sm.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-5)

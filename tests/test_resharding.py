@@ -12,7 +12,7 @@ Four pillars:
 * **Reshard parity** — a live TrainState moved dp8 → tp4×dp2 → dp8 comes
   back bit-identical, and the memory preflight refuses targets that cannot
   fit BEFORE touching devices.
-* **Topology taxonomy** — checkpoints stamp their topology; validation
+* **Topology error family** — checkpoints stamp their topology; validation
   under a different live topology raises ReshardRequired (distinct from
   the Truncated/Meta/MissingLeaves/FutureFormat family — `--resume auto`
   must NOT fall back past a perfectly good checkpoint that merely needs a
@@ -382,7 +382,7 @@ def test_ledgers_repriced_from_registry_agree_with_scalar_model():
 
 
 # ---------------------------------------------------------------------------
-# topology taxonomy: ReshardRequired beside the invalid-checkpoint family
+# topology error family: ReshardRequired beside the invalid-checkpoint family
 # ---------------------------------------------------------------------------
 
 def _save_with_topology(path, axes, global_step=7):
@@ -432,7 +432,7 @@ def test_auto_resume_does_not_skip_reshardable_checkpoints(tmp_path):
 
 def test_validate_orbax_directory_shapes(tmp_path):
     """Directory checkpoints validate structurally: a real-looking orbax
-    layout passes, a torn one raises the distinct taxonomy errors."""
+    layout passes, a torn one raises the distinct error classes."""
     d = tmp_path / "run_step4.npz"  # the CLI's sharded paths keep .npz names
     (d / "state").mkdir(parents=True)
     with pytest.raises(resilience.CheckpointMetaError, match="meta.json"):

@@ -29,9 +29,11 @@ def main():
     ap.add_argument("--steps", type=int, default=8)
     args = ap.parse_args()
 
+    from dalle_pytorch_tpu.cli.common import enable_compile_cache
     from dalle_pytorch_tpu.kernels.flash_attention import flash_attention
     from dalle_pytorch_tpu.ops.masks import _pattern_mask_np
 
+    enable_compile_cache()
     b, h, n, d = args.batch, args.heads, args.seq, args.dim_head
     bh = b * h
     q, k, v = (

@@ -37,6 +37,7 @@ def main():
     ap.add_argument("--warmup", type=int, default=2)
     args = ap.parse_args()
 
+    from dalle_pytorch_tpu.cli.common import enable_compile_cache
     from dalle_pytorch_tpu.models import dalle as dalle_mod
     from dalle_pytorch_tpu.models.dalle import DALLEConfig
     from dalle_pytorch_tpu.parallel.train_step import StepSettings, make_train_step
@@ -44,6 +45,7 @@ def main():
         chip_peak_flops, dalle_step_flops, matmul_param_count,
     )
 
+    enable_compile_cache()
     try:  # init OOMs for billion-param configs must yield a JSON row too
         cfg = DALLEConfig(
             dim=args.dim, depth=args.depth, heads=args.heads, dim_head=args.dim_head,
@@ -90,13 +92,14 @@ def main():
         return
 
     flops = dalle_step_flops(cfg, batch, n_matmul, granularity="tile")
+    peak = chip_peak_flops()  # None on CPU: a CPU time is not an MFU
     stats = jax.local_devices()[0].memory_stats() or {}
     print(json.dumps({
         "config": vars(args),
         "params_million": round(sum(x.size for x in jax.tree_util.tree_leaves(state.params)) / 1e6, 1),
         "step_time_s": round(dt, 4),
         "img_tok_per_sec": round(batch * cfg.image_seq_len / dt, 1),
-        "mfu": round(flops / dt / chip_peak_flops(), 4),
+        "mfu": round(flops / dt / peak, 4) if peak else None,
         "peak_hbm_gb": round(stats.get("peak_bytes_in_use", 0) / 2**30, 2),
         "loss": round(loss, 4),
     }))

@@ -258,10 +258,12 @@ def main(argv=None) -> int:
 
     import jax
 
+    from dalle_pytorch_tpu.cli.common import enable_compile_cache
     from dalle_pytorch_tpu.models import dalle as dalle_mod
     from dalle_pytorch_tpu.models.dalle import DALLEConfig
     from dalle_pytorch_tpu.serving.engine import EngineConfig, GenerationEngine
 
+    enable_compile_cache()
     cfg = DALLEConfig(
         dim=args.dim, depth=args.depth, num_text_tokens=256, text_seq_len=16,
         heads=4, dim_head=args.dim // 4, num_image_tokens=256,

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""At-scale numerics smoke (VERDICT r4 weak #8 / next-step #10).
+"""At-scale numerics smoke.
 
 Runs N real optimization steps at dim>=1024 with the full low-memory recipe —
 bf16 compute, bf16 grads, PURE-bf16 param storage with stochastic rounding,
@@ -8,8 +8,7 @@ DECREASES.  This is where subtle numerics first bite (sub-ulp updates,
 factored second moments, rounding bias); throughput rows time 4 steps on
 random weights and cannot see any of it.
 
-Prints one JSON line with the loss curve (first/last and a decimated trace)
-so the driver can archive it in sweep_results.jsonl / BENCH artifacts.
+Prints one JSON line with the loss curve (first/last and a decimated trace).
 
     python tools/numerics_smoke.py                  # flagship-width, TPU
     python tools/numerics_smoke.py --dim 128 --depth 2 --steps 40   # CPU check
@@ -41,10 +40,12 @@ def main():
     ap.add_argument("--text_tokens", type=int, default=10000)
     args = ap.parse_args()
 
+    from dalle_pytorch_tpu.cli.common import enable_compile_cache
     from dalle_pytorch_tpu.models import dalle as dalle_mod
     from dalle_pytorch_tpu.models.dalle import DALLEConfig
     from dalle_pytorch_tpu.parallel.train_step import StepSettings, make_train_step
 
+    enable_compile_cache()
     small = args.dim < 512  # CPU harness check
     try:
         cfg = DALLEConfig(
