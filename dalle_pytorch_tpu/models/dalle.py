@@ -234,6 +234,7 @@ def remap_and_bos(cfg: DALLEConfig, text: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([jnp.zeros((b, 1), text.dtype), text], axis=1)
 
 
+@jax.named_scope("embed")
 def embed_text_ids(params: dict, cfg: DALLEConfig, text_ids: jnp.ndarray) -> jnp.ndarray:
     """text_ids: (b, n) post-remap ids incl. bos, positions 0..n-1."""
     emb = jnp.take(_text_table(params, cfg), text_ids, axis=0)
@@ -253,6 +254,7 @@ def image_pos_table(params: dict, cfg: DALLEConfig) -> Optional[jnp.ndarray]:
     return h + w
 
 
+@jax.named_scope("embed")
 def embed_image_codes(params: dict, cfg: DALLEConfig, codes: jnp.ndarray, start: int = 0) -> jnp.ndarray:
     """codes: (b, m) image code ids occupying raster positions start..start+m-1."""
     emb = jnp.take(_image_table(params, cfg), codes, axis=0, mode="clip")
@@ -329,10 +331,11 @@ def forward(
     if cfg.stable:
         out = divide_max(out)
 
-    logits = to_logits(params, cfg, out)
-    logits = jnp.where(
-        logits_mask_slice(cfg, n)[None], jnp.finfo(logits.dtype).min, logits
-    )
+    with jax.named_scope("logits_loss"):
+        logits = to_logits(params, cfg, out)
+        logits = jnp.where(
+            logits_mask_slice(cfg, n)[None], jnp.finfo(logits.dtype).min, logits
+        )
 
     if health_mod.taps_active():
         # output-head numerics for the diagnostic probe: vocab-logit max and
@@ -351,18 +354,19 @@ def forward(
         return logits
 
     assert image_codes is not None, "when training, image codes must be supplied"
-    labels = jnp.concatenate(
-        [text_ids[:, 1:], image_codes + cfg.num_text_tokens_padded], axis=1
-    )
-    assert labels.shape[1] == cfg.total_seq_len
+    with jax.named_scope("logits_loss"):
+        labels = jnp.concatenate(
+            [text_ids[:, 1:], image_codes + cfg.num_text_tokens_padded], axis=1
+        )
+        assert labels.shape[1] == cfg.total_seq_len
 
-    # CE as gather - logsumexp: same math as log_softmax+gather but never
-    # materializes a second (b, n, vocab) f32 tensor (XLA streams the
-    # reduction over the bf16 logits)
-    logits32 = logits.astype(jnp.float32)
-    lse = jax.scipy.special.logsumexp(logits32, axis=-1)
-    label_logit = jnp.take_along_axis(logits32, labels[..., None], axis=-1)[..., 0]
-    token_ll = label_logit - lse
-    loss_text = -jnp.mean(token_ll[:, : cfg.text_seq_len])
-    loss_img = -jnp.mean(token_ll[:, cfg.text_seq_len :])
-    return (loss_text + cfg.loss_img_weight * loss_img) / (cfg.loss_img_weight + 1)
+        # CE as gather - logsumexp: same math as log_softmax+gather but never
+        # materializes a second (b, n, vocab) f32 tensor (XLA streams the
+        # reduction over the bf16 logits)
+        logits32 = logits.astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits32, axis=-1)
+        label_logit = jnp.take_along_axis(logits32, labels[..., None], axis=-1)[..., 0]
+        token_ll = label_logit - lse
+        loss_text = -jnp.mean(token_ll[:, : cfg.text_seq_len])
+        loss_img = -jnp.mean(token_ll[:, cfg.text_seq_len :])
+        return (loss_text + cfg.loss_img_weight * loss_img) / (cfg.loss_img_weight + 1)

@@ -42,6 +42,7 @@ from dalle_pytorch_tpu.ops.stable import divide_max
 DEFAULT_PRIME_FRACTION = 0.4375  # OpenAI used 14 * 32 initial tokens to prime
 
 
+@jax.named_scope("sample")
 def _logits_at(params, cfg: DALLEConfig, out_last: jnp.ndarray, position) -> jnp.ndarray:
     """Masked vocab logits from the transformer output at `position` (the row
     index selects the logits-mask slice, matching dalle_pytorch.py:646-652)."""

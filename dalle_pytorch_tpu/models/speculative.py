@@ -174,6 +174,7 @@ def rollback_slot_rings(new_rings, old_rings, slots, a, tcfg):
 # the engine's per-position emit pipeline (single source of truth)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("sample")
 def lane_sample_pipeline(params, cfg, out, offsets, key_index, state,
                          filter_thres: float, degraded_filter_thres: float):
     """Transformer output -> per-lane sampled code, exactly the serving
@@ -236,6 +237,7 @@ def lane_sample_pipeline(params, cfg, out, offsets, key_index, state,
     return code, bad
 
 
+@jax.named_scope("embed")
 def _embed_prev(params, cfg, prev, img_idx):
     """The engine's decode-step embedding of a previous code at per-lane
     image positions (mode="clip" keeps clamped overflow positions legal)."""

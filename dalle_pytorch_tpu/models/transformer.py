@@ -428,6 +428,7 @@ def _use_ring(cfg, pattern, key_mask) -> bool:
     )
 
 
+@jax.named_scope("attn")
 def _attention_full(shared, cfg, x, pattern, rotary, key_mask, dkey, live=None,
                     tables=None):
     b, n, _ = x.shape
@@ -493,6 +494,7 @@ def _attention_full(shared, cfg, x, pattern, rotary, key_mask, dkey, live=None,
     return apply_dropout(dkey, out, cfg.attn_dropout)
 
 
+@jax.named_scope("ff")
 def _feed_forward(shared, cfg, x, dkey):
     # GEGLU via two column-parallel projections (see init_transformer) —
     # both carry the 'ff_pre' checkpoint name so the flash_qkv_ff remat
@@ -504,18 +506,20 @@ def _feed_forward(shared, cfg, x, dkey):
     return linear(shared["w2"], h)
 
 
+@jax.named_scope("attn")
 def _attention_prefill(shared, cfg, layer_cache, x, pattern, rotary, key_mask,
                        live=None, tables=None):
     """Length-n prefix attention that also fills the KV cache from offset 0.
     Mutates layer_cache['k'/'v'] (caller passes a fresh dict copy)."""
     b, n, _ = x.shape
     q, k, v = _qkv_heads(shared, cfg, x, None if rotary is None else rotary[:n])
-    layer_cache["k"] = jax.lax.dynamic_update_slice(
-        layer_cache["k"], k.astype(layer_cache["k"].dtype), (0, 0, 0, 0)
-    )
-    layer_cache["v"] = jax.lax.dynamic_update_slice(
-        layer_cache["v"], v.astype(layer_cache["v"].dtype), (0, 0, 0, 0)
-    )
+    with jax.named_scope("kv_write"):
+        layer_cache["k"] = jax.lax.dynamic_update_slice(
+            layer_cache["k"], k.astype(layer_cache["k"].dtype), (0, 0, 0, 0)
+        )
+        layer_cache["v"] = jax.lax.dynamic_update_slice(
+            layer_cache["v"], v.astype(layer_cache["v"].dtype), (0, 0, 0, 0)
+        )
     if _use_flash(cfg, n, key_mask):
         # generation prefill on the kernel path: the dense fallback below
         # materializes a (b, h, n, n) mask — O(n^2) HBM per prefill at
@@ -568,7 +572,8 @@ def _residual_branch(
     single-token cached decode (the reference re-implements this composition
     per wrapper; here every mode runs the one definition).  Returns
     (branch output, updated layer cache or None)."""
-    h = layer_norm(wrap[f"{kind}_norm"], x)
+    with jax.named_scope("norm"):
+        h = layer_norm(wrap[f"{kind}_norm"], x)
     if cfg.shift_tokens:
         if mode == "decode":
             if text_mode:
@@ -586,7 +591,8 @@ def _residual_branch(
                 # raw (normed, pre-shift) values feed the ring buffer
                 layer_cache = dict(layer_cache)
                 layer_cache[f"shift_{kind}"] = _fill_ring(cfg, layer_cache[f"shift_{kind}"], h)
-            h = token_shift(h, cfg.seq_len, cfg.image_fmap_size)
+            with jax.named_scope("token_shift"):
+                h = token_shift(h, cfg.seq_len, cfg.image_fmap_size)
     if kind == "attn":
         if mode == "full":
             h = _attention_full(
@@ -607,9 +613,10 @@ def _residual_branch(
             )
     else:
         h = _feed_forward(ff_params, cfg, h, dkey)
-    if cfg.sandwich_norm:
-        h = layer_norm(wrap[f"{kind}_norm_out"], h)
-    return h * wrap[f"{kind}_scale"].astype(h.dtype), layer_cache
+    with jax.named_scope("norm"):
+        if cfg.sandwich_norm:
+            h = layer_norm(wrap[f"{kind}_norm_out"], h)
+        return h * wrap[f"{kind}_scale"].astype(h.dtype), layer_cache
 
 
 def _branch(params, cfg, spec, x, kind, rotary, pattern, key_mask, dkey):
@@ -745,7 +752,8 @@ def _stacked_bundles(params, specs):
         }
         for s in specs
     ]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *bundles)
+    with jax.named_scope("stack_layers"):
+        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *bundles)
 
 
 def _stacked_masks(cfg, specs, n: int):
@@ -985,6 +993,7 @@ def init_cache(cfg: TransformerConfig, batch: int, dtype=jnp.float32) -> dict:
     return {"offset": jnp.zeros((), jnp.int32), "layers": layers}
 
 
+@jax.named_scope("token_shift")
 def _shift_cached_step(cfg, rb, x, offset):
     """Single-token cached token shift — the fixed-shape replacement for the
     reference's deque (transformer.py:138-153).  x: (b, 1, dim);
@@ -1008,6 +1017,7 @@ def _shift_cached_step(cfg, rb, x, offset):
     return shifted, rb
 
 
+@jax.named_scope("attn")
 def _attention_cached(shared, cfg, layer_cache, x, pattern, rotary, offset,
                       decode_tab=None):
     """Single-token cached attention.  x: (b, 1, dim).  Returns (out, (k, v)).
@@ -1322,6 +1332,7 @@ def prefill(
     return out, {"offset": jnp.asarray(n, jnp.int32), "layers": new_layers}
 
 
+@jax.named_scope("token_shift")
 def _fill_ring(cfg: TransformerConfig, rb: jnp.ndarray, pre_shift: jnp.ndarray) -> jnp.ndarray:
     """Populate the shift ring buffer from a length-n prefix ending at n-1.
 
@@ -1433,6 +1444,7 @@ def init_slot_rings(
     return {"layers": layers}
 
 
+@jax.named_scope("kv_write")
 def write_prefill_to_pool(
     cfg: TransformerConfig,
     pool: dict,
@@ -1521,16 +1533,17 @@ def _paged_attention_step(shared, cfg, layer_pool, block_tables, offsets, x,
     quantized = "k_scale" in layer_pool
 
     def one(x_s, bt_s, off_s):
-        k = jnp.take(layer_pool["k"], bt_s, axis=0)  # (B, h, bs, dh)
-        v = jnp.take(layer_pool["v"], bt_s, axis=0)
-        k = k.transpose(1, 0, 2, 3).reshape(cfg.heads, -1, cfg.dim_head)[None, :, :seq]
-        v = v.transpose(1, 0, 2, 3).reshape(cfg.heads, -1, cfg.dim_head)[None, :, :seq]
-        cache = {"k": k, "v": v}
-        if quantized:
-            ks = jnp.take(layer_pool["k_scale"], bt_s, axis=0)  # (B, h, bs)
-            vs = jnp.take(layer_pool["v_scale"], bt_s, axis=0)
-            cache["k_scale"] = ks.transpose(1, 0, 2).reshape(cfg.heads, -1)[None, :, :seq]
-            cache["v_scale"] = vs.transpose(1, 0, 2).reshape(cfg.heads, -1)[None, :, :seq]
+        with jax.named_scope("kv_gather"):
+            k = jnp.take(layer_pool["k"], bt_s, axis=0)  # (B, h, bs, dh)
+            v = jnp.take(layer_pool["v"], bt_s, axis=0)
+            k = k.transpose(1, 0, 2, 3).reshape(cfg.heads, -1, cfg.dim_head)[None, :, :seq]
+            v = v.transpose(1, 0, 2, 3).reshape(cfg.heads, -1, cfg.dim_head)[None, :, :seq]
+            cache = {"k": k, "v": v}
+            if quantized:
+                ks = jnp.take(layer_pool["k_scale"], bt_s, axis=0)  # (B, h, bs)
+                vs = jnp.take(layer_pool["v_scale"], bt_s, axis=0)
+                cache["k_scale"] = ks.transpose(1, 0, 2).reshape(cfg.heads, -1)[None, :, :seq]
+                cache["v_scale"] = vs.transpose(1, 0, 2).reshape(cfg.heads, -1)[None, :, :seq]
         out, new_cache = _attention_cached(
             shared, cfg, cache, x_s[None], pattern, rotary, off_s,
             decode_tab=decode_tab,
@@ -1550,6 +1563,7 @@ def _paged_attention_step(shared, cfg, layer_pool, block_tables, offsets, x,
     return res[0], tuple(res[1:])
 
 
+@jax.named_scope("kv_write")
 def _paged_scatter_cols(layer_pool, block_tables, offsets, cols, block_size: int):
     """Write each slot's new KV column into its pool block.  Inactive slots
     share the trash block (their tables are all-zero), so their duplicate
@@ -1572,6 +1586,7 @@ def _paged_scatter_cols(layer_pool, block_tables, offsets, cols, block_size: int
     return new
 
 
+@jax.named_scope("token_shift")
 def _paged_shift_step(cfg, ring, x, offsets):
     """Per-slot cached token shift: vmap of `_shift_cached_step` with a
     per-slot offset.  ring: (S, fmap, 2, q); x: (S, 1, dim)."""
@@ -1589,7 +1604,8 @@ def _paged_branch(cfg, wrap, attn_params, ff_params, x, kind, layer_pool,
     """Decode-mode residual branch over paged per-slot state — the same
     composition as `_residual_branch(mode='decode')` with vectors where that
     path has scalars.  Returns (branch out, new ring, new KV cols or None)."""
-    h = layer_norm(wrap[f"{kind}_norm"], x)
+    with jax.named_scope("norm"):
+        h = layer_norm(wrap[f"{kind}_norm"], x)
     new_ring = ring
     if cfg.shift_tokens:
         h, new_ring = _paged_shift_step(cfg, ring, h, offsets)
@@ -1601,9 +1617,10 @@ def _paged_branch(cfg, wrap, attn_params, ff_params, x, kind, layer_pool,
         )
     else:
         h = _feed_forward(ff_params, cfg, h, None)
-    if cfg.sandwich_norm:
-        h = layer_norm(wrap[f"{kind}_norm_out"], h)
-    return h * wrap[f"{kind}_scale"].astype(h.dtype), new_ring, cols
+    with jax.named_scope("norm"):
+        if cfg.sandwich_norm:
+            h = layer_norm(wrap[f"{kind}_norm_out"], h)
+        return h * wrap[f"{kind}_scale"].astype(h.dtype), new_ring, cols
 
 
 def paged_decode_step(

@@ -6,9 +6,21 @@ Instrumented code imports the cheap module-level helpers:
 
     from dalle_pytorch_tpu.observability import span, counter, gauge, histogram
 
-which are no-ops / registry updates until a CLI calls
-`telemetry.configure(dir=...)`.  See tools/telemetry_report.py for turning a
-run's spans JSONL into a per-step time-attribution table."""
+which are registry updates and inert profiler annotations until something
+switches them on.
+
+The switch: `span(name, **attrs)` is always a `jax.profiler.TraceAnnotation`
+of that name, which costs a fraction of a microsecond and records nothing
+unless a `jax.profiler` session runs; while one does (a `--profile_steps`
+capture, the on-alarm `TraceTrigger`, the benchmark's `--trace 1`) every span
+is an event on the profiler's own host plane, on the same clock as the
+device's operations, with its attributes as stats.  No flag, no environment
+variable, no second recorder.  `telemetry.configure(dir=...)` adds the JSONL:
+the same call then also writes a `kind:"span"` record (and mirrors into the
+profiler as before).  `timed_span` is `span` that hands back its duration, so
+a phase's accounting and its trace event are one reading.  See
+tools/telemetry_report.py for turning a run's spans JSONL into a per-step
+time-attribution table."""
 from dalle_pytorch_tpu.observability.capture import TraceTrigger, parse_profile_steps
 from dalle_pytorch_tpu.observability.comms import (
     CommsCrosscheck,
@@ -59,6 +71,7 @@ from dalle_pytorch_tpu.observability.telemetry import (
     active,
     configure,
     span,
+    timed_span,
 )
 from dalle_pytorch_tpu.observability.xla import (
     CompileWatcher,
@@ -115,5 +128,6 @@ __all__ = [
     "tap_attention",
     "taps_active",
     "thread_stacks",
+    "timed_span",
     "tree_health",
 ]

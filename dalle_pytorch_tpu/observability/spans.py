@@ -2,9 +2,9 @@
 
 Zero-dependency nested span tracer for the training loop: `span("data_wait")`
 / `span("dispatch")` record per-step wall-clock intervals to a JSONL file
-and, when `jax.profiler` is importable, mirror into
-`jax.profiler.TraceAnnotation` so the same names appear as rows in
-TensorBoard/xprof traces captured around the run.
+and mirror into `jax.profiler.TraceAnnotation`, attributes included, so the
+same names appear on the host plane of a profiler trace captured around the
+run (inert while no profiler session runs).
 
 Two recording modes per span:
 
@@ -27,10 +27,7 @@ import time
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
-try:  # mirror spans into xprof traces when jax is present
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover - jax is a hard dep of this repo
-    _TraceAnnotation = None
+from jax.profiler import TraceAnnotation
 
 SCHEMA_VERSION = 1
 
@@ -50,8 +47,8 @@ class _SpanCtx:
         stack = self._rec._stack()
         stack.append(self.name)
         self._path = "/".join(stack)
-        if self._rec.mirror_profiler and _TraceAnnotation is not None:
-            self._ta = _TraceAnnotation(self.name)
+        if self._rec.mirror_profiler:
+            self._ta = TraceAnnotation(self.name, **self.attrs)
             self._ta.__enter__()
         else:
             self._ta = None
@@ -172,6 +169,17 @@ class SpanRecorder:
                 self._file.flush()
         return summary
 
+    def flush(self):
+        """Write the buffered spans without closing a step: for loops that
+        have no step boundary (the serving engine flushes once a telemetry
+        window, so its spans never fill the per-step buffer)."""
+        with self._lock:
+            buffer, self._buffer = self._buffer, []
+            for rec in buffer:
+                self._write(rec)
+            if self._file is not None:
+                self._file.flush()
+
     def abort_step(self):
         """Drop the current step's buffered spans without writing (e.g. the
         epoch-end data_wait that only discovered the iterator was empty)."""
@@ -213,13 +221,10 @@ class SpanRecorder:
             self._file.write(json.dumps(rec) + "\n")
 
     def close(self):
+        # spans completed after the last end_step (e.g. the final checkpoint
+        # save) must not be dropped
+        self.flush()
         with self._lock:
-            # flush spans completed after the last end_step (e.g. the final
-            # checkpoint save) — closing must not drop them
-            buffer, self._buffer = self._buffer, []
-            for rec in buffer:
-                self._write(rec)
             if self._file is not None:
-                self._file.flush()
                 self._file.close()
                 self._file = None

@@ -15,16 +15,19 @@ Lifecycle (what the CLIs do):
     tele.close()
 
 Everything degrades gracefully: with no directory the spans stay in memory
-(bench mode), with no active Telemetry the module-level `span()` is a
-reusable nullcontext, and instrumented library code (data loader, prefetch)
-only ever touches `span()` + the metrics registry — it keeps working
-unconfigured."""
+(bench mode), with no active Telemetry the module-level `span()` is a bare
+`jax.profiler.TraceAnnotation` (inert unless a profiler session runs, and
+then an event on the profiler's own host plane, on the device trace's
+clock), and instrumented library code (data loader, prefetch) only ever
+touches `span()` + the metrics registry — it keeps working unconfigured."""
 from __future__ import annotations
 
 import contextlib
 import time
 from pathlib import Path
 from typing import Any, Dict, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from dalle_pytorch_tpu.observability import metrics as metrics_mod
 from dalle_pytorch_tpu.observability.heartbeat import Heartbeat
@@ -369,9 +372,37 @@ def active() -> Optional[Telemetry]:
 
 
 def span(name: str, aggregate: bool = False, **attrs):
-    """Span on the active Telemetry; a reusable no-op when none is
-    configured — library code can instrument unconditionally."""
+    """Span on the active Telemetry (a JSONL record, mirrored into the
+    profiler).  With none configured it is the profiler annotation alone:
+    a running `jax.profiler` session is the one switch that makes it an
+    event, so library code can instrument unconditionally.  `aggregate`
+    spans (per-sample loader work) stay no-ops there."""
     tele = _ACTIVE
     if tele is None:
-        return _NULL
+        return _NULL if aggregate else TraceAnnotation(name, **attrs)
     return tele.spans.span(name, aggregate=aggregate, **attrs)
+
+
+class _TimedSpan:
+    """`span()` that also hands back its own duration: `with timed_span(..)
+    as t: ...` then `t.s`.  The one reading the engine's phase accounting,
+    its histograms and the profiler event of the same name all come from."""
+
+    __slots__ = ("_cm", "_t0", "s")
+
+    def __init__(self, cm):
+        self._cm = cm
+        self.s = 0.0
+
+    def __enter__(self):
+        self._cm.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.s = time.perf_counter() - self._t0
+        return self._cm.__exit__(*exc)
+
+
+def timed_span(name: str, **attrs) -> _TimedSpan:
+    return _TimedSpan(span(name, **attrs))

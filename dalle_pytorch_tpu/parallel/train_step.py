@@ -373,9 +373,19 @@ def make_train_step(
             )
         return new_state, metrics
 
+    batch_sh = None if mesh is None else NamedSharding(mesh, P(BATCH_AXES))
+
+    # the function's name is the program's in a trace: `jit_train_step`
+    def train_step(state, batch, key, with_health: bool = False):
+        if batch_sh is not None:
+            batch = jax.tree_util.tree_map(
+                lambda x: jax.lax.with_sharding_constraint(x, batch_sh), batch
+            )
+        return step_fn_inner(state, batch, key, with_health=with_health)
+
     if mesh is None:
         jitted_single = jax.jit(
-            step_fn_inner, donate_argnums=0, static_argnames=("with_health",)
+            train_step, donate_argnums=0, static_argnames=("with_health",)
         )
         # donation introspection: the memory observability stack
         # (observability/memory.audit_donation) verifies that argument 0 —
@@ -385,15 +395,7 @@ def make_train_step(
         jitted_single.registry = reg
         return init_fn, jitted_single
 
-    batch_sh = NamedSharding(mesh, P(BATCH_AXES))
-
-    def step_fn(state, batch, key, with_health: bool = False):
-        batch = jax.tree_util.tree_map(
-            lambda x: jax.lax.with_sharding_constraint(x, batch_sh), batch
-        )
-        return step_fn_inner(state, batch, key, with_health=with_health)
-
-    jitted = jax.jit(step_fn, donate_argnums=0, static_argnames=("with_health",))
+    jitted = jax.jit(train_step, donate_argnums=0, static_argnames=("with_health",))
 
     def with_mesh_ctx(state, batch, key, with_health: bool = False):
         # mesh in context during trace + dispatch so models can use raw

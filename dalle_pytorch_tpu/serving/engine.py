@@ -25,14 +25,41 @@ the steady-state decode loop dispatches asynchronously.
 
 Observability: every request leaves exactly one `kind:"request"` JSONL
 record — outcome completed/shed/deferred plus per-phase wall-seconds
-(queue_wait, admission, prefill, decode, evict, vae_decode) that sum to its
-latency — and each poll() iteration accumulates admit/dispatch/evict phase
-windows published as `serving/phase_*` gauges (the serving mirror of the
-train loop's data_wait/dispatch/block split) together with a goodput gauge
-(lane-tokens actually decoded vs the ideal slots × steps).  All of it is
-`time.monotonic()` bookkeeping on values the engine already holds on the
-host: telemetry-off poll() performs ZERO additional device syncs
-(tools/lint_host_sync.py keeps that mechanical).
+(queue_wait, admission, prefill, decode, evict_sync, codes_pull, vae_decode,
+evict) that sum to its latency.  Every phase of the loop is a span through
+`observability.telemetry.span`: an event on the profiler's host plane while a
+`jax.profiler` session runs (so the engine's phases and the device's
+operations share one clock), a JSONL record as well while a `Telemetry` is
+configured, and otherwise inert.  Nested by time on the polling thread, each
+carrying `iter=` and, where it belongs to one request, `req=<Request.id>`:
+
+    serve/submit
+    serve/poll
+      serve/admit                 one per admitted request; lanes=
+        serve/admit.alloc         tables, RNG split        -> phases["admission"]
+        serve/admit.dispatch      the admit / ingest jit call
+        serve/admit.lane_meta     the eager .at[].set scatters
+        serve/admit.ttft_sync     block_until_ready; with dispatch and
+                                  lane_meta                -> phases["prefill"]
+      serve/decode.dispatch       around it serve/spec.draft and
+                                  serve/spec.verify on the speculative path
+      serve/evict                 only in a poll that has finished requests
+        serve/evict.flag_sync     the poisoned-flag pull: the drain of the
+                                  queued steps             -> phases["evict_sync"]
+        serve/evict.codes_pull    per request              -> phases["codes_pull"]
+        serve/evict.lane_reset    the eager scatters that free the lanes
+        serve/evict.vae_decode    per request, dispatch + block
+                                                           -> phases["vae_decode"]
+        serve/evict.pixels_pull   per request
+
+The same readings feed the admit/dispatch/block/evict split of the
+`serving_window` event and a goodput figure (lane-tokens actually decoded vs
+the ideal slots x steps).  Every span closes where the code already returns
+or already blocks: telemetry-off poll() performs ZERO additional device syncs
+(tools/lint_host_sync.py keeps that mechanical).  The jitted programs carry
+stable names (`serve_decode_step`, `serve_admit`, `serve_ingest`,
+`serve_vae_decode`, `serve_spec_draft`, `serve_spec_verify`), which is how a
+trace's `XLA Modules` line is read.
 """
 from __future__ import annotations
 
@@ -49,7 +76,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dalle_pytorch_tpu.models import dalle as dalle_mod
 from dalle_pytorch_tpu.models import sampling as sampling_mod
 from dalle_pytorch_tpu.models import speculative as spec_mod
 from dalle_pytorch_tpu.models.transformer import (
@@ -262,31 +288,41 @@ class GenerationEngine:
             self._spec = spec_mod.validate_spec(
                 self.tcfg, engine_cfg.spec_k, engine_cfg.spec_draft_layers)
             k, d = self._spec
-            self._spec_draft_fn = jax.jit(
-                lambda params, state: spec_mod.engine_spec_draft(
+
+            def serve_spec_draft(params, state):
+                return spec_mod.engine_spec_draft(
                     params, self.cfg, self.tcfg, state, spec_k=k,
                     draft_layers=d, block_size=engine_cfg.block_size,
                     filter_thres=engine_cfg.filter_thres,
                     degraded_filter_thres=engine_cfg.degraded_filter_thres,
-                ))
-            self._spec_verify_fn = jax.jit(
-                lambda params, state, draft: spec_mod.engine_spec_verify(
+                )
+
+            def serve_spec_verify(params, state, draft):
+                return spec_mod.engine_spec_verify(
                     params, self.cfg, self.tcfg, state, draft, spec_k=k,
                     draft_layers=d, block_size=engine_cfg.block_size,
                     n_gen=self.n_gen,
                     filter_thres=engine_cfg.filter_thres,
                     degraded_filter_thres=engine_cfg.degraded_filter_thres,
-                ))
+                )
 
-        self._decode_fn = jax.jit(self._decode_step_impl, donate_argnums=(1,))
+            self._spec_draft_fn = jax.jit(serve_spec_draft)
+            self._spec_verify_fn = jax.jit(serve_spec_verify)
+
+        # the function's name is the program's in a trace (`jit_<name>`)
+        def serve_decode_step(params, state):
+            return self._decode_step_impl(params, state)
+
+        self._decode_fn = jax.jit(serve_decode_step, donate_argnums=(1,))
         self._admit_fns: Dict[Any, Any] = {}
         self._vae_decode = None
         if vae_params is not None:
             from dalle_pytorch_tpu.models import vae_registry
 
-            self._vae_decode = jax.jit(
-                lambda codes: vae_registry.decode_indices(vae_params, vae_cfg, codes)
-            )
+            def serve_vae_decode(codes):
+                return vae_registry.decode_indices(vae_params, vae_cfg, codes)
+
+            self._vae_decode = jax.jit(serve_vae_decode)
 
     # ------------------------------------------------------------------ jits
     def _decode_step_impl(self, params, state):
@@ -299,11 +335,7 @@ class GenerationEngine:
         cfg, tcfg = self.cfg, self.tcfg
         prev = state["prev_code"]
 
-        emb = jnp.take(dalle_mod._image_table(params, cfg), prev[:, None],
-                       axis=0, mode="clip")
-        pos = dalle_mod.image_pos_table(params, cfg)
-        if pos is not None:
-            emb = emb + jnp.take(pos, state["img_prev"], axis=0, mode="clip")[:, None]
+        emb = spec_mod._embed_prev(params, cfg, prev, state["img_prev"])
 
         out, pool, rings = paged_decode_step(
             params["transformer"], tcfg, emb, state["pool"],
@@ -321,22 +353,23 @@ class GenerationEngine:
 
         act = state["active"]
         S = self.ecfg.num_slots
-        img_new = jnp.where(act, state["img_prev"] + 1, state["img_prev"])
-        widx = jnp.clip(img_new, 0, self.n_gen - 1)
-        existing = jnp.take_along_axis(state["codes"], widx[:, None], axis=1)[:, 0]
-        codes_buf = state["codes"].at[jnp.arange(S), widx].set(
-            jnp.where(act, code, existing)
-        )
-        return dict(
-            state,
-            pool=pool,
-            rings=rings,
-            offsets=jnp.where(act, state["offsets"] + 1, state["offsets"]),
-            prev_code=jnp.where(act, code, state["prev_code"]),
-            img_prev=img_new,
-            codes=codes_buf,
-            poisoned=poisoned,
-        )
+        with jax.named_scope("codes_write"):
+            img_new = jnp.where(act, state["img_prev"] + 1, state["img_prev"])
+            widx = jnp.clip(img_new, 0, self.n_gen - 1)
+            existing = jnp.take_along_axis(state["codes"], widx[:, None], axis=1)[:, 0]
+            codes_buf = state["codes"].at[jnp.arange(S), widx].set(
+                jnp.where(act, code, existing)
+            )
+            return dict(
+                state,
+                pool=pool,
+                rings=rings,
+                offsets=jnp.where(act, state["offsets"] + 1, state["offsets"]),
+                prev_code=jnp.where(act, code, state["prev_code"]),
+                img_prev=img_new,
+                codes=codes_buf,
+                poisoned=poisoned,
+            )
 
     def _prefill_sample_impl(self, params, text, k0, temperature,
                              cond_scale: float):
@@ -357,37 +390,39 @@ class GenerationEngine:
         )
         rings = state["rings"]
         if rings is not None:
-            if tcfg.scan_layers:
-                rl, cl = rings["layers"], cache_layers
-                rings = {"layers": dict(
-                    rl,
-                    shift_attn=rl["shift_attn"].at[:, lane_idx].set(
-                        cl["shift_attn"].astype(rl["shift_attn"].dtype)),
-                    shift_ff=rl["shift_ff"].at[:, lane_idx].set(
-                        cl["shift_ff"].astype(rl["shift_ff"].dtype)),
-                )}
-            else:
-                new_layers = []
-                for rl, cl in zip(rings["layers"], cache_layers):
-                    new_layers.append({
-                        "shift_attn": rl["shift_attn"].at[lane_idx].set(
+            with jax.named_scope("token_shift"):
+                if tcfg.scan_layers:
+                    rl, cl = rings["layers"], cache_layers
+                    rings = {"layers": dict(
+                        rl,
+                        shift_attn=rl["shift_attn"].at[:, lane_idx].set(
                             cl["shift_attn"].astype(rl["shift_attn"].dtype)),
-                        "shift_ff": rl["shift_ff"].at[lane_idx].set(
+                        shift_ff=rl["shift_ff"].at[:, lane_idx].set(
                             cl["shift_ff"].astype(rl["shift_ff"].dtype)),
-                    })
-                rings = {"layers": new_layers}
+                    )}
+                else:
+                    new_layers = []
+                    for rl, cl in zip(rings["layers"], cache_layers):
+                        new_layers.append({
+                            "shift_attn": rl["shift_attn"].at[lane_idx].set(
+                                cl["shift_attn"].astype(rl["shift_attn"].dtype)),
+                            "shift_ff": rl["shift_ff"].at[lane_idx].set(
+                                cl["shift_ff"].astype(rl["shift_ff"].dtype)),
+                        })
+                    rings = {"layers": new_layers}
 
-        codeb = jnp.broadcast_to(code, (lanes,))
-        return dict(
-            state,
-            pool=pool,
-            rings=rings,
-            block_tables=state["block_tables"].at[lane_idx].set(bt_rows),
-            codes=state["codes"].at[lane_idx, 0].set(codeb),
-            prev_code=state["prev_code"].at[lane_idx].set(codeb),
-            offsets=state["offsets"].at[lane_idx].set(self.n_pre),
-            img_prev=state["img_prev"].at[lane_idx].set(0),
-        )
+        with jax.named_scope("codes_write"):
+            codeb = jnp.broadcast_to(code, (lanes,))
+            return dict(
+                state,
+                pool=pool,
+                rings=rings,
+                block_tables=state["block_tables"].at[lane_idx].set(bt_rows),
+                codes=state["codes"].at[lane_idx, 0].set(codeb),
+                prev_code=state["prev_code"].at[lane_idx].set(codeb),
+                offsets=state["offsets"].at[lane_idx].set(self.n_pre),
+                img_prev=state["img_prev"].at[lane_idx].set(0),
+            )
 
     def _admit_fn_for(self, cond_scale: float, lanes: int):
         key = (float(cond_scale), lanes)  # host-sync-ok: python jit-cache key
@@ -395,13 +430,13 @@ class GenerationEngine:
         if fn is not None:
             return fn
 
-        def admit(params, state, text, k0, temperature, bt_rows, lane_idx):
+        def serve_admit(params, state, text, k0, temperature, bt_rows, lane_idx):
             cache_layers, code = self._prefill_sample_impl(
                 params, text, k0, temperature, cond_scale)
             return self._ingest_impl(
                 state, cache_layers, code, bt_rows, lane_idx, lanes)
 
-        fn = jax.jit(admit, donate_argnums=(1,))
+        fn = jax.jit(serve_admit, donate_argnums=(1,))
         self._admit_fns[key] = fn
         return fn
 
@@ -413,11 +448,11 @@ class GenerationEngine:
         if fn is not None:
             return fn
 
-        def ingest(state, cache_layers, code, bt_rows, lane_idx):
+        def serve_ingest(state, cache_layers, code, bt_rows, lane_idx):
             return self._ingest_impl(
                 state, cache_layers, code, bt_rows, lane_idx, lanes)
 
-        fn = jax.jit(ingest, donate_argnums=(0,))
+        fn = jax.jit(serve_ingest, donate_argnums=(0,))
         self._admit_fns[key] = fn
         return fn
 
@@ -462,20 +497,21 @@ class GenerationEngine:
         req = self._make_request(text, key, temperature, cond_scale,
                                  synthetic, deadline_s, retries_left,
                                  replayed)
-        try:
-            if self.degrade is not None:
-                self.degrade.shape_request(req)
-            self.admission.screen_submit(req)
-            self.queue.push(req)
-        except AdmissionRefused as e:
-            obs_metrics.counter("serving/refused").inc()
-            self.admission.note_refusal(e.reason, kind=e.kind)
-            req.phases["queue_wait"] = time.monotonic() - req.arrival_t
-            self._finish_record(req, "shed", reason=e.reason)
-            raise
-        obs_metrics.counter("serving/submitted").inc()
-        if self.journal is not None:
-            self.journal.accepted(req)
+        with telemetry.span("serve/submit", iter=self._iter, req=req.id):
+            try:
+                if self.degrade is not None:
+                    self.degrade.shape_request(req)
+                self.admission.screen_submit(req)
+                self.queue.push(req)
+            except AdmissionRefused as e:
+                obs_metrics.counter("serving/refused").inc()
+                self.admission.note_refusal(e.reason, kind=e.kind)
+                req.phases["queue_wait"] = time.monotonic() - req.arrival_t
+                self._finish_record(req, "shed", reason=e.reason)
+                raise
+            obs_metrics.counter("serving/submitted").inc()
+            if self.journal is not None:
+                self.journal.accepted(req)
         return req
 
     def submit_when_able(self, text, key=None, temperature: float = 1.0,
@@ -484,10 +520,10 @@ class GenerationEngine:
                          replayed: bool = False) -> Request:
         """Blocking submit for batch callers (generate.py --engine, the
         prompt-mode serve CLI) and router requeues: a full queue BLOCKS —
-        the engine polls until a slot frees — instead of refusing.  Counted
-        as ONE `serving/submit_waits`, not a refusal per retry (those
-        counters measure shed load, which a waiting batch caller is not).  A
-        request that can NEVER fit the pool still refuses outright."""
+        the engine polls until a slot frees — instead of refusing, and the
+        wait counts as no refusal (those counters measure shed load, which a
+        waiting batch caller is not).  A request that can NEVER fit the pool
+        still refuses outright."""
         req = self._make_request(text, key, temperature, cond_scale,
                                  synthetic, deadline_s, retries_left,
                                  replayed)
@@ -500,16 +536,13 @@ class GenerationEngine:
             req.phases["queue_wait"] = time.monotonic() - req.arrival_t
             self._finish_record(req, "shed", reason=e.reason)
             raise
-        waited = False
         while len(self.queue) >= self.queue.max_depth:
-            if not waited:
-                obs_metrics.counter("serving/submit_waits").inc()
-                waited = True
             self.poll()  # a full queue implies busy, so this makes progress
-        self.queue.push(req)
-        obs_metrics.counter("serving/submitted").inc()
-        if self.journal is not None:
-            self.journal.accepted(req)
+        with telemetry.span("serve/submit", iter=self._iter, req=req.id):
+            self.queue.push(req)
+            obs_metrics.counter("serving/submitted").inc()
+            if self.journal is not None:
+                self.journal.accepted(req)
         return req
 
     @property
@@ -522,7 +555,6 @@ class GenerationEngine:
         process stays alive and the engine keeps its queue/in-flight state,
         but its iteration counter and heartbeat stop advancing."""
         self._stall_until = time.monotonic() + float(seconds)  # host-sync-ok: CLI/host scalar
-        obs_metrics.counter("serving/wedged").inc()
 
     @property
     def stalled(self) -> bool:
@@ -636,12 +668,12 @@ class GenerationEngine:
         decode step, evictions.  Returns the requests completed this
         iteration (codes — and images when a VAE is attached — populated).
 
-        Phase attribution: wall time is split into admit (admission checks
-        + prefill, which contains the deliberate TTFT sync), dispatch (the
-        async fused decode step), and evict/block (finished-slot handling;
-        the device pull is counted under "block", mirroring the train
-        loop's data_wait/dispatch/block) — accumulated per telemetry
-        window, all via time.monotonic, no device syncs added."""
+        Phase attribution: every phase is a `serve/...` span (the tree is in
+        the module docstring), and the telemetry window's split is fed from
+        the same readings: admit (the `serve/admit` spans, which contain the
+        deliberate TTFT sync), dispatch (`serve/decode.dispatch`), block (the
+        eviction's device waits: flag sync, codes pulls, VAE decodes) and
+        evict (the rest of `serve/evict`).  No device syncs added."""
         if self._stall_until:
             if time.monotonic() < self._stall_until:
                 # wedged (stall-replica fault): alive but making no progress
@@ -670,26 +702,19 @@ class GenerationEngine:
             victim.poison_victim = True
             print(f"[chaos] poison-request: request {victim.id} poisoned — "
                   "NaN decode logits until its retry budget burns", flush=True)
-            obs_metrics.counter("serving/poison_injected").inc()
-        self._phase = "admit"
-        t0 = time.monotonic()
-        self._admit_ready()
-        t1 = time.monotonic()
-        self._phase_acc["admit"] += t1 - t0
-        self._track_poison_lane()
-        if self._inflight:
-            self._phase = "dispatch"
-            self._decode_once()
-            self._phase_acc["dispatch"] += time.monotonic() - t1
-        self._phase = "evict"
-        t2 = time.monotonic()
-        blk0 = self._phase_acc["block"]
-        done = self._evict_finished()
-        # evict window = host bookkeeping only; the device pull/VAE wait
-        # inside _evict_finished went to the "block" accumulator
-        self._phase_acc["evict"] += (time.monotonic() - t2) - (
-            self._phase_acc["block"] - blk0)
-        self._phase = "idle"
+        with telemetry.span("serve/poll", iter=self._iter):
+            self._phase = "admit"
+            self._admit_ready()
+            self._track_poison_lane()
+            if self._inflight:
+                self._phase = "dispatch"
+                with telemetry.timed_span("serve/decode.dispatch",
+                                          iter=self._iter) as t:
+                    self._decode_once()
+                self._phase_acc["dispatch"] += t.s
+            self._phase = "evict"
+            done = self._evict_finished()
+            self._phase = "idle"
         if self.ecfg.telemetry_every and self._iter % self.ecfg.telemetry_every == 0:
             self._window_event()
         if self._capture is not None:
@@ -874,114 +899,121 @@ class GenerationEngine:
             self.admission.note_flow()
 
     def _do_admit(self, req: Request) -> None:
-        t_pop = time.monotonic()
-        req.phases["queue_wait"] = t_pop - req.arrival_t
-        lanes = [self._free_lanes.pop(0) for _ in range(req.lanes_needed)]
-        req.lanes = lanes
-        # prompt-prefix content hash: shared by the redundancy profiler
-        # (_note_prefix) and the flight recorder's alloc context — the key
-        # pool_report's prefix-sharing forecast refcounts on
-        phash = hashlib.sha1(req.text.tobytes()).hexdigest()[:12]
-        rec = self.pool.recorder
-        if rec is not None:
-            rec.ctx = {
-                "req": req.id, "journey": tracing.journey_uid(req),
-                "lanes": req.lanes_needed, "guided": req.guided,
-                "prefix_hash": phash, "replica": self.replica_id,
-            }
-        tables = np.stack([
-            self.pool.alloc_table(owner=(req.id << 1) | i)
-            for i in range(len(lanes))
-        ])
-        if rec is not None:
-            rec.ctx = None
-        # the request's RNG stream, derived exactly as _decode_phase does
-        key, k0 = jax.random.split(jnp.asarray(req.key, jnp.uint32))
-        step_keys = jax.random.split(key, max(self.n_gen - 1, 1))
+        """One admission, as the `serve/admit` span and its four children:
+        alloc (tables, RNG split), dispatch (the admit / ingest jit),
+        lane_meta (the eager per-lane scatters) and ttft_sync (the first
+        token must exist).  `Request.phases` and the window's admit time are
+        fed from the spans' own readings."""
+        req.phases["queue_wait"] = time.monotonic() - req.arrival_t
+        ids = {"iter": self._iter, "req": req.id}
+        with telemetry.timed_span("serve/admit", lanes=req.lanes_needed,
+                                  **ids) as t_admit:
+            with telemetry.timed_span("serve/admit.alloc", **ids) as t_alloc:
+                lanes = [self._free_lanes.pop(0) for _ in range(req.lanes_needed)]
+                req.lanes = lanes
+                # prompt-prefix content hash: shared by the redundancy profiler
+                # (_note_prefix) and the flight recorder's alloc context — the
+                # key pool_report's prefix-sharing forecast refcounts on
+                phash = hashlib.sha1(req.text.tobytes()).hexdigest()[:12]
+                rec = self.pool.recorder
+                if rec is not None:
+                    rec.ctx = {
+                        "req": req.id, "journey": tracing.journey_uid(req),
+                        "lanes": req.lanes_needed, "guided": req.guided,
+                        "prefix_hash": phash, "replica": self.replica_id,
+                    }
+                tables = np.stack([
+                    self.pool.alloc_table(owner=(req.id << 1) | i)
+                    for i in range(len(lanes))
+                ])
+                if rec is not None:
+                    rec.ctx = None
+                # the request's RNG stream, derived exactly as _decode_phase does
+                key, k0 = jax.random.split(jnp.asarray(req.key, jnp.uint32))
+                step_keys = jax.random.split(key, max(self.n_gen - 1, 1))
 
-        text = jnp.asarray(req.text[None], jnp.int32)
-        lane_idx = jnp.asarray(lanes, jnp.int32)
-        t_dispatch = time.monotonic()
-        req.phases["admission"] = t_dispatch - t_pop
-        if self.prefill_backend is not None:
-            # disaggregated: the prefill worker ran _prefill_sample_impl on
-            # ITS mesh (deriving the same k0 from req.key) and hands the KV
-            # prefix + first code over; this side only scatters it into the
-            # pool — the ingest jit is the identical graph the fused admit
-            # traces, so the two paths stay bit-identical
-            handoff = self.prefill_backend.prefill(req)
-            ingest_fn = self._ingest_fn_for(len(lanes))
-            with self._suspend_compiles():
-                self._state = ingest_fn(
-                    self._state, handoff["layers"], handoff["code"],
-                    jnp.asarray(tables, jnp.int32), lane_idx,
+                text = jnp.asarray(req.text[None], jnp.int32)
+                lane_idx = jnp.asarray(lanes, jnp.int32)
+            req.phases["admission"] = t_alloc.s
+            with telemetry.timed_span("serve/admit.dispatch", **ids) as t_dispatch:
+                if self.prefill_backend is not None:
+                    # disaggregated: the prefill worker ran _prefill_sample_impl
+                    # on ITS mesh (deriving the same k0 from req.key) and hands
+                    # the KV prefix + first code over; this side only scatters it
+                    # into the pool — the ingest jit is the identical graph the
+                    # fused admit traces, so the two paths stay bit-identical
+                    handoff = self.prefill_backend.prefill(req)
+                    ingest_fn = self._ingest_fn_for(len(lanes))
+                    with self._suspend_compiles():
+                        self._state = ingest_fn(
+                            self._state, handoff["layers"], handoff["code"],
+                            jnp.asarray(tables, jnp.int32), lane_idx,
+                        )
+                else:
+                    admit_fn = self._admit_fn_for(req.cond_scale, len(lanes))
+                    with self._suspend_compiles():
+                        self._state = admit_fn(
+                            self.params, self._state, text, k0,
+                            jnp.asarray(req.temperature, jnp.float32),
+                            jnp.asarray(tables, jnp.int32), lane_idx,
+                        )
+            with telemetry.timed_span("serve/admit.lane_meta", **ids) as t_meta:
+                # host-owned lane metadata (small per-admission device updates)
+                st = self._state
+                cond = lanes[0]
+                st = dict(
+                    st,
+                    keys=st["keys"].at[cond].set(step_keys.astype(jnp.uint32)),
+                    temp=st["temp"].at[lane_idx].set(req.temperature),
+                    cscale=st["cscale"].at[lane_idx].set(req.cond_scale),
+                    active=st["active"].at[lane_idx].set(True),
+                    cand_cap=st["cand_cap"].at[lane_idx].set(req.degrade_rung >= 2),
                 )
-        else:
-            admit_fn = self._admit_fn_for(req.cond_scale, len(lanes))
-            with self._suspend_compiles():
-                self._state = admit_fn(
-                    self.params, self._state, text, k0,
-                    jnp.asarray(req.temperature, jnp.float32),
-                    jnp.asarray(tables, jnp.int32), lane_idx,
+                if len(lanes) == 2:
+                    null = lanes[1]
+                    st = dict(
+                        st,
+                        guided=st["guided"].at[cond].set(True).at[null].set(False),
+                        partner=st["partner"].at[cond].set(null).at[null].set(null),
+                        feed_src=st["feed_src"].at[cond].set(cond).at[null].set(cond),
+                    )
+                else:
+                    st = dict(
+                        st,
+                        guided=st["guided"].at[cond].set(False),
+                        partner=st["partner"].at[cond].set(cond),
+                        feed_src=st["feed_src"].at[cond].set(cond),
+                    )
+                self._state = st
+                self._inflight.append(req)
+                req.codes_done = 1  # the first image token came out of prefill
+            with telemetry.timed_span("serve/admit.ttft_sync", **ids) as t_sync:
+                # TTFT: the first token must actually exist
+                jax.block_until_ready(self._state["prev_code"])  # host-sync-ok: TTFT measurement point
+            now = time.monotonic()
+            req.admitted_t = now
+            req.ttft_s = now - req.arrival_t
+            req.phases["prefill"] = t_dispatch.s + t_meta.s + t_sync.s
+            obs_metrics.counter("serving/admitted").inc()
+            obs_metrics.histogram("serving/ttft_s").observe(req.ttft_s)
+            # prefix profiling + the hop's admit span: all inputs are host
+            # values this method already holds — emitted AT the existing TTFT
+            # sync, adding none
+            prefix_hash, prefix_repeat = self._note_prefix(req, phash)
+            if tracing.enabled():
+                tracing.emit(
+                    "admit", tracing.journey_uid(req), hop=req.id,
+                    replica=self.replica_id,
+                    arrival_ts=round(tracing.wall(req.arrival_t), 6),
+                    queue_wait_s=round(req.phases["queue_wait"], 6),
+                    admission_s=round(req.phases["admission"], 6),
+                    prefill_s=round(req.phases["prefill"], 6),
+                    ttft_s=round(req.ttft_s, 6), lanes=len(lanes),
+                    mode=("handoff" if self.prefill_backend is not None
+                          else "fused"),
+                    prefix_hash=prefix_hash, prefix_repeat=prefix_repeat,
                 )
-        # host-owned lane metadata (small per-admission device updates)
-        st = self._state
-        cond = lanes[0]
-        st = dict(
-            st,
-            keys=st["keys"].at[cond].set(step_keys.astype(jnp.uint32)),
-            temp=st["temp"].at[lane_idx].set(req.temperature),
-            cscale=st["cscale"].at[lane_idx].set(req.cond_scale),
-            active=st["active"].at[lane_idx].set(True),
-            cand_cap=st["cand_cap"].at[lane_idx].set(req.degrade_rung >= 2),
-        )
-        if len(lanes) == 2:
-            null = lanes[1]
-            st = dict(
-                st,
-                guided=st["guided"].at[cond].set(True).at[null].set(False),
-                partner=st["partner"].at[cond].set(null).at[null].set(null),
-                feed_src=st["feed_src"].at[cond].set(cond).at[null].set(cond),
-            )
-        else:
-            st = dict(
-                st,
-                guided=st["guided"].at[cond].set(False),
-                partner=st["partner"].at[cond].set(cond),
-                feed_src=st["feed_src"].at[cond].set(cond),
-            )
-        self._state = st
-        self._inflight.append(req)
-        req.codes_done = 1  # the first image token came out of prefill
-        # TTFT: the first token must actually exist
-        jax.block_until_ready(self._state["prev_code"])  # host-sync-ok: TTFT measurement point
-        now = time.monotonic()
-        req.admitted_t = now
-        req.ttft_s = now - req.arrival_t
-        req.phases["prefill"] = now - t_dispatch
-        obs_metrics.counter("serving/admitted").inc()
-        obs_metrics.histogram("serving/ttft_s").observe(req.ttft_s)
-        obs_metrics.gauge("serving/active_lanes").set(
-            self.ecfg.num_slots - len(self._free_lanes))
-        obs_metrics.gauge("serving/pool_occupancy_frac").set(self.pool.occupancy_frac)
-        obs_metrics.gauge("serving/pool_free_blocks").set(self.pool.free_blocks)
-        # prefix profiling + the hop's admit span: all inputs are host
-        # values this method already holds — emitted AT the existing TTFT
-        # sync, adding none
-        prefix_hash, prefix_repeat = self._note_prefix(req, phash)
-        if tracing.enabled():
-            tracing.emit(
-                "admit", tracing.journey_uid(req), hop=req.id,
-                replica=self.replica_id,
-                arrival_ts=round(tracing.wall(req.arrival_t), 6),
-                queue_wait_s=round(req.phases["queue_wait"], 6),
-                admission_s=round(req.phases["admission"], 6),
-                prefill_s=round(req.phases["prefill"], 6),
-                ttft_s=round(req.ttft_s, 6), lanes=len(lanes),
-                mode=("handoff" if self.prefill_backend is not None
-                      else "fused"),
-                prefix_hash=prefix_hash, prefix_repeat=prefix_repeat,
-            )
+        self._phase_acc["admit"] += t_admit.s
 
     def _note_prefix(self, req: Request, h: str) -> tuple:
         """Prefix-redundancy accounting for one admission: price the
@@ -1055,17 +1087,18 @@ class GenerationEngine:
         journal progress, drain exactness) — the honest overhead the README
         documents; the sequential path keeps its zero-extra-sync property."""
         k = self._spec[0]
-        t0 = time.perf_counter()
         with (self._suspend_compiles() if not self._warm_spec
               else contextlib.nullcontext()):
-            draft = self._spec_draft_fn(self.params, self._state)
-            # draft/verify wall attribution needs the boundary to exist
-            jax.block_until_ready(draft["drafts"])  # host-sync-ok: spec/draft_time_frac attribution point
-            t1 = time.perf_counter()
-            self._state, acc = self._spec_verify_fn(
-                self.params, self._state, draft)
-            acc_np = np.asarray(acc)  # host-sync-ok: accepted lengths drive codes_done/eviction
-        t2 = time.perf_counter()
+            with telemetry.timed_span("serve/spec.draft",
+                                      iter=self._iter) as t_draft:
+                draft = self._spec_draft_fn(self.params, self._state)
+                # draft/verify wall attribution needs the boundary to exist
+                jax.block_until_ready(draft["drafts"])  # host-sync-ok: spec/draft_time_frac attribution point
+            with telemetry.timed_span("serve/spec.verify",
+                                      iter=self._iter) as t_verify:
+                self._state, acc = self._spec_verify_fn(
+                    self.params, self._state, draft)
+                acc_np = np.asarray(acc)  # host-sync-ok: accepted lengths drive codes_done/eviction
         self._warm_spec = True
         accepted = 0
         lane_tokens = 0
@@ -1100,16 +1133,16 @@ class GenerationEngine:
         # request-rounds, so the window gauge is mean accepted/step/request
         self._win_spec_rounds += len(self._inflight)
         self._win_spec_accepted += accepted
-        self._win_spec_draft_s += t1 - t0
-        self._win_spec_total_s += t2 - t0
+        self._win_spec_draft_s += t_draft.s
+        self._win_spec_total_s += t_draft.s + t_verify.s
         if tracing.enabled():
-            # one event per round, not per request: draft/verify walls come
-            # from the t0/t1/t2 stamps the existing waived syncs bound, and
-            # `hops` maps engine request id -> accepted tokens (joined to
+            # one event per round, not per request: draft/verify walls are
+            # the two spans' readings (each ends in an existing waived sync),
+            # and `hops` maps engine request id -> accepted tokens (joined to
             # journeys through each hop's admit span)
             tracing.emit(
                 "spec_round", None, replica=self.replica_id,
-                draft_s=round(t1 - t0, 6), verify_s=round(t2 - t1, 6),
+                draft_s=round(t_draft.s, 6), verify_s=round(t_verify.s, 6),
                 hops=round_hops,
             )
 
@@ -1117,13 +1150,27 @@ class GenerationEngine:
         done = [r for r in self._inflight if r.codes_done >= self.n_gen]
         if not done:
             return done
-        t_evict = time.monotonic()
+        with telemetry.timed_span("serve/evict", iter=self._iter,
+                                  n=len(done)) as t_evict:
+            done, blocked_s = self._evict(done)
+        # evict window = host bookkeeping only; the device waits inside it
+        # (flag sync, codes pulls, VAE decodes) are the "block" share
+        self._phase_acc["block"] += blocked_s
+        self._phase_acc["evict"] += t_evict.s - blocked_s
+        return done
+
+    def _evict(self, done: List[Request]) -> tuple:
+        """The eviction under its `serve/evict` span.  Returns (the healthy
+        completions, the seconds spent waiting for the device)."""
+        it = self._iter
+        t_start = time.monotonic()
         self._inflight = [r for r in self._inflight if r.codes_done < self.n_gen]
         # the per-lane nonfinite flags, pulled at the EXISTING eviction sync
-        # (the jit accumulated them; the steady-state decode loop never did)
-        t_flag = time.monotonic()
-        poisoned_flags = np.asarray(self._state["poisoned"])  # host-sync-ok: flag pull at the eviction sync
-        self._phase_acc["block"] += time.monotonic() - t_flag
+        # (the jit accumulated them; the steady-state decode loop never did):
+        # the wait drains every decode step the host has queued ahead
+        with telemetry.timed_span("serve/evict.flag_sync", iter=it) as t_flag:
+            poisoned_flags = np.asarray(self._state["poisoned"])  # host-sync-ok: flag pull at the eviction sync
+        blocked_s = t_flag.s
         retry: List[Request] = []
         quarantine: List[Request] = []
         healthy: List[Request] = []
@@ -1137,11 +1184,14 @@ class GenerationEngine:
                 healthy.append(req)
         all_lanes: List[int] = []
         for req in done:
-            req.phases["decode"] = t_evict - req.admitted_t
+            req.phases["decode"] = t_start - req.admitted_t
+            req.phases["evict_sync"] = t_flag.s
             if req in healthy:
-                t_pull = time.monotonic()
-                req.codes = np.asarray(self._state["codes"][req.lanes[0]])  # host-sync-ok: pulling the finished slot's codes
-                self._phase_acc["block"] += time.monotonic() - t_pull
+                with telemetry.timed_span("serve/evict.codes_pull", iter=it,
+                                          req=req.id) as t_pull:
+                    req.codes = np.asarray(self._state["codes"][req.lanes[0]])  # host-sync-ok: pulling the finished slot's codes
+                req.phases["codes_pull"] = t_pull.s
+                blocked_s += t_pull.s
             for i in range(len(req.lanes)):
                 # same written-KV arithmetic as drain(): offsets stop at
                 # n_pre + codes_done - 1 (the final code is never fed back)
@@ -1151,17 +1201,18 @@ class GenerationEngine:
             all_lanes.extend(req.lanes)
             self._free_lanes.extend(req.lanes)
             req.latency_s = time.monotonic() - req.arrival_t
-        li = jnp.asarray(all_lanes, jnp.int32)
-        st = self._state
-        self._state = dict(
-            st,
-            active=st["active"].at[li].set(False),
-            block_tables=st["block_tables"].at[li].set(0),
-            offsets=st["offsets"].at[li].set(0),
-            img_prev=st["img_prev"].at[li].set(0),
-            poisoned=st["poisoned"].at[li].set(False),
-            cand_cap=st["cand_cap"].at[li].set(False),
-        )
+        with telemetry.span("serve/evict.lane_reset", iter=it):
+            li = jnp.asarray(all_lanes, jnp.int32)
+            st = self._state
+            self._state = dict(
+                st,
+                active=st["active"].at[li].set(False),
+                block_tables=st["block_tables"].at[li].set(0),
+                offsets=st["offsets"].at[li].set(0),
+                img_prev=st["img_prev"].at[li].set(0),
+                poisoned=st["poisoned"].at[li].set(False),
+                cand_cap=st["cand_cap"].at[li].set(False),
+            )
         for req in retry:
             # nonfinite lane: evict, free, and re-decode from scratch (same
             # key, same RNG stream) — a transient NaN won't recur; a truly
@@ -1190,36 +1241,35 @@ class GenerationEngine:
             self._finish_record(req, "poisoned",
                                 reason="nonfinite decode logits",
                                 retries=req.poison_retries)
-        done = healthy
-        for req in done:
+        for req in healthy:
             if self._vae_decode is not None:
-                t0 = time.perf_counter()
-                images = self._vae_decode(req.codes[None])
-                jax.block_until_ready(images)  # host-sync-ok: completion boundary
-                vae_s = time.perf_counter() - t0
-                obs_metrics.histogram("gen/vae_decode_s").observe(vae_s)
-                req.images = np.asarray(images)  # host-sync-ok: delivering the result
-                req.phases["vae_decode"] = vae_s
-                self._phase_acc["block"] += vae_s
+                with telemetry.timed_span("serve/evict.vae_decode", iter=it,
+                                          req=req.id) as t_vae:
+                    images = self._vae_decode(req.codes[None])
+                    jax.block_until_ready(images)  # host-sync-ok: completion boundary
+                obs_metrics.histogram("gen/vae_decode_s").observe(t_vae.s)
+                with telemetry.span("serve/evict.pixels_pull", iter=it,
+                                    req=req.id):
+                    req.images = np.asarray(images)  # host-sync-ok: delivering the result
+                req.phases["vae_decode"] = t_vae.s
+                blocked_s += t_vae.s
                 req.latency_s = time.monotonic() - req.arrival_t
             # phases must sum to the latency (reports and the flood drill
-            # rely on it): the residual — codes pull, table frees, waiting
-            # behind batch peers' eviction/VAE work — is evict time
+            # rely on it): the residual — lane reset, pixel pull, table
+            # frees, waiting behind batch peers' eviction/VAE work — is
+            # evict time
             req.phases["evict"] = max(
                 req.latency_s - sum(req.phases.values()), 0.0)
             obs_metrics.counter("serving/completed").inc()
             obs_metrics.histogram("serving/request_s").observe(req.latency_s)
             self._finish_record(req, "completed")
-        obs_metrics.gauge("serving/active_lanes").set(
-            self.ecfg.num_slots - len(self._free_lanes))
-        obs_metrics.gauge("serving/pool_occupancy_frac").set(self.pool.occupancy_frac)
-        obs_metrics.gauge("serving/pool_free_blocks").set(self.pool.free_blocks)
-        return done
+        return healthy, blocked_s
 
     def _window_event(self) -> None:
-        """Close one telemetry window: publish the poll-phase split and the
-        goodput gauge, emit the serving_window event (when telemetry is on),
-        run the SLO monitor, and refresh the status_json scrape file."""
+        """Close one telemetry window: emit the serving_window event with the
+        poll-phase split and the goodput figure and flush the window's spans
+        (when telemetry is on), run the SLO monitor, and refresh the
+        status_json scrape file."""
         now = time.monotonic()
         elapsed = max(now - self._win_t, 1e-9)
         steps = self._win_decode_steps
@@ -1228,11 +1278,6 @@ class GenerationEngine:
         # goodput: lane-tokens actually decoded vs every slot busy every step
         goodput = lane_tokens / ideal if ideal else None
         phases = {k: round(v, 6) for k, v in self._phase_acc.items()}
-        for k, v in self._phase_acc.items():
-            obs_metrics.gauge(f"serving/phase_{k}_s").set(v)
-        if goodput is not None:
-            obs_metrics.gauge("serving/goodput_frac").set(goodput)
-        obs_metrics.gauge("serving/lane_tokens_per_s").set(lane_tokens / elapsed)
         spec_accept = None
         spec_draft_frac = None
         if self._win_spec_rounds:
@@ -1273,8 +1318,10 @@ class GenerationEngine:
         # lifecycle events leave the ring as kind:"pool" records, and the
         # live gauges re-publish (all host work on already-recorded dicts)
         prec = self.pool.recorder
-        if prec is not None and tele is not None:
-            prec.flush(tele.spans, replica=self.replica_id)
+        if tele is not None:
+            tele.spans.flush()  # the window's serve/ spans
+            if prec is not None:
+                prec.flush(tele.spans, replica=self.replica_id)
         if self._pool_gauges is not None:
             self._pool_gauges.publish(
                 dropped=prec.dropped if prec is not None else 0)
@@ -1374,15 +1421,16 @@ def prefill_sample(params, cfg, filter_thres: float, text, k0, temperature,
     cache, last_logits = sampling_mod._prefill_phase(
         params, cfg, text, None, 0, cond_scale
     )
-    lg = (sampling_mod._cfg_combine(last_logits, cond_scale)
-          if guided else last_logits)
-    filtered = top_k_filter(lg, thres=filter_thres)
-    # cast to the logits dtype: the fused path's python-float temperature is
-    # WEAKLY typed (bf16 logits stay bf16 through the division); a strong
-    # f32 scalar would promote and break parity
-    tok = gumbel_sample(k0, filtered,
-                        temperature=temperature.astype(filtered.dtype))
-    code = jnp.clip(
-        tok - cfg.num_text_tokens_padded, 0, cfg.num_image_tokens - 1
-    ).astype(jnp.int32)  # (1,)
+    with jax.named_scope("sample"):
+        lg = (sampling_mod._cfg_combine(last_logits, cond_scale)
+              if guided else last_logits)
+        filtered = top_k_filter(lg, thres=filter_thres)
+        # cast to the logits dtype: the fused path's python-float temperature
+        # is WEAKLY typed (bf16 logits stay bf16 through the division); a
+        # strong f32 scalar would promote and break parity
+        tok = gumbel_sample(k0, filtered,
+                            temperature=temperature.astype(filtered.dtype))
+        code = jnp.clip(
+            tok - cfg.num_text_tokens_padded, 0, cfg.num_image_tokens - 1
+        ).astype(jnp.int32)  # (1,)
     return cache["layers"], code

@@ -114,7 +114,7 @@ class PrefillWorker:
 
             kv_quant = self.quantize_kv
 
-            def run(params, text, k0, temperature):
+            def serve_prefill(params, text, k0, temperature):
                 layers, code = prefill_sample(params, cfg, thres, text, k0,
                                               temperature, cond_scale)
                 if kv_quant:
@@ -128,7 +128,7 @@ class PrefillWorker:
                     layers = quantize_cache_layers(layers)
                 return layers, code
 
-            fn = jax.jit(run)
+            fn = jax.jit(serve_prefill)
             self._fns[key] = fn
         return fn
 

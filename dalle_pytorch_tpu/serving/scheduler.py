@@ -56,7 +56,8 @@ class Request:
     reproducible against the fused sampler.
 
     Lifecycle trace: the engine stamps `phases` (queue_wait / admission /
-    prefill / decode / evict / vae_decode wall-seconds) as the request moves
+    prefill / decode / evict_sync / codes_pull / vae_decode / evict
+    wall-seconds, read off its `serve/` spans) as the request moves
     through it and sets `outcome` exactly once — "completed", "shed"
     (refused at submit), or "deferred" (still queued/in-flight when the
     engine closed) — then emits one `kind:"request"` telemetry record."""

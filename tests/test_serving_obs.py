@@ -84,7 +84,8 @@ def test_request_records_all_outcomes(base, tmp_path):  # noqa: F811
 
     comp = by_outcome["completed"][0]
     phases = comp["phases"]
-    for name in ("queue_wait", "admission", "prefill", "decode", "evict"):
+    for name in ("queue_wait", "admission", "prefill", "decode", "evict_sync",
+                 "codes_pull", "evict"):
         assert name in phases, f"missing phase {name}"
     assert comp["latency_s"] == pytest.approx(sum(phases.values()), abs=1e-4)
     assert comp["decode_tokens"] == cfg.image_seq_len
@@ -161,6 +162,146 @@ def test_serving_window_phase_gauges_and_status_json(base, tmp_path):  # noqa: F
     out = build_report(recs)
     assert "phase attribution" in out and "waterfall" in out
     assert "SLO windows" in out and "SLO burn-rate alarms" in out
+
+
+# --------------------------------------------------------------------------
+# the engine's phases as spans on the profiler's clock
+
+
+# child span -> the span it must lie inside (the tree of serving/engine.py)
+SPAN_TREE = {
+    "serve/admit": "serve/poll",
+    "serve/admit.alloc": "serve/admit",
+    "serve/admit.dispatch": "serve/admit",
+    "serve/admit.lane_meta": "serve/admit",
+    "serve/admit.ttft_sync": "serve/admit",
+    "serve/decode.dispatch": "serve/poll",
+    "serve/spec.draft": "serve/decode.dispatch",
+    "serve/spec.verify": "serve/decode.dispatch",
+    "serve/evict": "serve/poll",
+    "serve/evict.flag_sync": "serve/evict",
+    "serve/evict.codes_pull": "serve/evict",
+    "serve/evict.lane_reset": "serve/evict",
+    "serve/evict.vae_decode": "serve/evict",
+    "serve/evict.pixels_pull": "serve/evict",
+}
+
+
+@pytest.fixture(scope="module")
+def profiled_polls(base, tmp_path_factory):  # noqa: F811
+    """(serve/ events of the host plane, the requests) of two tiny engines,
+    one with a VAE and one speculative, polled to completion under a
+    `jax.profiler` session with NO Telemetry configured."""
+    from jax.profiler import ProfileData
+
+    from dalle_pytorch_tpu.models.vae import DiscreteVAEConfig, init_discrete_vae
+
+    cfg, params, text = base
+    assert telemetry.active() is None
+    vcfg = DiscreteVAEConfig(image_size=16, num_tokens=cfg.num_image_tokens,
+                             num_layers=2, hidden_dim=8, codebook_dim=8)
+    vparams = init_discrete_vae(jax.random.PRNGKey(3), vcfg)
+    engines = [
+        GenerationEngine(params, cfg, vae_params=vparams, vae_cfg=vcfg,
+                         engine_cfg=EngineConfig(num_slots=2, block_size=4)),
+        GenerationEngine(params, cfg,
+                         engine_cfg=EngineConfig(num_slots=2, block_size=4, spec_k=2)),
+    ]
+    out = tmp_path_factory.mktemp("prof")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(out), profiler_options=options)
+    try:
+        reqs = [eng.generate(text[:2], keys=[jax.random.PRNGKey(90 + i) for i in range(2)])
+                for eng in engines]
+    finally:
+        jax.profiler.stop_trace()
+    events = []
+    pb = sorted(out.glob("**/*.xplane.pb"))[-1]
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                events += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+                           for ev in line.events if ev.name.startswith("serve/")]
+    return events, reqs
+
+
+@pytest.mark.parametrize("child,parent", sorted(SPAN_TREE.items()))
+def test_profiler_session_alone_records_the_span_tree(profiled_polls, child, parent):
+    """A running profiler session is the one switch: every span of the tree
+    is in the host plane, each inside a span of its parent's name, carrying
+    the poll's `iter` (and the request's `req` where it belongs to one)."""
+    events, _ = profiled_polls
+    kids = [e for e in events if e[0] == child]
+    assert kids, f"no {child} event in the host plane"
+    parents = [e for e in events if e[0] == parent]
+    for _, a, b, stats in kids:
+        inside = [p for p in parents if p[1] <= a and b <= p[2]]
+        assert inside, f"{child} outside every {parent}"
+        assert str(stats["iter"]) == str(inside[-1][3]["iter"])
+        if child.startswith("serve/admit") or child in (
+                "serve/evict.codes_pull", "serve/evict.vae_decode", "serve/evict.pixels_pull"):
+            assert "req" in stats
+
+
+def test_spans_of_one_request_share_its_id(profiled_polls):
+    events, reqs = profiled_polls
+    req = reqs[0][0]  # first request of the engine with a VAE
+    first_engine_polls = max(int(e[3]["iter"]) for e in events if e[0] == "serve/evict.vae_decode")
+    mine = {e[0] for e in events if e[3].get("req") is not None
+            and int(e[3]["req"]) == req.id and int(e[3]["iter"]) <= first_engine_polls}
+    assert {"serve/submit", "serve/admit", "serve/admit.alloc", "serve/admit.dispatch",
+            "serve/admit.lane_meta", "serve/admit.ttft_sync", "serve/evict.codes_pull",
+            "serve/evict.vae_decode", "serve/evict.pixels_pull"} <= mine
+    for r in reqs[0]:
+        assert set(r.phases) == {"queue_wait", "admission", "prefill", "decode", "evict_sync",
+                                 "codes_pull", "vae_decode", "evict"}
+        assert r.latency_s == pytest.approx(sum(r.phases.values()), abs=1e-4)
+        assert r.images is not None
+
+
+def test_span_without_session_or_telemetry_writes_nothing(tmp_path, monkeypatch):
+    """No profiler session, no Telemetry: `span` is a bare TraceAnnotation
+    (inert), aggregate spans stay no-ops, `timed_span` still hands back its
+    duration, and nothing is written anywhere."""
+    monkeypatch.chdir(tmp_path)
+    assert telemetry.active() is None
+    cm = telemetry.span("serve/poll", iter=1, req=2)
+    assert isinstance(cm, jax.profiler.TraceAnnotation)
+    with cm:
+        pass
+    with telemetry.span("decode_image", aggregate=True) as nothing:
+        assert nothing is None
+    with telemetry.timed_span("serve/evict", iter=1) as t:
+        time.sleep(0.002)
+    assert 0.002 <= t.s < 1.0
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_telemetry_configured_writes_the_same_spans_to_jsonl(base, tmp_path):  # noqa: F811
+    """With a Telemetry the same calls also leave JSONL span records, flushed
+    once a telemetry window (the engine has no step boundary)."""
+    cfg, params, text = base
+    tele = telemetry.configure(str(tmp_path), run_name="serve",
+                               heartbeat_s=None, watch_compiles=False)
+    try:
+        eng = GenerationEngine(params, cfg,
+                               engine_cfg=EngineConfig(num_slots=2, block_size=4,
+                                                       telemetry_every=4))
+        (req,) = eng.generate(text[:1], keys=[jax.random.PRNGKey(5)])
+        eng.close()
+    finally:
+        tele.close()
+    spans = [r for r in _load_spans(tmp_path / "serve.spans.jsonl") if r.get("kind") == "span"]
+    names = {r["name"] for r in spans}
+    assert {"serve/submit", "serve/poll", "serve/decode.dispatch", "serve/evict",
+            "serve/evict.flag_sync"} <= names
+    assert {k for k, v in SPAN_TREE.items() if v == "serve/admit"} <= names
+    admit = next(r for r in spans if r["name"] == "serve/admit.alloc")
+    assert admit["req"] == req.id
+    assert admit["dur_s"] == pytest.approx(req.phases["admission"], abs=1e-4)
+    polls = [r for r in spans if r["name"] == "serve/poll"]
+    assert len(polls) == cfg.image_seq_len - 1  # none dropped, none buffered at close
 
 
 # --------------------------------------------------------------------------

@@ -80,7 +80,8 @@ def _ms(v) -> str:
 
 # lifecycle phase order + the glyph each gets in the waterfall bars
 PHASES = (("queue_wait", "."), ("admission", "a"), ("prefill", "p"),
-          ("decode", "#"), ("evict", "e"), ("vae_decode", "v"))
+          ("decode", "#"), ("evict_sync", "s"), ("codes_pull", "c"),
+          ("evict", "e"), ("vae_decode", "v"))
 
 
 def _phase_table(done: List[Dict[str, Any]]) -> List[str]:

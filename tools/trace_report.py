@@ -46,8 +46,8 @@ from typing import Any, Dict, List, Optional, Tuple
 ACK_OUTCOMES = ("completed", "shed", "poisoned", "requeue_exhausted")
 
 # canonical phase layout inside one hop (extras sort after these)
-PHASE_ORDER = ("queue_wait", "admission", "prefill", "decode",
-               "vae_decode", "evict")
+PHASE_ORDER = ("queue_wait", "admission", "prefill", "decode", "evict_sync",
+               "codes_pull", "vae_decode", "evict")
 
 _TOL = 2e-6  # join/ordering tolerance: both sides round timestamps to 6dp
 
