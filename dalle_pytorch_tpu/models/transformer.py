@@ -1369,15 +1369,34 @@ def _fill_ring(cfg: TransformerConfig, rb: jnp.ndarray, pre_shift: jnp.ndarray) 
 # *values* and the per-slot `offsets` vector, so admitting or evicting a
 # sequence never recompiles anything.
 #
-# Bit parity with the dense path is by construction: each slot's attention
-# runs the SAME `_attention_cached` math on a dense (h, seq_len, dh) view
-# gathered from its blocks (vmapped over slots with a per-slot offset).
-# Positions past a slot's offset hold stale bytes from evicted sequences,
-# but `attend` masks them to finfo.min BEFORE the softmax — exp underflows
-# to exactly 0.0 — so they contribute exactly nothing, same as the dense
-# cache's zeros.  The gathered view is a transient: only ONE layer's view is
-# live at a time, so the decode working set is dense/depth while the at-rest
-# footprint is just the pool (priced by sampling_memory_ledger's paged rows).
+# Decode attention over the pool has two implementations of one operation,
+# chosen per layer from what the code can observe (`_use_paged_kernel`):
+#
+# * the Pallas kernel (kernels/paged_attention.py) reads each slot's K/V
+#   blocks where they lie, addressed through the block table by scalar
+#   prefetch, and writes the new column into its block in place.  No
+#   per-slot view exists, no XLA operation touches a pool array, and only
+#   a slot's live blocks are fetched (none past its offset, none in which
+#   the pattern permits no key): the decode working set is two block tiles.
+#   Float32 online softmax: the same mathematics as the dense path in
+#   another order of summation (float32 round-off, not bit parity).
+# * the XLA path (`_paged_attention_step` + `_paged_scatter_cols`) is the
+#   meaning of the operation and what runs where a tile cannot hold the
+#   shape (dim_head not a multiple of 128, block_size not of 8), on int8
+#   pools, per-head patterns, `stable` softmax and under a health tap.  Bit
+#   parity with the dense cache is by construction: each slot's attention
+#   runs the SAME `_attention_cached` math on a dense (h, seq_len, dh) view
+#   gathered from its blocks (vmapped over slots with a per-slot offset).
+#   That view is a transient of one layer, but on the chip the path is
+#   mostly copies: the gather, and a relayout of the layer's whole pool on
+#   entry and exit that XLA's scatter of the new column asks for (17 of 25
+#   ms of a decode step at DALL-E width; PERF.md section 6, PR 25).
+#
+# Either way positions past a slot's offset hold stale bytes from evicted
+# sequences, and the mask fills them with finfo.min BEFORE the softmax —
+# exp underflows to exactly 0.0 — so they contribute exactly nothing, same
+# as the dense cache's zeros.  The at-rest footprint is just the pool
+# (priced by sampling_memory_ledger's paged rows).
 
 
 def paged_blocks_per_seq(cfg: TransformerConfig, block_size: int) -> int:
@@ -1586,6 +1605,61 @@ def _paged_scatter_cols(layer_pool, block_tables, offsets, cols, block_size: int
     return new
 
 
+def _use_paged_kernel(cfg, layer_pool, pattern, block_size: int) -> bool:
+    """Whether a layer's decode attention takes the Pallas paged kernel, from
+    what the input shows (as `_use_flash` does for training): an unquantized
+    pool, a shared (2-D) pattern or none, the plain softmax, no health tap
+    wanting the scores, and a block tile the chip's tiling holds.  Anything
+    else runs `_paged_attention_step` + `_paged_scatter_cols`."""
+    from dalle_pytorch_tpu.kernels import paged_attention
+    from dalle_pytorch_tpu.observability import health as health_mod
+
+    if "k_scale" in layer_pool or cfg.stable or health_mod.taps_active():
+        return False
+    if pattern is not None and jnp.ndim(pattern) != 2:
+        return False
+    if jax.default_backend() not in ("cpu", "tpu"):
+        return False
+    return paged_attention.supports(
+        cfg.dim_head, block_size, layer_pool["k"].dtype)
+
+
+def _note_paged_path(path_tally: Optional[Dict[str, int]], use_kernel: bool,
+                     layers: int = 1) -> None:
+    """Trace-time count, into the caller's dict, of the attention layers that
+    took the kernel and of those that fell back (see `paged_decode_step`)."""
+    if path_tally is not None:
+        key = "kernel" if use_kernel else "fallback"
+        path_tally[key] = path_tally.get(key, 0) + layers
+
+
+@jax.named_scope("attn")
+def _paged_attention_kernel_step(shared, cfg, layer_pool, block_tables,
+                                 offsets, x, pattern, rotary):
+    """The kernel path of `_paged_attention_step`: x (S, 1, dim) -> (out
+    (S, 1, dim), new layer pool).  The kernel reads the pool through the
+    block table and writes the new column in place; what is left of the
+    gather is each slot's rotary angles and mask row."""
+    from dalle_pytorch_tpu.kernels.paged_attention import paged_decode_attention
+
+    with jax.named_scope("kv_gather"):
+        ang = None
+        if rotary is not None:  # (S, rot), broadcast over (h, qkv, 1)
+            ang = jnp.take(rotary, offsets, axis=0, mode="clip")[:, None, None, None, :]
+        rows = jnp.arange(cfg.seq_len)[None, :] <= offsets[:, None]
+        if pattern is not None:
+            rows = rows & jnp.take(
+                jnp.asarray(pattern), offsets, axis=0, mode="clip")[:, :cfg.seq_len]
+    q, k, v = _qkv_heads(shared, cfg, x, ang)  # (S, h, 1, dh)
+    q = q * (cfg.dim_head ** -0.5)
+    out, k_pool, v_pool = paged_decode_attention(
+        q[:, :, 0], k[:, :, 0], v[:, :, 0], layer_pool["k"], layer_pool["v"],
+        block_tables, offsets, rows,
+    )
+    out = linear(shared["out"], out.reshape(x.shape[0], 1, -1))
+    return out, dict(layer_pool, k=k_pool, v=v_pool)
+
+
 @jax.named_scope("token_shift")
 def _paged_shift_step(cfg, ring, x, offsets):
     """Per-slot cached token shift: vmap of `_shift_cached_step` with a
@@ -1599,28 +1673,36 @@ def _paged_shift_step(cfg, ring, x, offsets):
 
 
 def _paged_branch(cfg, wrap, attn_params, ff_params, x, kind, layer_pool,
-                  block_tables, offsets, ring, pattern, rotary,
-                  decode_tab=None):
+                  block_tables, offsets, ring, pattern, rotary, block_size,
+                  decode_tab=None, use_kernel=False):
     """Decode-mode residual branch over paged per-slot state — the same
     composition as `_residual_branch(mode='decode')` with vectors where that
-    path has scalars.  Returns (branch out, new ring, new KV cols or None)."""
+    path has scalars.  Returns (branch out, new ring, layer pool): an attn
+    branch hands back the pool with the new K/V column written (in the
+    kernel, or by `_paged_scatter_cols`), an ff branch the pool it got."""
     with jax.named_scope("norm"):
         h = layer_norm(wrap[f"{kind}_norm"], x)
     new_ring = ring
     if cfg.shift_tokens:
         h, new_ring = _paged_shift_step(cfg, ring, h, offsets)
-    cols = None
-    if kind == "attn":
+    if kind == "attn" and use_kernel:
+        h, layer_pool = _paged_attention_kernel_step(
+            attn_params, cfg, layer_pool, block_tables, offsets, h, pattern,
+            rotary,
+        )
+    elif kind == "attn":
         h, cols = _paged_attention_step(
             attn_params, cfg, layer_pool, block_tables, offsets, h, pattern,
             rotary, decode_tab=decode_tab,
         )
+        layer_pool = _paged_scatter_cols(
+            layer_pool, block_tables, offsets, cols, block_size)
     else:
         h = _feed_forward(ff_params, cfg, h, None)
     with jax.named_scope("norm"):
         if cfg.sandwich_norm:
             h = layer_norm(wrap[f"{kind}_norm_out"], h)
-        return h * wrap[f"{kind}_scale"].astype(h.dtype), new_ring, cols
+        return h * wrap[f"{kind}_scale"].astype(h.dtype), new_ring, layer_pool
 
 
 def paged_decode_step(
@@ -1634,6 +1716,7 @@ def paged_decode_step(
     block_size: int,
     layer_start: int = 0,
     layer_stop: int = None,
+    path_tally: Optional[Dict[str, int]] = None,
 ) -> Tuple[jnp.ndarray, dict, Optional[dict]]:
     """One decode step for a whole SLOT BATCH of independent sequences at
     per-slot positions.  x: (S, 1, dim) embedded tokens; `offsets`: (S,)
@@ -1643,7 +1726,11 @@ def paged_decode_step(
 
     layer_start/layer_stop restrict the pass to layers [layer_start,
     layer_stop) — the speculative draft (prefix) and verify (continuation)
-    halves.  The returned pool/rings keep untouched layers' state verbatim."""
+    halves.  The returned pool/rings keep untouched layers' state verbatim.
+
+    `path_tally`: a dict of the caller's; while the step is TRACED it gains
+    the number of attention layers that took the Pallas paged kernel
+    ("kernel") and that ran the gather path ("fallback")."""
     specs = derive_layer_specs(cfg)
     specs, partial = _resolve_layer_range(cfg, specs, layer_start, layer_stop)
     rotary = transformer_rotary(cfg)
@@ -1663,7 +1750,7 @@ def paged_decode_step(
                     lambda a: a[sl], rings["layers"])}
         out, new_pool, new_rings = _paged_decode_scan(
             params, cfg, specs, x, run_pool, block_tables, offsets, run_rings,
-            block_size, rotary,
+            block_size, rotary, path_tally,
         )
         if partial:
             new_pool = {"layers": jax.tree_util.tree_map(
@@ -1676,14 +1763,25 @@ def paged_decode_step(
         return out, new_pool, new_rings
 
     patterns = spec_patterns(cfg, specs)
-    dec_tabs = _decode_tables_by_key(cfg, patterns)
+    use_kernel = {}
+    for spec in specs:
+        use_kernel[spec.index] = _use_paged_kernel(
+            cfg, pool["layers"][spec.index], patterns[_pattern_key(spec)],
+            block_size)
+        _note_paged_path(path_tally, use_kernel[spec.index])
+    # gather tables only for the patterns of layers that gather
+    dec_tabs = _decode_tables_by_key(cfg, {
+        _pattern_key(spec): patterns[_pattern_key(spec)]
+        for spec in specs if not use_kernel[spec.index]
+    })
 
     def branch(spec, h, kind, layer_pool, ring):
         return _paged_branch(
             cfg, params["layers"][spec.index], params["shared_attn"][spec.attn_id],
             params["shared_ff"][spec.ff_id], h, kind, layer_pool, block_tables,
-            offsets, ring, patterns[_pattern_key(spec)], rotary,
+            offsets, ring, patterns[_pattern_key(spec)], rotary, block_size,
             decode_tab=dec_tabs.get(_pattern_key(spec)),
+            use_kernel=use_kernel[spec.index],
         )
 
     new_pool_layers, new_ring_layers = [], []
@@ -1694,8 +1792,7 @@ def paged_decode_step(
         lp = pool["layers"][spec.index]
         ring_layer = rings["layers"][spec.index] if cfg.shift_tokens else None
         r_attn = ring_layer["shift_attn"] if cfg.shift_tokens else None
-        fa, r_attn, cols = branch(spec, h, "attn", lp, r_attn)
-        lp = _paged_scatter_cols(lp, block_tables, offsets, cols, block_size)
+        fa, r_attn, lp = branch(spec, h, "attn", lp, r_attn)
         r_ff = ring_layer["shift_ff"] if cfg.shift_tokens else None
         fb, r_ff, _ = branch(spec, h + fa, "ff", lp, r_ff)
         new_ring = (
@@ -1709,8 +1806,7 @@ def paged_decode_step(
             lp0 = pool["layers"][spec.index]
             ring_layer = rings["layers"][spec.index] if cfg.shift_tokens else None
             r_attn = ring_layer["shift_attn"] if cfg.shift_tokens else None
-            fa, r_attn, cols = branch(spec, x2, "attn", lp0, r_attn)
-            lp = _paged_scatter_cols(lp0, block_tables, offsets, cols, block_size)
+            fa, r_attn, lp = branch(spec, x2, "attn", lp0, r_attn)
             x1 = x1 + fa
             r_ff = ring_layer["shift_ff"] if cfg.shift_tokens else None
             fb, r_ff, _ = branch(spec, x1, "ff", lp, r_ff)
@@ -1744,7 +1840,7 @@ def paged_decode_step(
 
 
 def _paged_decode_scan(params, cfg, specs, x, pool, block_tables, offsets,
-                       rings, block_size, rotary):
+                       rings, block_size, rotary, path_tally=None):
     """scan_layers paged decode: one lax.scan over stacked params + stacked
     pool blocks (+ stacked rings), per-layer pattern selected by traced
     index — the paged mirror of `_run_cached_scan(mode='decode')`."""
@@ -1752,7 +1848,9 @@ def _paged_decode_scan(params, cfg, specs, x, pool, block_tables, offsets,
     masks_np, midx = _stacked_masks(cfg, specs, cfg.seq_len)
     masks = jnp.asarray(masks_np)
     stacked = _stacked_bundles(params, specs)
-    dec_tabs = _stacked_decode_tables(cfg, specs)
+    use_kernel = _use_paged_kernel(cfg, pool["layers"], masks_np[0], block_size)
+    _note_paged_path(path_tally, use_kernel, len(specs))
+    dec_tabs = None if use_kernel else _stacked_decode_tables(cfg, specs)
 
     def body(h, xs):
         if cfg.shift_tokens:
@@ -1768,16 +1866,16 @@ def _paged_decode_scan(params, cfg, specs, x, pool, block_tables, offsets,
                 jnp.take(dec_tabs[1], mi, axis=0, mode="clip"),
             )
         r_attn = ring_layer["shift_attn"] if cfg.shift_tokens else None
-        fa, r_attn, cols = _paged_branch(
+        fa, r_attn, lp = _paged_branch(
             cfg, bundle["wrap"], bundle["attn"], bundle["ff"], h, "attn",
-            lp, block_tables, offsets, r_attn, mask, rotary, decode_tab=dtab,
+            lp, block_tables, offsets, r_attn, mask, rotary, block_size,
+            decode_tab=dtab, use_kernel=use_kernel,
         )
-        lp = _paged_scatter_cols(lp, block_tables, offsets, cols, block_size)
         h = h + fa
         r_ff = ring_layer["shift_ff"] if cfg.shift_tokens else None
         fb, r_ff, _ = _paged_branch(
             cfg, bundle["wrap"], bundle["attn"], bundle["ff"], h, "ff",
-            lp, block_tables, offsets, r_ff, mask, rotary, decode_tab=dtab,
+            lp, block_tables, offsets, r_ff, mask, rotary, block_size,
         )
         ys = (lp, {"shift_attn": r_attn, "shift_ff": r_ff}) if cfg.shift_tokens else lp
         return h + fb, ys

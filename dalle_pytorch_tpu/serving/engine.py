@@ -309,6 +309,9 @@ class GenerationEngine:
             self._spec_draft_fn = jax.jit(serve_spec_draft)
             self._spec_verify_fn = jax.jit(serve_spec_verify)
 
+        # {"kernel": n, "fallback": n} once the decode program is traced
+        self._paged_paths: Optional[Dict[str, int]] = None
+
         # the function's name is the program's in a trace (`jit_<name>`)
         def serve_decode_step(params, state):
             return self._decode_step_impl(params, state)
@@ -337,11 +340,13 @@ class GenerationEngine:
 
         emb = spec_mod._embed_prev(params, cfg, prev, state["img_prev"])
 
+        paths = {"kernel": 0, "fallback": 0}
         out, pool, rings = paged_decode_step(
             params["transformer"], tcfg, emb, state["pool"],
             state["block_tables"], state["offsets"], state["rings"],
-            self.ecfg.block_size,
+            self.ecfg.block_size, path_tally=paths,
         )
+        self._note_paged_paths(paths)
 
         # per-slot _logits_at row = producing position = pre-increment offset;
         # per-lane step key row = img_prev (the index of the token being made)
@@ -370,6 +375,15 @@ class GenerationEngine:
                 codes=codes_buf,
                 poisoned=poisoned,
             )
+
+    def _note_paged_paths(self, paths: Dict[str, int]) -> None:
+        """Runs while the decode program is TRACED: how many of its attention
+        layers took the Pallas paged kernel and how many the XLA gather, into
+        the registry (and the status file).  A retrace counts nothing new."""
+        if self._paged_paths is None:
+            self._paged_paths = dict(paths)
+            obs_metrics.counter("serving/paged_attn_kernel_layers").inc(paths["kernel"])
+            obs_metrics.counter("serving/paged_attn_fallback_layers").inc(paths["fallback"])
 
     def _prefill_sample_impl(self, params, text, k0, temperature,
                              cond_scale: float):
@@ -1313,6 +1327,7 @@ class GenerationEngine:
                 decode_steps=steps,
                 **spec_fields,
                 **self.quantization_state(),
+                **self.paged_path_state(),
             )
         # flight-recorder drain rides the same cadence: pending block-
         # lifecycle events leave the ring as kind:"pool" records, and the
@@ -1343,10 +1358,20 @@ class GenerationEngine:
             "inflight": len(self._inflight),
             "pool_occupancy_frac": self.pool.occupancy_frac,
             "pool_free_blocks": self.pool.free_blocks,
+            **self.paged_path_state(),
         }
         payload["pool"] = self.pool_observability()
         payload["quantization"] = self.quantization_state()
         write_status_json(self._status_path, payload)
+
+    def paged_path_state(self) -> Dict[str, Optional[int]]:
+        """How many attention layers of the decode program took the Pallas
+        paged kernel and how many the XLA gather (None until it is traced):
+        the registry's `serving/paged_attn_*_layers`, for status_json and
+        the serving_window event."""
+        paths = self._paged_paths or {}
+        return {"paged_attn_kernel_layers": paths.get("kernel"),
+                "paged_attn_fallback_layers": paths.get("fallback")}
 
     def pool_observability(self) -> Dict[str, Any]:
         """Live pool section for status_json and the serve report: the

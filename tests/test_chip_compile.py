@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from dalle_pytorch_tpu.core import chips
 from dalle_pytorch_tpu.kernels import flash_attention as fa
+from dalle_pytorch_tpu.kernels import paged_attention as pa
 from dalle_pytorch_tpu.models.transformer import TransformerConfig, _pattern_for
 
 B, H, N, D = 4, 16, 1280, 128  # the smoke's attention shape (fmap 32, text 256)
@@ -89,12 +90,69 @@ CASES = {
 }
 
 
+def _paged_shapes(pool_dtype, block_size):
+    """serve_batch's decode attention: 8 slots, 16 heads x 128, sequence
+    1,152, a pool of 8 x 18 + 1 blocks."""
+    seq, slots = 1152, 8
+    nblk = -(-seq // block_size)
+    row = ((slots, H, D), jnp.float32)
+    pool = ((slots * nblk + 1, H, block_size, D), pool_dtype)
+    return [row, row, row, pool, pool, ((slots, nblk), jnp.int32),
+            ((slots,), jnp.int32), ((slots, seq), jnp.bool_)]
+
+
+# the serving kernel: the float32 pool of both serve cells, and a bf16 pool
+# (whose tile is 16 rows)
+CASES["paged_decode_f32_block64"] = (
+    lambda: pa.paged_decode_attention, _paged_shapes(jnp.float32, 64), 1)
+CASES["paged_decode_bf16_block16"] = (
+    lambda: pa.paged_decode_attention, _paged_shapes(jnp.bfloat16, 16), 1)
+
+
 @pytest.mark.parametrize("name", list(CASES))
 def test_kernel_compiles_for_v5e(one_chip, name):
     build, shapes, n_kernels = CASES[name]
     text = _compile(build(), one_chip, *shapes)
     assert text.count("tpu_custom_call") >= n_kernels, (
         f"{name}: expected >= {n_kernels} Pallas custom calls in the compiled program")
+
+
+def test_decode_program_touches_the_pool_only_through_the_kernel(one_chip):
+    """The engine's decode program at serve_batch's width (depth cut to 2),
+    compiled for the chip: both layers take the kernel, and no operation but
+    the kernel has a pool array for an operand or a result — no gather, no
+    relayout `copy` (XLA's scatter wants the pool in another layout: two
+    copies of each pool array a step), and no staging of a pool through VMEM
+    by XLA's memory-space assignment (`slice-start` / `copy-start`), which a
+    cost estimate on the kernel brings on.  The kernel cases above compile
+    WITHOUT donating the pool, where pinning it to HBM aborted the compiler.
+    Nothing runs."""
+    import re
+
+    from dalle_pytorch_tpu.models import dalle as dalle_mod
+    from dalle_pytorch_tpu.models.dalle import DALLEConfig
+    from dalle_pytorch_tpu.serving.engine import EngineConfig, GenerationEngine
+
+    cfg = DALLEConfig(dim=H * D, depth=2, heads=H, dim_head=D, num_text_tokens=16384,
+                      text_seq_len=128, num_image_tokens=8192, image_fmap_size=32,
+                      attn_types=("full", "axial_row"), shift_tokens=True)
+    params = jax.jit(lambda k: dalle_mod.init_dalle(k, cfg))(jax.random.PRNGKey(0))
+    eng = GenerationEngine(params, cfg, engine_cfg=EngineConfig(num_slots=8, block_size=64))
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    text = eng._decode_fn.lower(described(eng.params), described(eng._state)).compile().as_text()
+    assert eng._paged_paths == {"kernel": 2, "fallback": 0}
+    pool = eng._state["pool"]["layers"][0]["k"]
+    shape = "f32[" + ",".join(map(str, pool.shape)) + "]"
+    ops = {}
+    for line in text[text.index("ENTRY "):].splitlines()[1:]:
+        if shape in line:
+            op = re.search(r"= .*? ([a-z][a-z\-]*)\(", line).group(1)
+            ops[op] = ops.get(op, 0) + 1
+    assert ops == {"parameter": 4, "custom-call": 2, "get-tuple-element": 4, "tuple": 1}, ops
 
 
 @pytest.mark.parametrize("lookup", ["flops", "hbm", "ici"])
