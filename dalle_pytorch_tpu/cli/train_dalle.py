@@ -831,9 +831,11 @@ def main(argv=None):
         if use_bf16:
             image = image.astype(jnp.bfloat16)
         codes = vae_registry.get_codebook_indices(encode_vae_params, vae_cfg, image)
+        # a routed trunk also hands back its experts' load (device scalars
+        # that ride in the step's metrics beside the loss)
         return dalle_mod.forward(
             params, dalle_cfg, batch["text"], jax.lax.stop_gradient(codes),
-            return_loss=True, key=key,
+            return_loss=True, key=key, return_aux=dalle_cfg.moe_experts > 0,
         )
 
     optimizer = optax.adam(args.learning_rate)
@@ -1454,6 +1456,9 @@ def main(argv=None):
                             dt = time.time() - t_window
                             steps_done = global_step - window_start + 1
                             record = {"loss": float(be.average_all(metrics["loss"])), "epoch": epoch}
+                            for name in ("moe_load_max_over_mean", "moe_pairs_here"):
+                                if name in metrics:  # a routed trunk's load, fetched with the loss
+                                    record[name] = float(metrics[name])
                             if not first_window:
                                 # the process's first window spans jit compilation —
                                 # minutes for billion-parameter configs — so its rate

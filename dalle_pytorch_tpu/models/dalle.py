@@ -19,12 +19,23 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from dalle_pytorch_tpu.core.module import embedding_init, layer_norm, layer_norm_init, linear, linear_init
+from dalle_pytorch_tpu.core.module import embedding_init, linear, linear_init
 from dalle_pytorch_tpu.core.rng import KeyChain
-from dalle_pytorch_tpu.models.transformer import TransformerConfig, apply_transformer, init_transformer
+from dalle_pytorch_tpu.models.transformer import (
+    TransformerConfig, apply_norm, apply_transformer, init_transformer, norm_init,
+)
 from dalle_pytorch_tpu.observability import health as health_mod
 from dalle_pytorch_tpu.ops.sampling import prob_mask_like
 from dalle_pytorch_tpu.ops.stable import divide_max
+
+
+# the fields that describe the block, handed to TransformerConfig as they are
+_BLOCK_FIELDS = (
+    "norm", "norm_eps", "layer_scale", "kv_heads", "partial_rotary_factor", "rotary_theta",
+    "gdn_key_heads", "gdn_value_heads", "gdn_key_dim", "gdn_value_dim", "gdn_conv_kernel",
+    "moe_experts", "moe_top_k", "moe_ff_dim", "moe_shared_ff_dim",
+    "moe_experts_held", "moe_first_expert",
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,6 +84,26 @@ class DALLEConfig:
     pipeline_axis: Optional[str] = None  # pipeline-parallel mesh axis (e.g. 'pp')
     pp_interleave: int = 1  # circular pipeline chunks per device (bubble / v)
     pp_num_micro: Optional[int] = None  # GPipe microbatches (None = auto)
+    # the block as a parameter (TransformerConfig has each field's meaning):
+    # `attn_types` may cycle `gated_delta` / `gated_full`; a hybrid trunk is
+    # trained through forward() and refused by every sampling entry point
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    layer_scale: bool = True
+    kv_heads: Optional[int] = None
+    partial_rotary_factor: float = 1.0
+    rotary_theta: float = 10000.0
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv_kernel: int = 4
+    moe_experts: int = 0
+    moe_top_k: int = 1
+    moe_ff_dim: int = 0
+    moe_shared_ff_dim: int = 0
+    moe_experts_held: Optional[int] = None
+    moe_first_expert: int = 0
 
     # -- derived ----------------------------------------------------------
     @property
@@ -130,6 +161,7 @@ class DALLEConfig:
             pipeline_axis=self.pipeline_axis,
             pp_num_micro=self.pp_num_micro,
             pp_interleave=self.pp_interleave,
+            **{k: getattr(self, k) for k in _BLOCK_FIELDS},
         )
 
     def to_dict(self) -> dict:
@@ -180,7 +212,7 @@ def init_dalle(key: jax.Array, cfg: DALLEConfig) -> dict:
     keys = KeyChain(key)
     params = {
         "transformer": init_transformer(keys.next(), cfg.transformer_config()),
-        "logits_norm": layer_norm_init(cfg.dim),
+        "logits_norm": norm_init(cfg.transformer_config()),
         "logits_linear": linear_init(keys.next(), cfg.dim, cfg.total_tokens),
     }
     if not cfg.share_input_output_emb:
@@ -275,7 +307,8 @@ def logits_mask_slice(cfg: DALLEConfig, n: int) -> jnp.ndarray:
 
 
 def to_logits(params: dict, cfg: DALLEConfig, x: jnp.ndarray) -> jnp.ndarray:
-    return linear(params["logits_linear"], layer_norm(params["logits_norm"], x))
+    return linear(params["logits_linear"],
+                  apply_norm(cfg.transformer_config(), params["logits_norm"], x))
 
 
 # ---------------------------------------------------------------------------
@@ -290,13 +323,17 @@ def forward(
     return_loss: bool = False,
     null_cond_prob: float = 0.0,
     key: Optional[jax.Array] = None,
+    return_aux: bool = False,
 ):
     """Training/scoring forward.
 
     text: (b, text_seq_len) token ids with 0 = padding.
     image_codes: (b, image_seq_len) VAE code indices (callers with raw pixels
     tokenize through the frozen VAE first).
-    Returns logits (b, n, total_tokens) or the weighted CE loss."""
+    Returns logits (b, n, total_tokens) or the weighted CE loss; with
+    `return_aux`, a pair of that and a dict of device scalars beside it (a
+    routed trunk's `moe_load_max_over_mean` and `moe_pairs_here`; {} for a
+    dense one)."""
     assert text.shape[-1] == cfg.text_seq_len, (
         f"text length {text.shape[-1]} != text_seq_len {cfg.text_seq_len}"
     )
@@ -326,7 +363,8 @@ def forward(
         alpha = 0.1
         tokens = tokens * alpha + jax.lax.stop_gradient(tokens) * (1 - alpha)
 
-    out = apply_transformer(params["transformer"], cfg.transformer_config(), tokens, dropout_key=drop_key)
+    out, aux = apply_transformer(params["transformer"], cfg.transformer_config(), tokens,
+                                 dropout_key=drop_key, return_stats=True)
 
     if cfg.stable:
         out = divide_max(out)
@@ -351,7 +389,7 @@ def forward(
         )
 
     if not return_loss:
-        return logits
+        return (logits, aux) if return_aux else logits
 
     assert image_codes is not None, "when training, image codes must be supplied"
     with jax.named_scope("logits_loss"):
@@ -369,4 +407,5 @@ def forward(
         token_ll = label_logit - lse
         loss_text = -jnp.mean(token_ll[:, : cfg.text_seq_len])
         loss_img = -jnp.mean(token_ll[:, cfg.text_seq_len :])
-        return (loss_text + cfg.loss_img_weight * loss_img) / (cfg.loss_img_weight + 1)
+        loss = (loss_text + cfg.loss_img_weight * loss_img) / (cfg.loss_img_weight + 1)
+    return (loss, aux) if return_aux else loss

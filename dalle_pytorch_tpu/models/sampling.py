@@ -35,7 +35,9 @@ from dalle_pytorch_tpu.quantization import weight_dtype as _weight_dtype
 from dalle_pytorch_tpu.observability import metrics as obs_metrics
 from dalle_pytorch_tpu.observability import telemetry
 from dalle_pytorch_tpu.models.dalle import DALLEConfig
-from dalle_pytorch_tpu.models.transformer import apply_transformer, decode_step, init_cache, prefill
+from dalle_pytorch_tpu.models.transformer import (
+    apply_transformer, decode_step, init_cache, prefill, refuse_hybrid,
+)
 from dalle_pytorch_tpu.ops.sampling import gumbel_sample, top_k_filter
 from dalle_pytorch_tpu.ops.stable import divide_max
 
@@ -246,6 +248,7 @@ def sample_image_codes(
     is bit-identical to spec_k=0 at any temperature; spec_stochastic=True
     swaps in standard rejection/residual sampling (same marginals, different
     RNG stream).  spec_k=0 is exactly today's path — same jit graph."""
+    refuse_hybrid(cfg.transformer_config(), "sample_image_codes")
     if spec_k > 0:
         assert noise_override is None, "speculation owns the RNG stream"
         assert not return_logit_stats, "logit stats live on the scan path"
@@ -368,6 +371,7 @@ def generate_images(
     image-tokens/sec, VAE decode time, sampling-logit numerics, and a CFG
     overhead counter when cond_scale != 1 (guidance doubles every network
     evaluation)."""
+    refuse_hybrid(cfg.transformer_config(), "generate_images")
     from dalle_pytorch_tpu.models import clip as clip_mod
     from dalle_pytorch_tpu.models import vae_registry
 
@@ -536,6 +540,7 @@ def generate_texts(
     O(text_len^2 * depth) re-forward per token (its own generate_texts never
     caches).  use_cache=False keeps the reference-shaped re-forward loop;
     both paths consume the identical RNG stream, so outputs agree."""
+    refuse_hybrid(cfg.transformer_config(), "generate_texts")
     if text is None:
         text = jnp.zeros((1, 1), jnp.int32)
     text = text.astype(jnp.int32)

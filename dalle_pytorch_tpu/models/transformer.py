@@ -43,6 +43,10 @@ from dalle_pytorch_tpu.ops.rotary import apply_rotary, build_dalle_rotary
 from dalle_pytorch_tpu.ops.shift import token_shift
 
 
+PATTERN_ATTN_TYPES = ("full", "axial_row", "axial_col", "conv_like", "sparse")
+HYBRID_ATTN_TYPES = ("gated_full", "gated_delta")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     dim: int
@@ -123,10 +127,53 @@ class TransformerConfig:
     # their pattern permits (Kmax per step) instead of attending over the full
     # seq_len cache — what makes seq-4096 (fmap 64) sampling tractable.
     sparse_decode: bool = True
+    # ---- the block as a parameter (hybrid trunks; training path only) ----
+    # 'layernorm' (LayerNorm, the DALL-E block) | 'rmsnorm_zc' (zero-centred
+    # RMSNorm: x / sqrt(mean(x^2) + norm_eps) * (1 + w))
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6  # RMSNorm only; LayerNorm keeps its 1e-5
+    layer_scale: bool = True  # the per-channel LayerScale on each branch
+    # `gated_full` layers: key/value heads (None = heads), the share of
+    # dim_head that is rotated (rotate-half, by stream position) and its base
+    kv_heads: Optional[int] = None
+    partial_rotary_factor: float = 1.0
+    rotary_theta: float = 10000.0
+    # `gated_delta` layers (models/gated_layers.py): key heads, value heads,
+    # their widths, and the causal depthwise convolution's taps
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv_kernel: int = 4
+    # routed feed-forward (models/moe.py): 0 experts = the dense GEGLU.  The
+    # router is `moe_experts` wide; the layer holds `moe_experts_held` of them
+    # (None = all) from `moe_first_expert` on: one rank's share of an
+    # expert-parallel deployment
+    moe_experts: int = 0
+    moe_top_k: int = 1
+    moe_ff_dim: int = 0
+    moe_shared_ff_dim: int = 0
+    moe_experts_held: Optional[int] = None
+    moe_first_expert: int = 0
 
     @property
     def inner_dim(self) -> int:
         return self.heads * self.dim_head
+
+    @property
+    def kv_heads_resolved(self) -> int:
+        return self.heads if self.kv_heads is None else self.kv_heads
+
+    @property
+    def moe_held(self) -> int:
+        return self.moe_experts if self.moe_experts_held is None else self.moe_experts_held
+
+    @property
+    def hybrid(self) -> bool:
+        """Anything of the block that only the full-sequence training path
+        computes (see `refuse_hybrid`)."""
+        return (self.moe_experts > 0 or self.norm != "layernorm"
+                or any(t in HYBRID_ATTN_TYPES for t in self.attn_types))
 
     @property
     def text_len(self) -> int:
@@ -153,7 +200,7 @@ def derive_layer_specs(cfg: TransformerConfig) -> List[LayerSpec]:
     seen_attn_types: Dict[str, str] = {}
     for i in range(cfg.depth):
         attn_type = cfg.attn_types[i % len(cfg.attn_types)]
-        if attn_type not in ("full", "axial_row", "axial_col", "conv_like", "sparse"):
+        if attn_type not in PATTERN_ATTN_TYPES + HYBRID_ATTN_TYPES:
             raise ValueError(f'attention type "{attn_type}" is not valid')
         attn_id = str(attn_ids[i % len(attn_ids)])
         ff_id = str(ff_ids[i % len(ff_ids)])
@@ -165,6 +212,34 @@ def derive_layer_specs(cfg: TransformerConfig) -> List[LayerSpec]:
         seen_attn_types[attn_id] = attn_type
         specs.append(LayerSpec(i, attn_type, attn_id, ff_id))
     return specs
+
+
+def refuse_hybrid(cfg: TransformerConfig, what: str) -> None:
+    """The one error of every entry point that cannot run a hybrid block
+    (`gated_delta` / `gated_full` layers, routed experts, RMSNorm): they are
+    computed by the full-sequence training path alone.  Serving them needs a
+    recurrent state beside the K/V cache and a one-token form of the delta
+    rule (ROADMAP.md, Queue 2); a wrong picture is worse than none."""
+    if cfg.hybrid:
+        raise NotImplementedError(
+            f"{what} does not support this block (attn_types {cfg.attn_types}, "
+            f"norm {cfg.norm!r}, {cfg.moe_experts} routed experts): gated_delta / "
+            "gated_full layers, routed experts and RMSNorm run on the training path "
+            "only (execution 'sequential' or 'remat', scan_layers off, no pipeline)")
+
+
+def _note_hybrid_layers(cfg: TransformerConfig, specs, gmm_paths: Dict[str, int]) -> None:
+    """Runs while a forward is TRACED: what of the hybrid block the program
+    holds, into the metrics registry (one count per traced forward)."""
+    from dalle_pytorch_tpu.observability import metrics as obs_metrics
+
+    obs_metrics.counter("train/gdn_layers").inc(
+        sum(s.attn_type == "gated_delta" for s in specs))
+    if cfg.moe_experts:
+        obs_metrics.counter("train/moe_layers").inc(len(specs))
+        obs_metrics.counter("train/moe_experts_held").inc(cfg.moe_held * len(specs))
+        obs_metrics.counter("train/moe_gmm_kernel_calls").inc(gmm_paths["kernel"])
+        obs_metrics.counter("train/moe_gmm_fallback_calls").inc(gmm_paths["fallback"])
 
 
 _REMAT_SAVE_NAMES = {
@@ -202,6 +277,25 @@ def _layerscale_eps(layer_one_indexed: int) -> float:
 # init
 # ---------------------------------------------------------------------------
 
+def norm_init(cfg: TransformerConfig) -> dict:
+    """The block's norm over `dim` (cfg.norm)."""
+    if cfg.norm == "layernorm":
+        return layer_norm_init(cfg.dim)
+    if cfg.norm != "rmsnorm_zc":
+        raise ValueError(f"norm {cfg.norm!r} is not valid; choose 'layernorm' or 'rmsnorm_zc'")
+    from dalle_pytorch_tpu.models.gated_layers import rms_norm_init
+
+    return rms_norm_init(cfg.dim)
+
+
+def apply_norm(cfg: TransformerConfig, params: dict, x):
+    if cfg.norm == "layernorm":
+        return layer_norm(params, x)
+    from dalle_pytorch_tpu.models.gated_layers import rms_norm
+
+    return rms_norm(params, x, cfg.norm_eps)
+
+
 def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
     keys = KeyChain(key)
     specs = derive_layer_specs(cfg)
@@ -210,6 +304,16 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
     shared_ff: Dict[str, dict] = {}
     layers = []
     for spec in specs:
+        if spec.attn_type in HYBRID_ATTN_TYPES and spec.attn_id not in shared_attn:
+            from dalle_pytorch_tpu.models import gated_layers
+
+            init = (gated_layers.init_gated_delta if spec.attn_type == "gated_delta"
+                    else gated_layers.init_gated_full)
+            shared_attn[spec.attn_id] = init(keys.next(), cfg)
+        if cfg.moe_experts and spec.ff_id not in shared_ff:
+            from dalle_pytorch_tpu.models.moe import init_moe
+
+            shared_ff[spec.ff_id] = init_moe(keys.next(), cfg)
         if spec.attn_id not in shared_attn:
             # qkv columns are HEAD-MAJOR: [h0:(q|k|v), h1:(q|k|v), ...] — the
             # head axis carries the tp sharding, so splitting into q/k/v is
@@ -230,15 +334,13 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
                 "w2": linear_init(keys.next(), cfg.dim * cfg.ff_mult, cfg.dim),
             }
         eps = _layerscale_eps(spec.index + 1)
-        layer = {
-            "attn_norm": layer_norm_init(cfg.dim),
-            "ff_norm": layer_norm_init(cfg.dim),
-            "attn_scale": jnp.full((1, 1, cfg.dim), eps, jnp.float32),
-            "ff_scale": jnp.full((1, 1, cfg.dim), eps, jnp.float32),
-        }
+        layer = {"attn_norm": norm_init(cfg), "ff_norm": norm_init(cfg)}
+        if cfg.layer_scale:
+            layer["attn_scale"] = jnp.full((1, 1, cfg.dim), eps, jnp.float32)
+            layer["ff_scale"] = jnp.full((1, 1, cfg.dim), eps, jnp.float32)
         if cfg.sandwich_norm:
-            layer["attn_norm_out"] = layer_norm_init(cfg.dim)
-            layer["ff_norm_out"] = layer_norm_init(cfg.dim)
+            layer["attn_norm_out"] = norm_init(cfg)
+            layer["ff_norm_out"] = norm_init(cfg)
         layers.append(layer)
 
     return {"shared_attn": shared_attn, "shared_ff": shared_ff, "layers": layers}
@@ -285,7 +387,9 @@ def migrate_transformer_layout(tparams: dict, heads: int, dim_head: int) -> dict
 
 
 def transformer_rotary(cfg: TransformerConfig) -> Optional[jnp.ndarray]:
-    if not cfg.rotary_emb:
+    """The DALL-E rotary table of the pattern layers (the hybrid layers rotate
+    by stream position, or not at all, themselves)."""
+    if not cfg.rotary_emb or all(t in HYBRID_ATTN_TYPES for t in cfg.attn_types):
         return None
     return build_dalle_rotary(cfg.dim_head, cfg.text_len, cfg.image_fmap_size)
 
@@ -305,7 +409,7 @@ def _pattern_for(cfg: TransformerConfig, attn_type: str, seed: int = 0):
         _pattern_mask_np,
     )
 
-    if attn_type == "full":
+    if attn_type == "full" or attn_type in HYBRID_ATTN_TYPES:
         return None
     if attn_type == "sparse":
         nr = cfg.sparse_num_random_blocks
@@ -566,6 +670,8 @@ def _residual_branch(
     layer_cache: Optional[dict] = None,
     offset=None,
     text_mode: bool = False,
+    attn_type: str = "full",
+    aux: Optional[dict] = None,
 ):
     """THE residual branch — PreShiftToken? -> PreNorm -> attn/ff -> sandwich?
     -> LayerScale — shared by full-sequence apply, scan-layers, prefill and
@@ -573,7 +679,7 @@ def _residual_branch(
     per wrapper; here every mode runs the one definition).  Returns
     (branch output, updated layer cache or None)."""
     with jax.named_scope("norm"):
-        h = layer_norm(wrap[f"{kind}_norm"], x)
+        h = apply_norm(cfg, wrap[f"{kind}_norm"], x)
     if cfg.shift_tokens:
         if mode == "decode":
             if text_mode:
@@ -593,7 +699,11 @@ def _residual_branch(
                 layer_cache[f"shift_{kind}"] = _fill_ring(cfg, layer_cache[f"shift_{kind}"], h)
             with jax.named_scope("token_shift"):
                 h = token_shift(h, cfg.seq_len, cfg.image_fmap_size)
-    if kind == "attn":
+    if kind == "attn" and attn_type in HYBRID_ATTN_TYPES:
+        h = _hybrid_mixer(attn_params, cfg, h, attn_type)
+    elif kind == "ff" and cfg.moe_experts:
+        h = _routed_feed_forward(ff_params, cfg, h, aux)
+    elif kind == "attn":
         if mode == "full":
             h = _attention_full(
                 attn_params, cfg, h, pattern, rotary, key_mask, dkey, live=live,
@@ -615,11 +725,37 @@ def _residual_branch(
         h = _feed_forward(ff_params, cfg, h, dkey)
     with jax.named_scope("norm"):
         if cfg.sandwich_norm:
-            h = layer_norm(wrap[f"{kind}_norm_out"], h)
-        return h * wrap[f"{kind}_scale"].astype(h.dtype), layer_cache
+            h = apply_norm(cfg, wrap[f"{kind}_norm_out"], h)
+        if cfg.layer_scale:
+            h = h * wrap[f"{kind}_scale"].astype(h.dtype)
+        return h, layer_cache
 
 
-def _branch(params, cfg, spec, x, kind, rotary, pattern, key_mask, dkey):
+@jax.named_scope("attn")
+def _hybrid_mixer(shared, cfg, x, attn_type: str):
+    """`gated_delta` / `gated_full` (models/gated_layers.py), full sequence."""
+    from dalle_pytorch_tpu.models import gated_layers
+
+    if attn_type == "gated_delta":
+        return gated_layers.gated_delta_net(shared, cfg, x)
+    return gated_layers.gated_full_attention(
+        shared, cfg, x, use_flash=_use_flash(cfg, x.shape[1], None), mesh=_kernel_mesh(cfg))
+
+
+@jax.named_scope("ff")
+def _routed_feed_forward(shared, cfg, x, aux: Optional[dict]):
+    """Routed experts (models/moe.py).  `aux`, the caller's dict, collects
+    the layer's load scalars and the grouped products' path count."""
+    from dalle_pytorch_tpu.models.moe import moe_feed_forward
+
+    paths = None if aux is None else aux.setdefault("gmm_paths", {"kernel": 0, "fallback": 0})
+    out, stats = moe_feed_forward(shared, cfg, x, path_tally=paths)
+    if aux is not None:
+        aux.setdefault("moe_stats", []).append(stats)
+    return out
+
+
+def _branch(params, cfg, spec, x, kind, rotary, pattern, key_mask, dkey, aux=None):
     """Full-sequence residual branch addressed by layer spec."""
     out, _ = _residual_branch(
         cfg,
@@ -632,6 +768,8 @@ def _branch(params, cfg, spec, x, kind, rotary, pattern, key_mask, dkey):
         pattern=pattern,
         key_mask=key_mask,
         dkey=dkey,
+        attn_type=spec.attn_type,
+        aux=aux,
     )
     return out
 
@@ -646,8 +784,17 @@ def apply_transformer(
     x: jnp.ndarray,
     key_mask: Optional[jnp.ndarray] = None,
     dropout_key: Optional[jax.Array] = None,
-) -> jnp.ndarray:
-    """x: (batch, n, dim) with n <= seq_len.  Full-sequence (training) mode."""
+    return_stats: bool = False,
+):
+    """x: (batch, n, dim) with n <= seq_len.  Full-sequence (training) mode.
+    `return_stats`: also return the routed layers' load scalars, averaged
+    over the layers ({} for a dense feed-forward)."""
+    if cfg.hybrid and (cfg.scan_layers or cfg.pipeline_axis is not None
+                       or cfg.seq_shard_axis is not None
+                       or cfg.execution not in ("sequential", "remat")):
+        refuse_hybrid(cfg, f"apply_transformer(execution={cfg.execution!r}, "
+                           f"scan_layers={cfg.scan_layers}, pipeline_axis={cfg.pipeline_axis!r}, "
+                           f"seq_shard_axis={cfg.seq_shard_axis!r})")
     if cfg.pipeline_axis is not None and not cfg.scan_layers:
         raise ValueError(
             "pipeline_axis requires scan_layers=True (pipeline stages shard "
@@ -679,8 +826,9 @@ def apply_transformer(
             x, PartitionSpec(None, cfg.seq_shard_axis, None)
         )
 
-    def branch(spec, x, kind, dkey):
-        return _branch(params, cfg, spec, x, kind, rotary, patterns[_pattern_key(spec)], key_mask, dkey)
+    def branch(spec, x, kind, dkey, aux=None):
+        return _branch(params, cfg, spec, x, kind, rotary, patterns[_pattern_key(spec)],
+                       key_mask, dkey, aux=aux)
 
     if cfg.execution == "reversible":
         f_fns = []
@@ -704,27 +852,40 @@ def apply_transformer(
             if layer_keys is not None
             else jnp.zeros((cfg.depth, 2, 2), jnp.uint32)
         )
-        return runner(params, x, keys)
+        out = runner(params, x, keys)
+        return (out, {}) if return_stats else out
 
     if cfg.scan_layers:
-        return _apply_scan(params, cfg, x, key_mask, layer_keys, seq_constraint, specs, rotary)
+        out = _apply_scan(params, cfg, x, key_mask, layer_keys, seq_constraint, specs, rotary)
+        return (out, {}) if return_stats else out
 
     x = seq_constraint(x)
+    gmm_paths = {"kernel": 0, "fallback": 0}
+    layer_stats = []
     for spec in specs:
         akey = layer_keys[spec.index, 0] if has_dropout else None
         fkey = layer_keys[spec.index, 1] if has_dropout else None
 
         def block(x, akey=akey, fkey=fkey, spec=spec):
+            # the routed layer's scalars leave the block as outputs: under
+            # jax.checkpoint nothing traced inside may leave any other way
+            aux = {"gmm_paths": gmm_paths}
             x = x + branch(spec, x, "attn", akey)
             x = seq_constraint(x)
-            x = x + branch(spec, x, "ff", fkey)
-            return seq_constraint(x)
+            x = x + branch(spec, x, "ff", fkey, aux)
+            return seq_constraint(x), aux.get("moe_stats", [])
 
         if cfg.execution == "remat":
-            x = _remat_wrap(block, cfg)(x)
+            x, stats = _remat_wrap(block, cfg)(x)
         else:
-            x = block(x)
-    return x
+            x, stats = block(x)
+        layer_stats += stats
+    if cfg.hybrid:
+        _note_hybrid_layers(cfg, specs, gmm_paths)
+    if not return_stats:
+        return x
+    return x, {k: sum(s[k] for s in layer_stats) / len(layer_stats)
+               for k in (layer_stats[0] if layer_stats else {})}
 
 
 def _assert_scannable(cfg, specs):
@@ -973,6 +1134,7 @@ def init_cache(cfg: TransformerConfig, batch: int, dtype=jnp.float32) -> dict:
     of positions already consumed.  With cfg.scan_layers the per-layer entries
     are stacked along a leading depth axis (the scan-layers cached paths scan
     over them) instead of held in a python list."""
+    refuse_hybrid(cfg, "init_cache")
 
     def entry(lead=()):
         e = {
@@ -1256,6 +1418,7 @@ def decode_step(
     continuation from a stored layer-d hidden (layer_start=d).  The returned
     cache keeps the untouched layers' entries verbatim, so a draft pass
     followed by a verify pass writes exactly what one full pass would."""
+    refuse_hybrid(cfg, "decode_step")
     specs = derive_layer_specs(cfg)
     specs, partial = _resolve_layer_range(cfg, specs, layer_start, layer_stop)
     rotary = transformer_rotary(cfg)
@@ -1308,6 +1471,7 @@ def prefill(
 ) -> Tuple[jnp.ndarray, dict]:
     """Consume a length-n prefix starting at offset 0, filling the KV cache and
     shift ring buffers, and return the transformer output for the prefix."""
+    refuse_hybrid(cfg, "prefill")
     n = x.shape[1]
     specs = derive_layer_specs(cfg)
     rotary = transformer_rotary(cfg)
@@ -1418,6 +1582,7 @@ def init_paged_pool(
     the decode scatter of one new column never re-scales a block's existing
     tokens.  Every paged op downstream keys off the presence of the scale
     arrays, so the quantized pool threads through the same jits."""
+    refuse_hybrid(cfg, "init_paged_pool")
     from dalle_pytorch_tpu.quantization import KV_SCALE_DTYPE
 
     def entry(lead=()):
@@ -1731,6 +1896,7 @@ def paged_decode_step(
     `path_tally`: a dict of the caller's; while the step is TRACED it gains
     the number of attention layers that took the Pallas paged kernel
     ("kernel") and that ran the gather path ("fallback")."""
+    refuse_hybrid(cfg, "paged_decode_step")
     specs = derive_layer_specs(cfg)
     specs, partial = _resolve_layer_range(cfg, specs, layer_start, layer_stop)
     rotary = transformer_rotary(cfg)

@@ -130,6 +130,8 @@ def make_train_step(
     specs.
 
     init_fn(params) -> TrainState (sharded when a mesh is given).
+    `loss_fn` returns a scalar loss, or `(loss, aux)` with `aux` a dict of
+    device scalars that land in `metrics` beside the loss (no new sync).
     step_fn(state, batch, key) -> (state, metrics); batch leaves have leading
     dim grad_accum * microbatch and are sharded over the data axes.
 
@@ -200,21 +202,29 @@ def make_train_step(
         )
 
     def grads_and_loss(params, batch, key, scale=None):
+        """(grads, loss, aux): `aux` is the dict of device scalars a loss_fn
+        may return beside its loss (`(loss, aux)`; {} from a plain one), the
+        mean over the microbatches."""
         accum = settings.grad_accum
         compute_params = cast_floating(params, settings.compute_dtype)
-        fn = loss_fn if scale is None else (
-            lambda p, b, k: loss_fn(p, b, k) * scale.astype(settings.compute_dtype)
-        )
+
+        def fn(p, b, k):
+            out = loss_fn(p, b, k)
+            loss, aux = out if isinstance(out, tuple) else (out, {})
+            if scale is not None:
+                loss = loss * scale.astype(settings.compute_dtype)
+            return loss, aux
+
         inv = None if scale is None else 1.0 / scale
 
         if accum == 1:
-            loss, grads = jax.value_and_grad(fn)(compute_params, batch, key)
+            (loss, aux), grads = jax.value_and_grad(fn, has_aux=True)(compute_params, batch, key)
             if inv is not None:
                 grads = jax.tree_util.tree_map(
                     lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype), grads
                 )
                 loss = loss * inv
-            return cast_floating(grads, settings.grad_dtype), loss
+            return cast_floating(grads, settings.grad_dtype), loss, aux
 
         micro = jax.tree_util.tree_map(
             lambda x: x.reshape(accum, x.shape[0] // accum, *x.shape[1:]), batch
@@ -224,21 +234,21 @@ def make_train_step(
         def body(carry, mb_and_key):
             g_acc, l_acc = carry
             mb, k = mb_and_key
-            loss, grads = jax.value_and_grad(fn)(compute_params, mb, k)
+            (loss, aux), grads = jax.value_and_grad(fn, has_aux=True)(compute_params, mb, k)
             g_acc = jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(jnp.float32), g_acc, grads
             )
-            return (g_acc, l_acc + loss), None
+            return (g_acc, l_acc + loss), aux
 
         zero = jax.tree_util.tree_map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params
         )
-        (g, l), _ = jax.lax.scan(body, (zero, 0.0), (micro, keys))
+        (g, l), aux = jax.lax.scan(body, (zero, 0.0), (micro, keys))
         mean = (1.0 / accum) if inv is None else inv / accum
         g = jax.tree_util.tree_map(
             lambda x: (x * mean).astype(settings.grad_dtype), g
         )
-        return g, l * mean
+        return g, l * mean, jax.tree_util.tree_map(lambda a: jnp.mean(a, axis=0), aux)
 
     # allow schedules that consume the loss (e.g. reduce_on_plateau)
     optimizer = optax.with_extra_args_support(optimizer)
@@ -267,7 +277,7 @@ def make_train_step(
             h["taps_dropped_inner_trace"] = jnp.asarray(
                 health_mod.taps_skipped(), jnp.int32
             )
-            h["probe_loss"] = probe_loss
+            h["probe_loss"] = probe_loss[0] if isinstance(probe_loss, tuple) else probe_loss
         return h
 
     def step_fn_inner(state: TrainState, batch, key, with_health: bool = False):
@@ -284,7 +294,7 @@ def make_train_step(
         # named scopes land in the HLO metadata, so these phases show up as
         # labelled regions in xprof/TensorBoard traces of the step
         with jax.named_scope("fwd_bwd"):
-            grads, loss = grads_and_loss(state.params, batch, key, scale=scale)
+            grads, loss, aux = grads_and_loss(state.params, batch, key, scale=scale)
         with jax.named_scope("grad_norm"):
             # norm in f32 regardless of grad_dtype (per-leaf fused reductions,
             # no f32 copy of the gradient buffer is materialized)
@@ -337,7 +347,7 @@ def make_train_step(
 
         if not ls_enabled:
             new_state = TrainState(state.step + 1, params, opt_state)
-            metrics = {"loss": loss, "grad_norm": gnorm}
+            metrics = {**aux, "loss": loss, "grad_norm": gnorm}
             if guarded:
                 metrics["skipped"] = (~finite).astype(jnp.int32)
             if with_health:
@@ -363,6 +373,7 @@ def make_train_step(
         new_ls = {"loss_scale": new_scale, "good_steps": good}
         new_state = TrainState(state.step + 1, params, (opt_state, new_ls))
         metrics = {
+            **aux,
             "loss": loss, "grad_norm": gnorm,
             "loss_scale": new_scale,
             "skipped": (~finite).astype(jnp.int32),

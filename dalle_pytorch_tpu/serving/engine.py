@@ -81,6 +81,7 @@ from dalle_pytorch_tpu.models import speculative as spec_mod
 from dalle_pytorch_tpu.models.transformer import (
     init_slot_rings,
     paged_decode_step,
+    refuse_hybrid,
     write_prefill_to_pool,
 )
 from dalle_pytorch_tpu.observability import metrics as obs_metrics
@@ -139,6 +140,7 @@ class GenerationEngine:
         usage_fn=None,
     ):
         assert cfg.image_seq_len >= 2, "engine needs at least 2 image tokens"
+        refuse_hybrid(cfg.transformer_config(), "GenerationEngine")
         self.params = params
         self.cfg = cfg
         self.tcfg = cfg.transformer_config()

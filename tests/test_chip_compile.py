@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from dalle_pytorch_tpu.core import chips
 from dalle_pytorch_tpu.kernels import flash_attention as fa
 from dalle_pytorch_tpu.kernels import paged_attention as pa
+from dalle_pytorch_tpu.models import moe
 from dalle_pytorch_tpu.models.transformer import TransformerConfig, _pattern_for
 
 B, H, N, D = 4, 16, 1280, 128  # the smoke's attention shape (fmap 32, text 256)
@@ -39,6 +40,7 @@ def one_chip():
         pytest.skip(f"cannot describe a v5e topology: {e!r}")
     mp = pytest.MonkeyPatch()
     mp.setattr(fa, "_interpret", lambda: False)
+    mp.setattr(moe, "_use_gmm_kernel", lambda: True)  # what a TPU backend answers
     yield SingleDeviceSharding(topo.devices[0])
     mp.undo()
 
@@ -107,6 +109,37 @@ CASES["paged_decode_f32_block64"] = (
     lambda: pa.paged_decode_attention, _paged_shapes(jnp.float32, 64), 1)
 CASES["paged_decode_bf16_block16"] = (
     lambda: pa.paged_decode_attention, _paged_shapes(jnp.bfloat16, 16), 1)
+
+
+# the hybrid trunk's cell (train_q3n_ep16), one microbatch: a gated_full layer's
+# attention (16 heads once the 2 key/value heads are spread, width 256) ...
+CASES["dense_full_d256_seq4224"] = (
+    lambda: _grad_of(grid="dense"), [((1, 16, 4224, 256), jnp.bfloat16)] * 3, 3)
+
+
+# ... and the held experts' grouped products over the pair buffer (4,224 x 10
+# rows, 32 experts): jax's megablox kernels of the backward (the rows'
+# gradient is the forward kernel on the transposed weights; the weights'
+# gradient is its transposed twin); a sum's gradient needs no forward value
+def _grouped_grad():
+    def loss(lhs, rhs, sizes):
+        return moe.grouped_matmul(lhs, rhs, sizes).astype(jnp.float32).sum()
+
+    def grads(lhs, rhs, sizes):
+        # tests/conftest.py asks every matmul for "highest", which the kernel's
+        # bfloat16 product refuses; the program runs with the default
+        with jax.default_matmul_precision("default"):
+            return jax.grad(loss, argnums=(0, 1))(lhs, rhs, sizes)
+
+    return grads
+
+
+CASES["moe_grouped_up_proj"] = (
+    _grouped_grad, [((42240, 2048), jnp.bfloat16), ((32, 2048, 512), jnp.bfloat16),
+                    ((32,), jnp.int32)], 2)
+CASES["moe_grouped_down_proj"] = (
+    _grouped_grad, [((42240, 512), jnp.bfloat16), ((32, 512, 2048), jnp.bfloat16),
+                    ((32,), jnp.int32)], 2)
 
 
 @pytest.mark.parametrize("name", list(CASES))
