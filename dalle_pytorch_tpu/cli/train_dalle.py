@@ -1456,7 +1456,7 @@ def main(argv=None):
                             dt = time.time() - t_window
                             steps_done = global_step - window_start + 1
                             record = {"loss": float(be.average_all(metrics["loss"])), "epoch": epoch}
-                            for name in ("moe_load_max_over_mean", "moe_pairs_here"):
+                            for name in ("moe_load_max_over_mean", "moe_pairs_here", "moe_overflow_share"):
                                 if name in metrics:  # a routed trunk's load, fetched with the loss
                                     record[name] = float(metrics[name])
                             if not first_window:

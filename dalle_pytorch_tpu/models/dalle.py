@@ -332,8 +332,8 @@ def forward(
     tokenize through the frozen VAE first).
     Returns logits (b, n, total_tokens) or the weighted CE loss; with
     `return_aux`, a pair of that and a dict of device scalars beside it (a
-    routed trunk's `moe_load_max_over_mean` and `moe_pairs_here`; {} for a
-    dense one)."""
+    routed trunk's `moe_load_max_over_mean`, `moe_pairs_here` and
+    `moe_overflow_share`; {} for a dense one)."""
     assert text.shape[-1] == cfg.text_seq_len, (
         f"text length {text.shape[-1]} != text_seq_len {cfg.text_seq_len}"
     )

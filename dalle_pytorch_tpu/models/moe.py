@@ -11,17 +11,26 @@ every rank computes alike), and that partial result is the layer's output.
 Nothing stands in for the absent ranks or their exchange; with every expert
 held the output is the whole layer's.
 
-No token is dropped, whatever the load: the (token, expert) pairs are sorted
+No token is dropped, whatever the load: the (token, expert) pairs are ranked
 by expert, so each held expert's rows are contiguous, and the three products
 are grouped matrix multiplications over row groups of the sizes the router
-produced.  The pair buffer has a row for every pair (tokens x top_k): under
-jit the bound is static, and that is the one bound no routing can exceed.
-Rows past the held pairs are zero and cost the grouped product nothing (on the
-TPU its grid covers the occupied row tiles only).
+produced.  Every row of a pair buffer is paid for whether a pair lies in it or
+not (a gather, the kernels' row tiles, the selects around them, a scatter-add;
+forward, recomputed and transposed), so the buffer is sized by the load this
+rank EXPECTS, not by the load no routing can exceed: `pair_rows` = tokens x
+top_k x held / experts, times `_HEAD_ROOM`, in whole row tiles, and never more
+than tokens x top_k.  The ranking is walked in chunks of that many rows, as
+many as hold a pair (a loop whose length the device decides: one chunk at the
+expected load, every chunk when every pair lies here), each chunk dispatched,
+multiplied and added into its tokens' rows on its own.  So the layer costs
+what its load costs, at any load, and is exact at every one;
+`moe_overflow_share` says how often one chunk was not enough.  Where every
+expert is held the one chunk is the whole ranking.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, Optional
 
 import jax
@@ -30,7 +39,14 @@ import jax.numpy as jnp
 from dalle_pytorch_tpu.core.module import Initializer, linear, linear_init
 from dalle_pytorch_tpu.core.rng import KeyChain
 
-_ROW_TILE = 128  # the kernel's row tile; the pair buffer is padded to it
+_ROW_TILE = 128  # the kernel's row tile; a chunk of the ranking is whole tiles
+# A chunk's rows over the pairs this rank expects.  Routing that sends pairs
+# here independently spreads by sqrt(pairs) (random weights: 2,640 +- 50 of
+# 42,240), so the first chunk holds such a load with room to spare and a
+# smaller factor would walk two.  It is no bound on the load: a router trained
+# with nothing to balance it across ranks came to send this rank twelve times
+# its share (PERF.md section 6, PR 27), and such a call walks more chunks.
+_HEAD_ROOM = 2
 
 
 def init_moe(key: jax.Array, cfg) -> dict:
@@ -102,44 +118,116 @@ def _swiglu(wg, wu, wd, x):
     return linear(wd, jax.nn.silu(linear(wg, x)) * linear(wu, x))
 
 
-def _routed_terms(cfg, path_tally, x2, weights, ids, ex):
-    """sum over the held experts e of a token's top-k of p_e E_e(x): (tokens,
-    dim), and the held experts' row counts."""
+def _whole_tiles(rows: int) -> int:
+    return rows + (-rows) % _ROW_TILE
+
+
+def _padded_rows(cfg, tokens: int) -> int:
+    return _whole_tiles(tokens * cfg.moe_top_k)
+
+
+def pair_rows(cfg, tokens: int) -> int:
+    """Rows of a chunk of the ranking of `tokens` tokens' pairs (static):
+    `_HEAD_ROOM` times the pairs expected at the held experts, in whole row
+    tiles, and at most every pair."""
+    expected = tokens * cfg.moe_top_k * cfg.moe_held / cfg.moe_experts
+    return min(_whole_tiles(math.ceil(_HEAD_ROOM * expected)), _padded_rows(cfg, tokens))
+
+
+def _chunk_terms(cfg, rows, path_tally, ranking, first_row, x2, weights, ex):
+    """What the `rows` rows of the ranking from `first_row` on add to the
+    routed terms: (tokens, dim).  `rows` is static, `first_row` is not."""
+    order, sizes, here = ranking
     tokens, dim = x2.shape
-    k, held, first = cfg.moe_top_k, cfg.moe_held, cfg.moe_first_expert
     with jax.named_scope("moe_dispatch"):
-        local = ids - first
-        here = (local >= 0) & (local < held)
-        group = jnp.where(here, local, held).reshape(-1)  # `held` = not ours: sorts last
-        order = jnp.argsort(group, stable=True)
-        sizes = jnp.bincount(group, length=held + 1)[:held]
-        rows = tokens * k
-        padded = rows + (-rows) % _ROW_TILE
-        order = jnp.pad(order, (0, padded - rows))
-        occupied = (jnp.arange(padded) < jnp.sum(sizes))[:, None]
-        xg = jnp.where(occupied, jnp.take(x2, order // k, axis=0), jnp.zeros((), x2.dtype))
+        pair = jax.lax.dynamic_slice(order, (first_row,), (rows,))
+        token = pair // cfg.moe_top_k
+        ends = jnp.cumsum(sizes)
+        last_row = jnp.minimum(ends, first_row + rows)
+        sizes = jnp.maximum(last_row - jnp.maximum(ends - sizes, first_row), 0)  # of each group, here
+        occupied = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        xg = jnp.where(occupied, jnp.take(x2, token, axis=0), jnp.zeros((), x2.dtype))
 
     with jax.named_scope("moe_experts"):
         gmm = functools.partial(grouped_matmul, group_sizes=sizes, path_tally=path_tally)
-        hidden = jax.nn.silu(gmm(xg, ex["wg"].astype(xg.dtype))) * gmm(xg, ex["wu"].astype(xg.dtype))
-        yg = gmm(hidden, ex["wd"].astype(xg.dtype))
+        hidden = jax.nn.silu(gmm(xg, ex["wg"])) * gmm(xg, ex["wu"])
+        yg = gmm(hidden, ex["wd"])
 
     with jax.named_scope("moe_combine"):
-        w_sorted = jnp.take(jnp.where(here, weights, 0.0).reshape(-1), order[:rows])
-        yg = (yg[:rows].astype(jnp.float32) * w_sorted[:, None]).astype(x2.dtype)
-        # back to pair order (token-major), then the k terms of each token add
-        # up, in x's type: at most `held` of them are not zero, and a float32
-        # sum would make the backward a float32 buffer of every pair
-        unsort = jnp.argsort(order[:rows])
-        out = jnp.take(yg, unsort, axis=0).reshape(tokens, k, dim).sum(axis=1)
-    return out, sizes
+        w_row = jnp.take(jnp.where(here, weights, 0.0).reshape(-1), pair)
+        yg = (yg.astype(jnp.float32) * w_row[:, None]).astype(x2.dtype)
+        # each row into its token's row, in x's type: at most `held` terms a
+        # token are not zero, and a float32 sum would make the backward a
+        # float32 buffer of every row.  Rows past the pairs are zero.
+        return jnp.zeros((tokens, dim), x2.dtype).at[token].add(yg)
+
+
+def _walk(cfg, rows, path_tally, x2, weights, ex, ranking):
+    """The sum of `_chunk_terms` over the chunks of `rows` rows that hold a
+    pair, and its gradient by the same walk."""
+    def chunks(ranking):
+        _, sizes, _ = ranking
+        return -(-jnp.sum(sizes) // rows)
+
+    def in_x_type(ex):
+        return jax.tree.map(lambda w: w.astype(x2.dtype), ex)
+
+    def add(total, part):  # in the combine's scope, so that the layer's scopes hold the loop's own cost
+        with jax.named_scope("moe_combine"):
+            return jax.tree.map(jnp.add, total, part)
+
+    def forward(x2, weights, ex, ranking):
+        term = functools.partial(_chunk_terms, cfg, rows, path_tally, ranking)
+        ex = in_x_type(ex)
+        return jax.lax.fori_loop(
+            0, chunks(ranking), lambda j, out: add(out, term(j * rows, x2, weights, ex)),
+            jnp.zeros(x2.shape, x2.dtype))
+
+    # A loop of a length the device decides has no transpose, and the buffers
+    # are to be recomputed in the backward instead of kept (they would be most
+    # of the layer's memory), as jax.checkpoint would: each chunk is
+    # differentiated on its own and the gradients add up, the experts' in
+    # x's type (an expert's rows lie in a chunk or two).
+    def backward(inputs, g):
+        x2, weights, ex, ranking = inputs
+        term = functools.partial(_chunk_terms, cfg, rows, None, ranking)
+        narrow = in_x_type(ex)
+
+        def add_chunk(j, grads):
+            _, pull = jax.vjp(functools.partial(term, j * rows), x2, weights, narrow)
+            return add(grads, pull(g))
+
+        dx, dw, dex = jax.lax.fori_loop(
+            0, chunks(ranking), add_chunk, jax.tree.map(jnp.zeros_like, (x2, weights, narrow)))
+        return dx, dw, jax.tree.map(lambda d, w: d.astype(w.dtype), dex, ex), None
+
+    terms = jax.custom_vjp(forward)
+    terms.defvjp(lambda *inputs: (forward(*inputs), inputs), backward)
+    return terms(x2, weights, ex, ranking)
+
+
+def _routed_terms(cfg, path_tally, x2, weights, ids, ex):
+    """sum over the held experts e of a token's top-k of p_e E_e(x): (tokens,
+    dim), and the held experts' row counts."""
+    tokens, held = x2.shape[0], cfg.moe_held
+    rows = pair_rows(cfg, tokens)
+    with jax.named_scope("moe_dispatch"):  # integers only: no gradient passes here
+        local = ids - cfg.moe_first_expert
+        here = (local >= 0) & (local < held)
+        group = jnp.where(here, local, held).reshape(-1)  # `held` = not ours: ranks last
+        order = jnp.argsort(group, stable=True)
+        order = jnp.pad(order, (0, (-order.shape[0]) % rows))  # whole chunks
+        sizes = jnp.bincount(group, length=held + 1)[:held]
+    return _walk(cfg, rows, path_tally, x2, weights, ex, (order, sizes, here)), sizes
 
 
 def moe_feed_forward(params: dict, cfg, x: jnp.ndarray,
                      path_tally: Optional[Dict[str, int]] = None):
     """x: (batch, n, dim) -> (out (batch, n, dim), stats).  `stats` are device
-    scalars: `moe_pairs_here` (pairs routed to held experts) and
-    `moe_load_max_over_mean` (the busiest held expert's rows over the mean)."""
+    scalars: `moe_pairs_here` (pairs routed to held experts),
+    `moe_load_max_over_mean` (the busiest held expert's rows over the mean) and
+    `moe_overflow_share` (1 where the pairs outgrew `pair_rows` and more than
+    one chunk was walked, else 0: averaged over layer calls, a share)."""
     b, n, dim = x.shape
     x2 = x.reshape(b * n, dim)
     held = cfg.moe_held
@@ -147,12 +235,7 @@ def moe_feed_forward(params: dict, cfg, x: jnp.ndarray,
     with jax.named_scope("moe_router"):
         weights, ids = route(params["router"], cfg, x2)
 
-    # the pair buffers (tokens * top_k rows, mostly empty) are recomputed in
-    # the backward instead of kept: at the expected load the held experts'
-    # products are a percent of the layer's operations, their buffers would be
-    # most of its memory
-    out, sizes = jax.checkpoint(functools.partial(_routed_terms, cfg, path_tally))(
-        x2, weights, ids, params["experts"])
+    out, sizes = _routed_terms(cfg, path_tally, x2, weights, ids, params["experts"])
     pairs_here = jnp.sum(sizes)
 
     if "shared" in params:
@@ -165,5 +248,6 @@ def moe_feed_forward(params: dict, cfg, x: jnp.ndarray,
     stats = {
         "moe_pairs_here": pairs_here.astype(jnp.float32),
         "moe_load_max_over_mean": jnp.max(sizes) / jnp.maximum(pairs_here / held, 1.0),
+        "moe_overflow_share": (pairs_here > pair_rows(cfg, b * n)).astype(jnp.float32),
     }
     return out.astype(x.dtype).reshape(b, n, dim), stats

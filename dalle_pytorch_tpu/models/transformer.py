@@ -228,9 +228,12 @@ def refuse_hybrid(cfg: TransformerConfig, what: str) -> None:
             "only (execution 'sequential' or 'remat', scan_layers off, no pipeline)")
 
 
-def _note_hybrid_layers(cfg: TransformerConfig, specs, gmm_paths: Dict[str, int]) -> None:
+def _note_hybrid_layers(cfg: TransformerConfig, specs, gmm_paths: Dict[str, int],
+                        tokens: int) -> None:
     """Runs while a forward is TRACED: what of the hybrid block the program
-    holds, into the metrics registry (one count per traced forward)."""
+    holds, into the metrics registry (one count per traced forward).  The
+    grouped products are counted as `moe.grouped_matmul` is traced: three a
+    layer, in the loop body that walks the pairs' chunks."""
     from dalle_pytorch_tpu.observability import metrics as obs_metrics
 
     obs_metrics.counter("train/gdn_layers").inc(
@@ -238,6 +241,9 @@ def _note_hybrid_layers(cfg: TransformerConfig, specs, gmm_paths: Dict[str, int]
     if cfg.moe_experts:
         obs_metrics.counter("train/moe_layers").inc(len(specs))
         obs_metrics.counter("train/moe_experts_held").inc(cfg.moe_held * len(specs))
+        from dalle_pytorch_tpu.models.moe import pair_rows
+
+        obs_metrics.counter("train/moe_pair_rows").inc(pair_rows(cfg, tokens) * len(specs))
         obs_metrics.counter("train/moe_gmm_kernel_calls").inc(gmm_paths["kernel"])
         obs_metrics.counter("train/moe_gmm_fallback_calls").inc(gmm_paths["fallback"])
 
@@ -881,7 +887,7 @@ def apply_transformer(
             x, stats = block(x)
         layer_stats += stats
     if cfg.hybrid:
-        _note_hybrid_layers(cfg, specs, gmm_paths)
+        _note_hybrid_layers(cfg, specs, gmm_paths, tokens=x.shape[0] * x.shape[1])
     if not return_stats:
         return x
     return x, {k: sum(s[k] for s in layer_stats) / len(layer_stats)

@@ -140,6 +140,15 @@ CASES["moe_grouped_up_proj"] = (
 CASES["moe_grouped_down_proj"] = (
     _grouped_grad, [((42240, 512), jnp.bfloat16), ((32, 512, 2048), jnp.bfloat16),
                     ((32,), jnp.int32)], 2)
+# those are every pair's rows at once (a rank that holds every expert); the
+# cell's program walks chunks of `moe.pair_rows` rows (twice the 2,640 pairs
+# expected at 32 of 512 experts, in row tiles: 5,376)
+CASES["moe_grouped_up_proj_pair_rows"] = (
+    _grouped_grad, [((5376, 2048), jnp.bfloat16), ((32, 2048, 512), jnp.bfloat16),
+                    ((32,), jnp.int32)], 2)
+CASES["moe_grouped_down_proj_pair_rows"] = (
+    _grouped_grad, [((5376, 512), jnp.bfloat16), ((32, 512, 2048), jnp.bfloat16),
+                    ((32,), jnp.int32)], 2)
 
 
 @pytest.mark.parametrize("name", list(CASES))

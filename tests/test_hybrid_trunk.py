@@ -102,7 +102,8 @@ def test_batch_rows_are_independent_and_aux_is_reported(model):
     logits, aux = jax.jit(lambda p: dalle_mod.forward(p, cfg, t, c, return_aux=True))(params)
     alone = jax.jit(lambda p: dalle_mod.forward(p, cfg, t[:1], c[:1]))(params)
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(alone[0]), atol=LOGITS_ATOL)
-    assert set(aux) == {"moe_pairs_here", "moe_load_max_over_mean"}
+    assert set(aux) == {"moe_pairs_here", "moe_load_max_over_mean", "moe_overflow_share"}
+    assert float(aux["moe_overflow_share"]) == 0.0
     # 2 sequences x 24 positions x top-3 over 16 experts, 4 held: 36 pairs expected
     assert 0 < float(aux["moe_pairs_here"]) < 2 * 24 * 3
     assert float(aux["moe_load_max_over_mean"]) >= 1.0
@@ -230,6 +231,95 @@ def test_an_overloaded_expert_drops_nothing():
     np.testing.assert_allclose(np.asarray(out.reshape(32, 32)),
                                np.asarray(ref.moe(_sizes_of(cfg), params, x.reshape(32, 32))),
                                atol=LOGITS_ATOL)
+
+
+_CLASSES = [(0, 1, 2), (4, 0, 1), (4, 5, 0), (4, 5, 6)]  # a token class's top-3, in order
+
+
+def _routed_to(counts, held, tokens=128, experts=16):
+    """A router and inputs that send `counts[j]` tokens to the experts of
+    class j, of which [4, 4 + held) are held: token classes one-hot in x's
+    first four channels, a router row per class, logits 6, 5.5, 5 against
+    0.01s, so no choice is near a tie and gradients flow.  Also the pairs
+    that makes here."""
+    cfg = _moe_cfg(moe_experts=experts, moe_experts_held=held, moe_first_expert=4)
+    params = moe.init_moe(jax.random.PRNGKey(0), cfg)
+    w = np.zeros((32, experts), np.float32)
+    for j, chosen in enumerate(_CLASSES):
+        w[j, list(chosen)] = (6.0, 5.5, 5.0)
+    params = {**params, "router": {"w": jnp.asarray(w) + 0.01 * params["router"]["w"]}}
+    x = 0.3 * np.asarray(jax.random.normal(jax.random.PRNGKey(1), (tokens, 32)))
+    x[:, :4] = 0.0
+    kinds = np.repeat(np.arange(4), [tokens - sum(counts[1:]), *counts[1:]])
+    x[np.arange(tokens), np.random.default_rng(0).permutation(kinds)] = 1.0
+    pairs = sum(n * sum(4 <= e < 4 + held for e in chosen) for n, chosen in zip(counts[1:], _CLASSES[1:]))
+    return cfg, params, jnp.asarray(x), pairs
+
+
+# Top-3.  128 tokens, 4 of 16 experts held: 96 of 384 pairs expected, chunks of
+# 256 rows.  512 tokens, 1 of 32 held: 48 of 1,536, chunks of 128 rows.
+@pytest.mark.parametrize("tokens,experts,held,counts,pairs,chunks", [
+    (128, 16, 4, (0, 20, 10, 5), 55, 1),     # well under a chunk
+    (128, 16, 4, (0, 1, 0, 85), 256, 1),     # the last row of the chunk is a pair
+    (128, 16, 4, (0, 2, 0, 85), 257, 2),     # one pair too many: a second chunk for it
+    (128, 16, 4, (0, 0, 0, 128), 384, 2),    # every pair of every token lies here
+    (512, 32, 1, (0, 128, 0, 0), 128, 1),    # a thirty-second of the experts: a twelfth of the rows
+    (512, 32, 1, (0, 200, 200, 112), 512, 4),  # ... and the held expert in every token's top-3
+    (512, 32, 1, (512, 0, 0, 0), 0, 0),      # ... and in no token's
+], ids=["under", "exactly", "one_over", "every_pair", "small_share", "small_share_busiest",
+        "small_share_idle"])
+def test_the_pair_buffer_is_bounded_and_its_overflow_is_exact(monkeypatch, tokens, experts, held,
+                                                              counts, pairs, chunks):
+    cfg, params, x, made = _routed_to(counts, held, tokens, experts)
+    rows = moe.pair_rows(cfg, tokens)
+    assert made == pairs and rows == {4: 256, 1: 128}[held] < tokens * 3
+    sizes = _sizes_of(cfg)
+    cot = jax.random.normal(jax.random.PRNGKey(2), (tokens, 32))
+    ran = []  # the first row of every chunk that RAN, forward and backward
+    chunk_terms = moe._chunk_terms
+
+    def noted(cfg, rows, tally, ranking, first_row, *args):
+        jax.debug.callback(lambda r: ran.append(int(r)), first_row)
+        return chunk_terms(cfg, rows, tally, ranking, first_row, *args)
+
+    monkeypatch.setattr(moe, "_chunk_terms", noted)
+
+    def system(p, x):
+        out, stats = moe.moe_feed_forward(p, cfg, x[None])
+        return jnp.sum(out[0] * cot), (out[0], stats)
+
+    (_, (out, stats)), g_sys = jax.block_until_ready(
+        jax.jit(jax.value_and_grad(system, argnums=(0, 1), has_aux=True))(params, x))
+    jax.effects_barrier()
+    assert sorted(ran) == sorted(2 * [j * rows for j in range(chunks)])
+    assert float(stats["moe_pairs_here"]) == pairs
+    assert float(stats["moe_overflow_share"]) == (chunks > 1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref.moe(sizes, params, x)), atol=LOGITS_ATOL)
+    g_ref = jax.jit(jax.grad(lambda p, x: jnp.sum(ref.moe(sizes, p, x) * cot), argnums=(0, 1)))(params, x)
+    flat_sys = jax.tree_util.tree_leaves_with_path(g_sys)
+    flat_ref = jax.tree_util.tree_leaves(g_ref)
+    assert len(flat_sys) == len(flat_ref) == 9  # x, the router, three expert and four shared leaves
+    for (path, a), b in zip(flat_sys, flat_ref):
+        scale = float(jnp.abs(b).max())
+        assert scale > 0 or not pairs, f"{jax.tree_util.keystr(path)}: the reference's gradient is all zero"
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=GRAD_RTOL * scale,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("held,tokens,rows", [
+    (16, 128, 384),     # every expert held: every pair has its row, one chunk
+    (16, 10, 128),      # ... padded to the kernel's row tile
+    (4, 128, 256),      # 2 x 96 expected pairs, in whole tiles
+    (4, 8, 24 + 104),   # never more than every pair, padded
+    (1, 1024, 384),     # 2 x 192
+    (1, 4096, 1536),    # 2 x 768
+])
+def test_pair_rows_follow_the_share_of_the_experts_held(held, tokens, rows):
+    cfg = _moe_cfg(moe_experts_held=held)
+    assert moe.pair_rows(cfg, tokens) == rows
+    assert moe.pair_rows(cfg, tokens) % 128 == 0
+    if held == cfg.moe_experts:
+        assert rows == moe._padded_rows(cfg, tokens)  # the one chunk is the whole ranking
 
 
 def test_routing_ties_go_to_the_lower_expert_in_program_and_reference():
@@ -367,7 +457,7 @@ def test_train_step_carries_the_experts_load_beside_the_loss_and_counts_the_laye
         return dalle_mod.forward(p, cfg, b["text"], b["image_codes"], return_loss=True,
                                  return_aux=True)
 
-    names = ("train/gdn_layers", "train/moe_layers", "train/moe_experts_held",
+    names = ("train/gdn_layers", "train/moe_layers", "train/moe_experts_held", "train/moe_pair_rows",
              "train/moe_gmm_fallback_calls", "train/moe_gmm_kernel_calls")
     before = {n: obs_metrics.counter(n).value for n in names}
     init_fn, step_fn = make_train_step(loss_fn, optax.adam(1e-3),
@@ -377,7 +467,9 @@ def test_train_step_carries_the_experts_load_beside_the_loss_and_counts_the_laye
     batch = {"text": jnp.asarray(np.stack([text] * 2 * accum)),
              "image_codes": jnp.asarray(np.stack([codes] * 2 * accum))}
     state, m = step_fn(state, batch, jax.random.PRNGKey(0))
-    assert {"loss", "grad_norm", "moe_pairs_here", "moe_load_max_over_mean"} <= set(m)
+    assert {"loss", "grad_norm", "moe_pairs_here", "moe_load_max_over_mean",
+            "moe_overflow_share"} <= set(m)
+    assert float(m["moe_overflow_share"]) == 0.0
     assert np.isfinite(float(m["loss"])) and int(m["skipped"]) == 0
     # every microbatch is the same two sequences: the mean over them is one microbatch's
     alone = jax.jit(lambda p: dalle_mod.forward(
@@ -387,4 +479,6 @@ def test_train_step_carries_the_experts_load_beside_the_loss_and_counts_the_laye
     grew = {n: obs_metrics.counter(n).value - before[n] for n in names}
     assert grew["train/gdn_layers"] >= 3 and grew["train/moe_layers"] >= 4
     assert grew["train/moe_experts_held"] >= 16
+    # 2 sequences x 24 positions x top-3 = 144 pairs, a quarter expected: one row tile of two
+    assert grew["train/moe_pair_rows"] >= 4 * 128 and grew["train/moe_pair_rows"] % (4 * 128) == 0
     assert grew["train/moe_gmm_fallback_calls"] >= 12 and grew["train/moe_gmm_kernel_calls"] == 0
