@@ -68,7 +68,7 @@ SCRIPTS = {
 COLLECTIVE_RE = re.compile(
     r"\b(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)(-start)?\(")
 
-# the model, at full width (bench.py's dim-2048 row; ROADMAP S0 cell (b))
+# the model, at full width (dim, heads and depth of benchmark/configs/dalle_2048_d8.json)
 FULL = dict(
     image_size=256, vae_layers=3, num_tokens=8192, vae_emb=512, vae_hidden=256,
     n_images=40, batch=8,

@@ -11,7 +11,7 @@ import jax
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its directory.
     Called once at the top of every entry point that jits (the CLIs,
-    bench.py, chip_smoke.py's children, tools/), BEFORE the first compile.
+    benchmark/run.py, chip_smoke.py's children, tools/), BEFORE the first compile.
 
     Where `JAX_COMPILATION_CACHE_DIR` is set, jax reads it itself and this
     sets nothing.  Otherwise the cache lives at ONE fixed path inside the

@@ -7,7 +7,7 @@ Two traffic sources:
   `--outputs_dir/<prompt>/N.png` exactly like generate.py.
 * `--loadgen N`: N synthetic requests under `--streams` Poisson streams at
   `--rate` req/s per stream (tools/loadgen.py) — the SLO bench mode, used
-  by bench.py's `serving` row and the chaos `flood` drill.
+  by the chaos `flood` drill.
 
 Either way the run ends with an SLO report (p50/p99 time-to-first-token,
 p50/p99 request latency, images/sec/chip, refusals) printed and optionally

@@ -1,6 +1,6 @@
 """Published per-chip peaks, keyed by the `device_kind` string JAX reports.
 
-The ONE hardware table: MFU (training/profiling.py, bench.py), the HBM
+The package's ONE hardware table: MFU (training/profiling.py), the HBM
 fits-verdict (observability/memory.py) and the comms roofline
 (observability/comms.py) all price against it.  Sources: Google Cloud TPU
 documentation, the "TPU v4" / "TPU v5e" / "TPU v5p" / "TPU v6e" system

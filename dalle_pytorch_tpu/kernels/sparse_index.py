@@ -188,11 +188,7 @@ def decode_kv_span(pattern: Optional[np.ndarray], n: int) -> int:
     return int(decode_kv_counts(pattern).max())
 
 
-def build_decode_tables(
-    pattern: np.ndarray,
-    *,
-    pad_to: Optional[int] = None,
-) -> Tuple[np.ndarray, np.ndarray]:
+def build_decode_tables(pattern: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """Gather tables for sparse-aware cached decode.
 
     Returns (idx, counts): idx[..., t, :] lists the ascending key positions
@@ -208,9 +204,6 @@ def build_decode_tables(
     heads, n, _ = p.shape
     counts = decode_kv_counts(p)
     kmax = int(counts.max())
-    if pad_to is not None:
-        assert pad_to >= kmax, (pad_to, kmax)
-        kmax = pad_to
     idx = np.zeros((heads, n, kmax), np.int32)
     for h in range(heads):
         for t in range(n):

@@ -109,8 +109,8 @@ def _restore_ring_slots(new_rb, old_rb, slots, a):
     """Restore a shift ring buffer's REJECTED slots from the pre-round
     snapshot.  `slots`: (k+1,) int32 ring slots written this round, in feed
     order; `a`: accepted advance (scalar) — slots i >= a revert to old.  The
-    fmap axis is ndim-3 for every ring layout ((b|S, fmap, 2, q) per-lane or
-    (depth, ..., fmap, 2, q) stacked), so one helper serves all of them."""
+    fmap axis is ndim-3 both for the dense cache's (b, fmap, 2, q) and for
+    one lane's (fmap, 2, q) under `rollback_slot_rings`' vmap."""
     ax = new_rb.ndim - 3
     rb = new_rb
     for i in range(slots.shape[0]):
@@ -129,14 +129,6 @@ def rollback_cache_rings(new_layers, old_layers, slots, a, tcfg):
     every read and rewritten before reuse."""
     if not tcfg.shift_tokens:
         return new_layers
-    if tcfg.scan_layers:
-        return dict(
-            new_layers,
-            shift_attn=_restore_ring_slots(
-                new_layers["shift_attn"], old_layers["shift_attn"], slots, a),
-            shift_ff=_restore_ring_slots(
-                new_layers["shift_ff"], old_layers["shift_ff"], slots, a),
-        )
     return [
         dict(
             nl,
@@ -149,19 +141,11 @@ def rollback_cache_rings(new_layers, old_layers, slots, a, tcfg):
     ]
 
 
-def rollback_slot_rings(new_rings, old_rings, slots, a, tcfg):
+def rollback_slot_rings(new_rings, old_rings, slots, a):
     """Engine (paged) ring rollback: per-lane slots (S, k+1) and per-lane
     advance (S,) — vmapped over the slot axis of init_slot_rings state."""
     if new_rings is None:
         return None
-    if tcfg.scan_layers:
-        fix = jax.vmap(_restore_ring_slots, in_axes=(1, 1, 0, 0), out_axes=1)
-        nl, ol = new_rings["layers"], old_rings["layers"]
-        return {"layers": dict(
-            nl,
-            shift_attn=fix(nl["shift_attn"], ol["shift_attn"], slots, a),
-            shift_ff=fix(nl["shift_ff"], ol["shift_ff"], slots, a),
-        )}
     fix = jax.vmap(_restore_ring_slots, in_axes=(0, 0, 0, 0))
     return {"layers": [
         {"shift_attn": fix(nl["shift_attn"], ol["shift_attn"], slots, a),
@@ -364,7 +348,7 @@ def engine_spec_verify(params, cfg, tcfg, state, draft, *, spec_k: int,
         jnp.mod(jnp.minimum(offsets + i, seq - 1) - text_len, fmap)
         for i in range(k + 1)
     ], axis=1)  # (S, k+1)
-    rings = rollback_slot_rings(rings, state["rings"], slots, a, tcfg)
+    rings = rollback_slot_rings(rings, state["rings"], slots, a)
 
     new_state = dict(
         state,

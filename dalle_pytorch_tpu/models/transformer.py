@@ -617,8 +617,7 @@ def _feed_forward(shared, cfg, x, dkey):
 
 
 @jax.named_scope("attn")
-def _attention_prefill(shared, cfg, layer_cache, x, pattern, rotary, key_mask,
-                       live=None, tables=None):
+def _attention_prefill(shared, cfg, layer_cache, x, pattern, rotary, key_mask):
     """Length-n prefix attention that also fills the KV cache from offset 0.
     Mutates layer_cache['k'/'v'] (caller passes a fresh dict copy)."""
     b, n, _ = x.shape
@@ -641,8 +640,8 @@ def _attention_prefill(shared, cfg, layer_cache, x, pattern, rotary, key_mask,
         km = key_mask[:, :n] if key_mask is not None else None
         out = flash_attention(
             q, k, v, mask=pm, causal=True, scale=cfg.dim_head ** -0.5,
-            key_mask=km, live=live, grid=cfg.attn_grid, tables=tables,
-            vfa=cfg.attn_vfa, mesh=_kernel_mesh(cfg),
+            key_mask=km, grid=cfg.attn_grid, vfa=cfg.attn_vfa,
+            mesh=_kernel_mesh(cfg),
         )
         return linear(shared["out"], _merge_heads(out))
     q = q * (cfg.dim_head ** -0.5)
@@ -680,7 +679,7 @@ def _residual_branch(
     aux: Optional[dict] = None,
 ):
     """THE residual branch — PreShiftToken? -> PreNorm -> attn/ff -> sandwich?
-    -> LayerScale — shared by full-sequence apply, scan-layers, prefill and
+    -> LayerScale — shared by full-sequence apply (unrolled and scanned), prefill and
     single-token cached decode (the reference re-implements this composition
     per wrapper; here every mode runs the one definition).  Returns
     (branch output, updated layer cache or None)."""
@@ -718,9 +717,7 @@ def _residual_branch(
         elif mode == "prefill":
             layer_cache = dict(layer_cache)
             h = _attention_prefill(
-                attn_params, cfg, layer_cache, h, pattern, rotary, key_mask,
-                live=live, tables=tables,
-            )
+                attn_params, cfg, layer_cache, h, pattern, rotary, key_mask)
         else:
             layer_cache = dict(layer_cache)
             h, (layer_cache["k"], layer_cache["v"]) = _attention_cached(
@@ -901,8 +898,6 @@ def _assert_scannable(cfg, specs):
         "mask per layer, and per-head layouts would multiply that memory by "
         "`heads` for every layer — use the unrolled sequential/remat engines"
     )
-    # compared against len(specs), not cfg.depth: the speculative draft/verify
-    # passes scan a contiguous SLICE of the stack
     assert len({s.attn_id for s in specs}) == len(specs) and len({s.ff_id for s in specs}) == len(specs), (
         "scan_layers requires unshared layers (shared_attn_ids/shared_ff_ids unset)"
     )
@@ -910,7 +905,7 @@ def _assert_scannable(cfg, specs):
 
 def _stacked_bundles(params, specs):
     """Per-layer param bundles stacked along a leading depth axis (the
-    lax.scan xs for every scan-layers path: training, prefill, decode)."""
+    lax.scan xs of `_apply_scan`)."""
     bundles = [
         {
             "attn": params["shared_attn"][s.attn_id],
@@ -984,36 +979,9 @@ def _select_flash_tables(tabstk, mi):
     return tuple(jnp.take(tabstk[k], mi, axis=0, mode="clip") for k in TABLE_KEYS)
 
 
-def _stacked_decode_tables(cfg, specs):
-    """Stacked sparse-decode gather tables (idx (D, n, Kmax), counts (D, n))
-    for the scan decode paths, or None when sparse decode doesn't pay: any
-    'full' layer in the stack forces Kmax = seq_len (the scan pads every
-    pattern to the widest gather), which is the dense read it was meant to
-    avoid.  The unrolled decode paths decide per layer instead."""
-    import numpy as np
-
-    if not cfg.sparse_decode:
-        return None
-    distinct = list(dict.fromkeys(_pattern_key(s) for s in specs))
-    pats = [_pattern_for(cfg, t, seed) for t, seed in distinct]
-    if any(p is None for p in pats):
-        return None
-    from dalle_pytorch_tpu.kernels.sparse_index import (
-        build_decode_tables, decode_kv_span,
-    )
-
-    kmax = max(decode_kv_span(p, cfg.seq_len) for p in pats)
-    tabs = [build_decode_tables(p, pad_to=kmax) for p in pats]
-    return (
-        jnp.asarray(np.stack([t[0] for t in tabs])),
-        jnp.asarray(np.stack([t[1] for t in tabs])),
-    )
-
-
 def _decode_tables_by_key(cfg, patterns):
-    """Sparse-decode gather tables per pattern key for the UNROLLED decode
-    paths ('full' layers stay on the dense cache read; pattern layers each
-    get their own minimal Kmax)."""
+    """Sparse-decode gather tables per pattern key ('full' layers stay on the
+    dense cache read; pattern layers each get their own minimal Kmax)."""
     if not cfg.sparse_decode:
         return {}
     from dalle_pytorch_tpu.kernels.sparse_index import build_decode_tables
@@ -1136,28 +1104,23 @@ def _apply_scan(params, cfg, x, key_mask, layer_keys, seq_constraint, specs, rot
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg: TransformerConfig, batch: int, dtype=jnp.float32) -> dict:
-    """Fixed-shape KV cache + token-shift ring buffers; `offset` is the number
-    of positions already consumed.  With cfg.scan_layers the per-layer entries
-    are stacked along a leading depth axis (the scan-layers cached paths scan
-    over them) instead of held in a python list."""
+    """Fixed-shape KV cache + token-shift ring buffers, one dict per layer;
+    `offset` is the number of positions already consumed."""
     refuse_hybrid(cfg, "init_cache")
 
-    def entry(lead=()):
+    def entry():
         e = {
-            "k": jnp.zeros((*lead, batch, cfg.heads, cfg.seq_len, cfg.dim_head), dtype),
-            "v": jnp.zeros((*lead, batch, cfg.heads, cfg.seq_len, cfg.dim_head), dtype),
+            "k": jnp.zeros((batch, cfg.heads, cfg.seq_len, cfg.dim_head), dtype),
+            "v": jnp.zeros((batch, cfg.heads, cfg.seq_len, cfg.dim_head), dtype),
         }
         if cfg.shift_tokens:
             q = cfg.dim // 4
             fmap = cfg.image_fmap_size
-            e["shift_attn"] = jnp.zeros((*lead, batch, fmap, 2, q), dtype)
-            e["shift_ff"] = jnp.zeros((*lead, batch, fmap, 2, q), dtype)
+            e["shift_attn"] = jnp.zeros((batch, fmap, 2, q), dtype)
+            e["shift_ff"] = jnp.zeros((batch, fmap, 2, q), dtype)
         return e
 
-    if cfg.scan_layers:
-        layers = entry(lead=(cfg.depth,))
-    else:
-        layers = [entry() for _ in derive_layer_specs(cfg)]
+    layers = [entry() for _ in derive_layer_specs(cfg)]
     return {"offset": jnp.zeros((), jnp.int32), "layers": layers}
 
 
@@ -1317,75 +1280,6 @@ def _run_cached_layers(cfg: TransformerConfig, specs, x, cache, branch):
     return h, new_layers
 
 
-def _run_cached_scan(params, cfg, specs, x, cache, mode, rotary, key_mask=None,
-                     text_only=False):
-    """Scan-layers version of the cached paths: one lax.scan over stacked
-    params + stacked cache entries, per-layer pattern selected by traced
-    index.  Returns (out, stacked new layer caches)."""
-    import numpy as np
-
-    _assert_scannable(cfg, specs)
-    offset = cache["offset"]
-    masks_np, midx = _stacked_masks(cfg, specs, cfg.seq_len)
-    masks = jnp.asarray(masks_np)
-    stacked = _stacked_bundles(params, specs)
-
-    lives = None
-    tabstk = None
-    if mode == "prefill":
-        # the scan selects a TRACED mask per layer, which defeats the flash
-        # kernel's trace-time liveness derivation — build the stacked tables
-        # at the prefill length, exactly like _apply_scan does for training
-        from dalle_pytorch_tpu.kernels.flash_attention import (
-            DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, resolve_block,
-        )
-
-        n = x.shape[1]
-        try:
-            bq = resolve_block(n, DEFAULT_BLOCK_Q)
-            bk = resolve_block(n, DEFAULT_BLOCK_K)
-            lives = jnp.asarray(np.stack([
-                m[:n, :n].reshape(n // bq, bq, n // bk, bk)
-                .any(axis=(1, 3)).astype(np.int32)
-                for m in masks_np
-            ]))
-            tabstk = _stacked_flash_tables(
-                cfg, [m[:n, :n] for m in masks_np], n, bq, bk, True
-            )
-        except ValueError:  # no valid block: the flash path won't be taken
-            lives = None
-
-    dec_tabs = _stacked_decode_tables(cfg, specs) if mode == "decode" else None
-
-    def body(h, xs):
-        bundle, mi, lc = xs
-        mask = jnp.take(masks, mi, axis=0)
-        live = jnp.take(lives, mi, axis=0, mode="clip") if lives is not None else None
-        tabs = _select_flash_tables(tabstk, mi)
-        dtab = None
-        if dec_tabs is not None:
-            dtab = (
-                jnp.take(dec_tabs[0], mi, axis=0, mode="clip"),
-                jnp.take(dec_tabs[1], mi, axis=0, mode="clip"),
-            )
-        fa, lc = _residual_branch(
-            cfg, bundle["wrap"], bundle["attn"], bundle["ff"], h, "attn",
-            mode=mode, rotary=rotary, pattern=mask, key_mask=key_mask,
-            layer_cache=lc, offset=offset, text_mode=text_only, live=live,
-            tables=tabs, decode_tab=dtab,
-        )
-        h = h + fa
-        fb, lc = _residual_branch(
-            cfg, bundle["wrap"], bundle["attn"], bundle["ff"], h, "ff",
-            mode=mode, rotary=rotary, pattern=mask, key_mask=key_mask,
-            layer_cache=lc, offset=offset, text_mode=text_only, live=live,
-            tables=tabs, decode_tab=dtab,
-        )
-        return h + fb, lc
-
-    return jax.lax.scan(body, x, (stacked, midx, cache["layers"]))
-
-
 def _resolve_layer_range(cfg, specs, layer_start, layer_stop):
     """Validate a [layer_start, layer_stop) slice of the stack (speculative
     drafting runs layers [0, d) then verification continues [d, depth)).
@@ -1430,23 +1324,6 @@ def decode_step(
     rotary = transformer_rotary(cfg)
     offset = cache["offset"]
 
-    if cfg.scan_layers:
-        run_cache = cache
-        if partial:
-            run_cache = dict(cache, layers=jax.tree_util.tree_map(
-                lambda a: a[layer_start:layer_start + len(specs)],
-                cache["layers"]))
-        out, new_layers = _run_cached_scan(
-            params, cfg, specs, x, run_cache, "decode", rotary,
-            text_only=text_only
-        )
-        if partial:
-            new_layers = jax.tree_util.tree_map(
-                lambda full, part:
-                full.at[layer_start:layer_start + len(specs)].set(part),
-                cache["layers"], new_layers)
-        return out, {"offset": offset + 1, "layers": new_layers}
-
     patterns = spec_patterns(cfg, specs)
     dec_tabs = _decode_tables_by_key(cfg, patterns)
 
@@ -1481,12 +1358,6 @@ def prefill(
     n = x.shape[1]
     specs = derive_layer_specs(cfg)
     rotary = transformer_rotary(cfg)
-
-    if cfg.scan_layers:
-        out, new_layers = _run_cached_scan(
-            params, cfg, specs, x, cache, "prefill", rotary, key_mask=key_mask
-        )
-        return out, {"offset": jnp.asarray(n, jnp.int32), "layers": new_layers}
 
     patterns = spec_patterns(cfg, specs)
 
@@ -1579,9 +1450,8 @@ def init_paged_pool(
     quantize: Optional[str] = None,
 ) -> dict:
     """One shared KV block pool: per layer, (num_blocks, heads, block_size,
-    dim_head) k/v arrays (stacked along a leading depth axis under
-    scan_layers, mirroring init_cache).  Block 0 is conventionally reserved
-    by the serving pool as the trash block inactive slots write into.
+    dim_head) k/v arrays.  Block 0 is conventionally reserved by the serving
+    pool as the trash block inactive slots write into.
 
     `quantize="int8"` stores int8 k/v with PER-TOKEN bf16 scales beside the
     blocks (`k_scale`/`v_scale`, block shape minus dim_head) — per-token so
@@ -1591,8 +1461,8 @@ def init_paged_pool(
     refuse_hybrid(cfg, "init_paged_pool")
     from dalle_pytorch_tpu.quantization import KV_SCALE_DTYPE
 
-    def entry(lead=()):
-        shape = (*lead, num_blocks, cfg.heads, block_size, cfg.dim_head)
+    def entry():
+        shape = (num_blocks, cfg.heads, block_size, cfg.dim_head)
         if quantize and quantize != "none":
             sshape = shape[:-1]
             return {
@@ -1603,11 +1473,7 @@ def init_paged_pool(
             }
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
-    if cfg.scan_layers:
-        layers = entry(lead=(cfg.depth,))
-    else:
-        layers = [entry() for _ in range(cfg.depth)]
-    return {"layers": layers}
+    return {"layers": [entry() for _ in range(cfg.depth)]}
 
 
 def init_slot_rings(
@@ -1621,22 +1487,17 @@ def init_slot_rings(
     q = cfg.dim // 4
     fmap = cfg.image_fmap_size
 
-    def entry(lead=()):
+    def entry():
         return {
-            "shift_attn": jnp.zeros((*lead, num_slots, fmap, 2, q), dtype),
-            "shift_ff": jnp.zeros((*lead, num_slots, fmap, 2, q), dtype),
+            "shift_attn": jnp.zeros((num_slots, fmap, 2, q), dtype),
+            "shift_ff": jnp.zeros((num_slots, fmap, 2, q), dtype),
         }
 
-    if cfg.scan_layers:
-        layers = entry(lead=(cfg.depth,))
-    else:
-        layers = [entry() for _ in range(cfg.depth)]
-    return {"layers": layers}
+    return {"layers": [entry() for _ in range(cfg.depth)]}
 
 
 @jax.named_scope("kv_write")
 def write_prefill_to_pool(
-    cfg: TransformerConfig,
     pool: dict,
     block_tables: jnp.ndarray,
     cache_layers,
@@ -1659,23 +1520,20 @@ def write_prefill_to_pool(
     pad = nb * block_size - n_pre
 
     def pack(k):
-        # (..., b, h, seq, dh) -> (..., b, nb, h, block_size, dh)
-        k = k[..., :n_pre, :]
+        # (b, h, seq, dh) -> (b, nb, h, block_size, dh)
+        k = k[:, :, :n_pre]
         if pad:
-            padw = [(0, 0)] * (k.ndim - 2) + [(0, pad), (0, 0)]
-            k = jnp.pad(k, padw)
-        *lead, b, h, _, dh = k.shape
-        k = k.reshape(*lead, b, h, nb, block_size, dh)
-        return jnp.swapaxes(k, -4, -3)
+            k = jnp.pad(k, [(0, 0), (0, 0), (0, pad), (0, 0)])
+        b, h, _, dh = k.shape
+        return jnp.swapaxes(k.reshape(b, h, nb, block_size, dh), 1, 2)
 
     def pack_scale(s):
-        # (..., b, h, seq) -> (..., b, nb, h, block_size)
-        s = s[..., :n_pre]
+        # (b, h, seq) -> (b, nb, h, block_size)
+        s = s[:, :, :n_pre]
         if pad:
-            s = jnp.pad(s, [(0, 0)] * (s.ndim - 1) + [(0, pad)])
-        *lead, b, h, _ = s.shape
-        s = s.reshape(*lead, b, h, nb, block_size)
-        return jnp.swapaxes(s, -3, -2)
+            s = jnp.pad(s, [(0, 0), (0, 0), (0, pad)])
+        b, h, _ = s.shape
+        return jnp.swapaxes(s.reshape(b, h, nb, block_size), 1, 2)
 
     def packed_kv(lp, lc):
         """(k, v[, k_scale, v_scale]) in pool layout for one layer."""
@@ -1690,14 +1548,6 @@ def write_prefill_to_pool(
         return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
 
     tbl = block_tables[:, :nb]
-    if cfg.scan_layers:
-        lp = pool["layers"]
-        pk = packed_kv(lp, cache_layers)
-        new_layers = dict(lp, **{
-            name: lp[name].at[(slice(None), tbl)].set(arr.astype(lp[name].dtype))
-            for name, arr in pk.items()
-        })
-        return {"layers": new_layers}
     new_layers = []
     for lp, lc in zip(pool["layers"], cache_layers):
         pk = packed_kv(lp, lc)
@@ -1795,13 +1645,12 @@ def _use_paged_kernel(cfg, layer_pool, pattern, block_size: int) -> bool:
         cfg.dim_head, block_size, layer_pool["k"].dtype)
 
 
-def _note_paged_path(path_tally: Optional[Dict[str, int]], use_kernel: bool,
-                     layers: int = 1) -> None:
+def _note_paged_path(path_tally: Optional[Dict[str, int]], use_kernel: bool) -> None:
     """Trace-time count, into the caller's dict, of the attention layers that
     took the kernel and of those that fell back (see `paged_decode_step`)."""
     if path_tally is not None:
         key = "kernel" if use_kernel else "fallback"
-        path_tally[key] = path_tally.get(key, 0) + layers
+        path_tally[key] = path_tally.get(key, 0) + 1
 
 
 @jax.named_scope("attn")
@@ -1911,29 +1760,6 @@ def paged_decode_step(
         f"{block_tables.shape[1]} x {block_size} < {cfg.seq_len}"
     )
 
-    if cfg.scan_layers:
-        run_pool, run_rings = pool, rings
-        if partial:
-            sl = slice(layer_start, layer_start + len(specs))
-            run_pool = {"layers": jax.tree_util.tree_map(
-                lambda a: a[sl], pool["layers"])}
-            if rings is not None:
-                run_rings = {"layers": jax.tree_util.tree_map(
-                    lambda a: a[sl], rings["layers"])}
-        out, new_pool, new_rings = _paged_decode_scan(
-            params, cfg, specs, x, run_pool, block_tables, offsets, run_rings,
-            block_size, rotary, path_tally,
-        )
-        if partial:
-            new_pool = {"layers": jax.tree_util.tree_map(
-                lambda full, part: full.at[sl].set(part),
-                pool["layers"], new_pool["layers"])}
-            if rings is not None:
-                new_rings = {"layers": jax.tree_util.tree_map(
-                    lambda full, part: full.at[sl].set(part),
-                    rings["layers"], new_rings["layers"])}
-        return out, new_pool, new_rings
-
     patterns = spec_patterns(cfg, specs)
     use_kernel = {}
     for spec in specs:
@@ -2009,53 +1835,3 @@ def paged_decode_step(
             new_ring_layers = merged_rings
     new_rings = {"layers": new_ring_layers} if cfg.shift_tokens else None
     return out, {"layers": new_pool_layers}, new_rings
-
-
-def _paged_decode_scan(params, cfg, specs, x, pool, block_tables, offsets,
-                       rings, block_size, rotary, path_tally=None):
-    """scan_layers paged decode: one lax.scan over stacked params + stacked
-    pool blocks (+ stacked rings), per-layer pattern selected by traced
-    index — the paged mirror of `_run_cached_scan(mode='decode')`."""
-    _assert_scannable(cfg, specs)
-    masks_np, midx = _stacked_masks(cfg, specs, cfg.seq_len)
-    masks = jnp.asarray(masks_np)
-    stacked = _stacked_bundles(params, specs)
-    use_kernel = _use_paged_kernel(cfg, pool["layers"], masks_np[0], block_size)
-    _note_paged_path(path_tally, use_kernel, len(specs))
-    dec_tabs = None if use_kernel else _stacked_decode_tables(cfg, specs)
-
-    def body(h, xs):
-        if cfg.shift_tokens:
-            bundle, mi, lp, ring_layer = xs
-        else:
-            bundle, mi, lp = xs
-            ring_layer = None
-        mask = jnp.take(masks, mi, axis=0)
-        dtab = None
-        if dec_tabs is not None:
-            dtab = (
-                jnp.take(dec_tabs[0], mi, axis=0, mode="clip"),
-                jnp.take(dec_tabs[1], mi, axis=0, mode="clip"),
-            )
-        r_attn = ring_layer["shift_attn"] if cfg.shift_tokens else None
-        fa, r_attn, lp = _paged_branch(
-            cfg, bundle["wrap"], bundle["attn"], bundle["ff"], h, "attn",
-            lp, block_tables, offsets, r_attn, mask, rotary, block_size,
-            decode_tab=dtab, use_kernel=use_kernel,
-        )
-        h = h + fa
-        r_ff = ring_layer["shift_ff"] if cfg.shift_tokens else None
-        fb, r_ff, _ = _paged_branch(
-            cfg, bundle["wrap"], bundle["attn"], bundle["ff"], h, "ff",
-            lp, block_tables, offsets, r_ff, mask, rotary, block_size,
-        )
-        ys = (lp, {"shift_attn": r_attn, "shift_ff": r_ff}) if cfg.shift_tokens else lp
-        return h + fb, ys
-
-    if cfg.shift_tokens:
-        xs = (stacked, midx, pool["layers"], rings["layers"])
-        out, (new_pool_layers, new_ring_layers) = jax.lax.scan(body, x, xs)
-        return out, {"layers": new_pool_layers}, {"layers": new_ring_layers}
-    xs = (stacked, midx, pool["layers"])
-    out, new_pool_layers = jax.lax.scan(body, x, xs)
-    return out, {"layers": new_pool_layers}, None

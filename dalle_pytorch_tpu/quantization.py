@@ -275,8 +275,6 @@ def quantize_cache_layers(layers: Any) -> Any:
         vq, vs = quantize_kv(e["v"])
         return dict(e, k=kq, v=vq, k_scale=ks, v_scale=vs)
 
-    if isinstance(layers, dict):
-        return qentry(layers)
     return [qentry(e) for e in layers]
 
 
@@ -421,24 +419,15 @@ def paged_greedy_logits(params: dict, cfg: Any, text,
     pool = tr.init_paged_pool(tcfg, bps + 1, block_size, dt,
                               quantize=quantize_kv_mode)
     bt = jnp.arange(1, bps + 1, dtype=jnp.int32)[None]
-    pool = tr.write_prefill_to_pool(tcfg, pool, bt, cache["layers"],
+    pool = tr.write_prefill_to_pool(pool, bt, cache["layers"],
                                     n_pre, block_size)
     rings = tr.init_slot_rings(tcfg, 1, dt)
     if rings is not None:
-        cl = cache["layers"]
-        if tcfg.scan_layers:
-            rl = rings["layers"]
-            rings = {"layers": dict(
-                rl,
-                shift_attn=cl["shift_attn"].astype(rl["shift_attn"].dtype),
-                shift_ff=cl["shift_ff"].astype(rl["shift_ff"].dtype),
-            )}
-        else:
-            rings = {"layers": [
-                {"shift_attn": c["shift_attn"].astype(r["shift_attn"].dtype),
-                 "shift_ff": c["shift_ff"].astype(r["shift_ff"].dtype)}
-                for r, c in zip(rings["layers"], cl)
-            ]}
+        rings = {"layers": [
+            {"shift_attn": c["shift_attn"].astype(r["shift_attn"].dtype),
+             "shift_ff": c["shift_ff"].astype(r["shift_ff"].dtype)}
+            for r, c in zip(rings["layers"], cache["layers"])
+        ]}
 
     def step(pool, rings, code, offset, img_prev):
         e = jnp.take(dalle_mod._image_table(params, cfg), code[:, None],

@@ -399,33 +399,22 @@ class GenerationEngine:
         payload — shared verbatim by the fused admit jit and the
         disaggregated ingest jit, which is what makes the two paths
         bit-identical."""
-        tcfg = self.tcfg
         pool = write_prefill_to_pool(
-            tcfg, state["pool"], bt_rows, cache_layers,
+            state["pool"], bt_rows, cache_layers,
             self.n_pre, self.ecfg.block_size,
         )
         rings = state["rings"]
         if rings is not None:
             with jax.named_scope("token_shift"):
-                if tcfg.scan_layers:
-                    rl, cl = rings["layers"], cache_layers
-                    rings = {"layers": dict(
-                        rl,
-                        shift_attn=rl["shift_attn"].at[:, lane_idx].set(
+                new_layers = []
+                for rl, cl in zip(rings["layers"], cache_layers):
+                    new_layers.append({
+                        "shift_attn": rl["shift_attn"].at[lane_idx].set(
                             cl["shift_attn"].astype(rl["shift_attn"].dtype)),
-                        shift_ff=rl["shift_ff"].at[:, lane_idx].set(
+                        "shift_ff": rl["shift_ff"].at[lane_idx].set(
                             cl["shift_ff"].astype(rl["shift_ff"].dtype)),
-                    )}
-                else:
-                    new_layers = []
-                    for rl, cl in zip(rings["layers"], cache_layers):
-                        new_layers.append({
-                            "shift_attn": rl["shift_attn"].at[lane_idx].set(
-                                cl["shift_attn"].astype(rl["shift_attn"].dtype)),
-                            "shift_ff": rl["shift_ff"].at[lane_idx].set(
-                                cl["shift_ff"].astype(rl["shift_ff"].dtype)),
-                        })
-                    rings = {"layers": new_layers}
+                    })
+                rings = {"layers": new_layers}
 
         with jax.named_scope("codes_write"):
             codeb = jnp.broadcast_to(code, (lanes,))

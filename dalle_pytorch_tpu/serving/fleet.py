@@ -9,7 +9,7 @@ The PR 7 engine is one process on one mesh; this module multiplies it:
   depth, free slots, free pool blocks, HBM headroom) and turns
   every-replica-refused into a counted router-level shed.  The fleet
   quacks like one engine (submit/poll/busy/run_until_idle), so
-  tools/loadgen.py, cli/serve.py, and bench.py drive it unchanged.
+  tools/loadgen.py and cli/serve.py drive it unchanged.
 * **Disaggregation** — `PrefillWorker` runs the prefill half of admission
   (`engine.prefill_sample`, the identical traced graph) on its OWN params —
   optionally placed on a different mesh through the PR 6 registry

@@ -92,7 +92,7 @@ def test_format_version_stamped_and_checked(tmp_path):
     with open(legacy, "wb") as f:
         np.savez(f, **legacy_payload)
     # legacy formats unpickle their treedefs — loading them now requires the
-    # explicit trusted-source opt-in (format-downgrade hole, ADVICE.md)
+    # explicit trusted-source opt-in (format-downgrade hole)
     with pytest.raises(ValueError, match="allow_legacy_pickle"):
         load_checkpoint(str(legacy))
     loaded, meta = load_checkpoint(str(legacy), allow_legacy_pickle=True)

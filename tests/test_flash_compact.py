@@ -312,8 +312,6 @@ def _decode_roll(cfg, params, x_prefix, n_steps):
 @pytest.mark.parametrize("kw", [
     dict(attn_types=("axial_row", "conv_like")),
     dict(attn_types=("sparse",), sparse_per_head=True),
-    # scan_layers sparse decode is covered end-to-end by test_sampling's
-    # scan greedy-oracle case (sparse_decode defaults on) — not repeated here
 ])
 def test_sparse_decode_matches_full_cache(kw):
     """Sparse-aware decode gathers only the pattern-permitted keys.  The
@@ -338,8 +336,8 @@ def test_seq4096_axial_tile_ratio():
     """At 64x64 fmaps (seq 4096 image side) the compacted grid runs >= 4x
     fewer tiles than the dense causal grid for axial patterns — the static
     tile counts ARE the speedup model (each live tile costs the same MXU
-    work), so the ratio is asserted here on CPU and measured as step time by
-    bench.py's sparse_attention rows on TPU."""
+    work), so the ratio is asserted here on CPU; no cell times it on the chip yet
+    (ROADMAP S6, `train_fmap64`)."""
     n = 4096
     cfg = _tcfg(seq_len=n, image_fmap_size=64)
     # 128x128 tiles: a query block spans 2 image rows, so axial_row's live

@@ -160,8 +160,6 @@ def test_handoff_priced_as_comms_row(base):
         a = np.asarray(a)
         return a.itemsize * a.size // a.shape[-2] * live_len
 
-    if isinstance(layers, dict):  # scan_layers: stacked leading depth axis
-        layers = [layers]
     for layer in layers:
         for name in ("k", "v"):
             a = np.asarray(layer[name])

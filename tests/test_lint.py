@@ -91,3 +91,22 @@ def test_lint_cli_runs_clean(capsys):
 
     assert main(["--root", str(REPO)]) == 0
     assert "clean" in capsys.readouterr().out
+
+
+def test_decode_side_does_not_know_scan_layers():
+    """`scan_layers` is a property of the training forward
+    (`transformer.apply_transformer` / `_apply_scan`).  The cache and pool
+    format is `models/transformer.py`'s alone and does not depend on it, so
+    no module that serves, samples or quantizes may name the flag."""
+    pkg = REPO / "dalle_pytorch_tpu"
+    decode_side = sorted((pkg / "serving").glob("*.py")) + [
+        pkg / "models" / "speculative.py", pkg / "models" / "sampling.py",
+        pkg / "quantization.py", pkg / "api.py",
+        pkg / "cli" / "serve.py", pkg / "cli" / "generate.py",
+    ]
+    assert len(decode_side) > 6
+    naming = [str(p.relative_to(REPO)) for p in decode_side if "scan_layers" in p.read_text()]
+    assert not naming, f"decode-side modules that name scan_layers: {naming}"
+    # ... and inside transformer.py nothing from init_cache down reads it
+    src = (pkg / "models" / "transformer.py").read_text()
+    assert "scan_layers" not in src[src.index("def init_cache("):]

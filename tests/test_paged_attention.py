@@ -45,7 +45,7 @@ def _cfg(**kw):
 def _pattern(cfg, kind):
     if kind == "none":
         return None
-    if kind == "full":  # what the scan path hands a 'full' layer
+    if kind == "full":  # a pattern that permits every key
         return np.ones((cfg.seq_len, cfg.seq_len), bool)
     return tr._pattern_for(cfg, kind)
 
@@ -178,8 +178,8 @@ def _model_step(cfg, bs, seed=0, **range_kw):
 
 @pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scan_layers"])
 def test_model_step_matches_gather_path(scan):
-    """Three layers (full, axial_row, conv_like) with token shift: unrolled
-    per-layer pools, and the scan's stacked pool with a traced pattern."""
+    """Three layers (full, axial_row, conv_like) with token shift, per-layer
+    pools: `scan_layers` (a property of the training forward) changes nothing."""
     cfg = _cfg(depth=3, attn_types=("full", "axial_row", "conv_like"),
                shift_tokens=True, scan_layers=scan)
     (out, pool, rings), (w_out, w_pool, w_rings), paths, fell, _ = _model_step(cfg, 8)
@@ -189,9 +189,7 @@ def test_model_step_matches_gather_path(scan):
     np.testing.assert_allclose(out[:4], w_out[:4], rtol=0, atol=3 * TOL)
     # layer 0's column comes from the same input; deeper layers' columns are
     # projections of hidden states that differ by round-off
-    k0 = pool["layers"]["k"][0] if scan else pool["layers"][0]["k"]
-    w0 = w_pool["layers"]["k"][0] if scan else w_pool["layers"][0]["k"]
-    np.testing.assert_array_equal(k0[1:], w0[1:])
+    np.testing.assert_array_equal(pool["layers"][0]["k"][1:], w_pool["layers"][0]["k"][1:])
     for g, w in zip(jax.tree_util.tree_leaves(pool), jax.tree_util.tree_leaves(w_pool)):
         np.testing.assert_allclose(g[..., 1:, :, :, :], w[..., 1:, :, :, :], rtol=0, atol=3 * TOL)
     for g, w in zip(jax.tree_util.tree_leaves(rings), jax.tree_util.tree_leaves(w_rings)):
@@ -208,14 +206,39 @@ def test_layer_range_leaves_other_layers_alone(scan):
         cfg, 8, layer_start=1, layer_stop=3)
     assert paths == {"kernel": 2, "fallback": 0}
     np.testing.assert_allclose(out[:4], w_out[:4], rtol=0, atol=3 * TOL)
-    if scan:
-        first, first_in = pool["layers"]["k"][0], pool_in["layers"]["k"][0]
-        second, w_second = pool["layers"]["k"][1], w_pool["layers"]["k"][1]
-    else:
-        first, first_in = pool["layers"][0]["k"], pool_in["layers"][0]["k"]
-        second, w_second = pool["layers"][1]["k"], w_pool["layers"][1]["k"]
+    first, first_in = pool["layers"][0]["k"], pool_in["layers"][0]["k"]
+    second, w_second = pool["layers"][1]["k"], w_pool["layers"][1]["k"]
     np.testing.assert_array_equal(first, first_in)
     np.testing.assert_array_equal(second[1:], w_second[1:])  # same input: same column
+
+
+@pytest.mark.parametrize("step", ["paged_decode_step", "decode_step"])
+def test_decode_program_ignores_scan_layers(step):
+    """A `scan_layers=True` config decodes through the program the `False`
+    config decodes through: the same jaxpr, so no `scan` over depth and no
+    layer weights stacked inside the decode program."""
+    kw = dict(depth=3, attn_types=("full", "axial_row", "conv_like"), shift_tokens=True)
+
+    def program(cfg):
+        params = jax.eval_shape(lambda: tr.init_transformer(jax.random.PRNGKey(0), cfg))
+        x = jax.ShapeDtypeStruct((2, 1, cfg.dim), jnp.float32)
+        if step == "decode_step":
+            cache = jax.eval_shape(lambda: tr.init_cache(cfg, 2))
+            return jax.make_jaxpr(lambda p, x, c: tr.decode_step(p, cfg, x, c))(params, x, cache)
+        nblk = tr.paged_blocks_per_seq(cfg, 8)
+        pool = jax.eval_shape(lambda: tr.init_paged_pool(cfg, 2 * nblk + 1, 8))
+        rings = jax.eval_shape(lambda: tr.init_slot_rings(cfg, 2))
+        tables = jax.ShapeDtypeStruct((2, nblk), jnp.int32)
+        offsets = jax.ShapeDtypeStruct((2,), jnp.int32)
+        return jax.make_jaxpr(
+            lambda p, x, pool, t, o, r: tr.paged_decode_step(p, cfg, x, pool, t, o, r, 8)
+        )(params, x, pool, tables, offsets, rings)
+
+    loop, scan = program(_cfg(**kw)), program(_cfg(scan_layers=True, **kw))
+    assert str(scan) == str(loop)
+    assert not any(e.primitive.name == "scan" for e in scan.jaxpr.eqns)
+    if step == "paged_decode_step":  # eligible shapes: every layer calls the kernel in place
+        assert str(scan).count("paged_decode_attn") == 3
 
 
 # ---------------------------------------------------------------------------
@@ -234,7 +257,7 @@ def _dense_and_paged(cfg, bs, steps=2, seed=0):
     nblk = tr.paged_blocks_per_seq(cfg, bs)
     pool = tr.init_paged_pool(cfg, nblk + 1, bs)
     tables = jnp.asarray(r.permutation(np.arange(1, nblk + 1)), jnp.int32)[None]
-    pool = tr.write_prefill_to_pool(cfg, pool, tables, cache["layers"], n_pre, bs)
+    pool = tr.write_prefill_to_pool(pool, tables, cache["layers"], n_pre, bs)
     outs, paths = [], {"kernel": 0, "fallback": 0}
     for t in range(steps):
         x = jnp.asarray(r.randn(1, 1, cfg.dim), jnp.float32)

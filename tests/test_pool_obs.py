@@ -373,17 +373,3 @@ def test_fleet_poisson_trace_validates(base, tmp_path):
     assert sum(r["admitted"] for r in val["pools"].values()) == 6
     for rid, row in val["pools"].items():
         assert row["high_water"] == row["recorded_high_water"] == hw[int(rid)]
-
-
-# ---------------------------------------------------------------------------
-# bench gate wiring
-# ---------------------------------------------------------------------------
-
-
-def test_bench_gates_pool_overhead():
-    """The recorder-overhead row is gated: overhead_frac is a lower-is-
-    better metric with a hard 1.0 ceiling."""
-    import bench
-
-    assert bench.GATE_SPECS["pool_observability.overhead_frac"] == (
-        "lower", 1.0)

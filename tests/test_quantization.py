@@ -136,12 +136,12 @@ def test_fused_equals_disaggregated_pool_writes(base):
 
     pool_a = tr.init_paged_pool(tcfg, bps + 1, block_size, jnp.float32,
                                 quantize="int8")
-    pool_a = tr.write_prefill_to_pool(tcfg, pool_a, bt, cache["layers"],
+    pool_a = tr.write_prefill_to_pool(pool_a, bt, cache["layers"],
                                       n_pre, block_size)
     pool_b = tr.init_paged_pool(tcfg, bps + 1, block_size, jnp.float32,
                                 quantize="int8")
     qlayers = quant.quantize_cache_layers(cache["layers"])
-    pool_b = tr.write_prefill_to_pool(tcfg, pool_b, bt, qlayers,
+    pool_b = tr.write_prefill_to_pool(pool_b, bt, qlayers,
                                       n_pre, block_size)
 
     la, lb = pool_a["layers"], pool_b["layers"]

@@ -64,7 +64,7 @@ def greedy_oracle(params, cfg, text):
         # PRODUCING position (dalle_pytorch.py:646-652); a text/image length
         # imbalance catches off-by-one row selection the square case hides
         dict(text_seq_len=12, image_fmap_size=3, num_image_tokens=24),
-        # scan-layers cached decode: stacked caches + traced mask select
+        # a scan_layers config: decoded through the same per-layer caches
         pytest.param(
             dict(scan_layers=True,
                  attn_types=("full", "axial_row", "conv_like")),
@@ -118,8 +118,8 @@ def test_priming_preserves_primer():
 
 
 def test_primed_greedy_matches_oracle_scan_layers():
-    """Priming under scan-layers: the stacked-cache prefill must fill the
-    shift ring buffers identically to the per-layer loop."""
+    """Priming under a `scan_layers` config: the same per-layer prefill, so
+    the shift ring buffers fill identically."""
     cfg = tiny_cfg(scan_layers=True)
     cfg_loop = tiny_cfg()
     params, text = setup(cfg)
@@ -306,11 +306,11 @@ def test_greedy_sampling_flash_prefill_matches_oracle():
 
 @pytest.mark.slow  # tier-1 budget: flash prefill stays fast via
 #                    test_greedy_sampling_flash_prefill_matches_oracle; this
-#                    leg adds the scan-layers stacked-liveness-table variant
+#                    leg adds a `scan_layers` config (sampled unrolled)
 def test_greedy_sampling_flash_prefill_scan_layers_matches_oracle():
-    """scan_layers + flash prefill: the traced per-layer mask comes with a
-    stacked tile-liveness table (dead pattern tiles stay skipped in the
-    prefill kernel) and cached sampling still matches the oracle."""
+    """scan_layers + flash prefill: the config prefills through the unrolled
+    loop (dead pattern tiles skipped from each layer's static mask) and
+    cached sampling still matches the oracle."""
     cfg = tiny_cfg(
         text_seq_len=127, image_fmap_size=4, num_image_tokens=32,
         attn_kernel="flash", scan_layers=True,

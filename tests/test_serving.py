@@ -93,8 +93,8 @@ def test_paged_parity_guided_cfg_lanes(base):
 
 
 def test_paged_parity_scan_layers():
-    """scan_layers: stacked pool blocks + traced per-layer masks through the
-    one lax.scan paged decode."""
+    """A model trained with `scan_layers` is served unrolled, through the
+    per-layer pool, and still delivers the fused sampler's codes."""
     cfg = tiny_cfg(scan_layers=True, attn_types=("full", "axial_row"))
     params = dalle_mod.init_dalle(jax.random.PRNGKey(0), cfg)
     text = np.asarray(jax.random.randint(
@@ -104,6 +104,35 @@ def test_paged_parity_scan_layers():
                            engine_cfg=EngineConfig(num_slots=2, block_size=4))
     (req,) = eng.generate(text, keys=[key])
     np.testing.assert_array_equal(req.codes[None], fused_ref(params, cfg, text[0], key))
+
+
+@pytest.mark.parametrize("kw,bs,want", [
+    (dict(), 4, (0, 2)),
+    (dict(dim=256, dim_head=128), 8, (2, 0)),
+], ids=["gather_path", "kernel_path"])
+def test_engine_ignores_scan_layers(kw, bs, want):
+    """An engine built from a `scan_layers=True` config (what a checkpoint
+    trained with `--scan_layers` hands `cli/common.py`) is the engine built
+    from the `False` one: the same delivered codes, a guided lane pair
+    included, and the same count of layers on the kernel and on the gather."""
+    cfgs = [tiny_cfg(attn_types=("full", "axial_row"), scan_layers=scan, **kw)
+            for scan in (False, True)]
+    params = dalle_mod.init_dalle(jax.random.PRNGKey(0), cfgs[0])
+    text = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(1), (2, cfgs[0].text_seq_len), 1, cfgs[0].num_text_tokens))
+    keys = [jax.random.PRNGKey(40 + i) for i in range(2)]
+    codes, paths = [], []
+    for cfg in cfgs:
+        eng = GenerationEngine(params, cfg,
+                               engine_cfg=EngineConfig(num_slots=4, block_size=bs))
+        plain = eng.generate(text, keys=keys)
+        guided = eng.generate(text, keys=keys, cond_scale=2.0)
+        codes.append([np.asarray(r.codes) for r in plain + guided])
+        paths.append(eng.paged_path_state())
+    for got, ref in zip(codes[1], codes[0]):
+        np.testing.assert_array_equal(got, ref)
+    assert paths[1] == paths[0] == {"paged_attn_kernel_layers": want[0],
+                                    "paged_attn_fallback_layers": want[1]}
 
 
 @pytest.mark.slow

@@ -1,6 +1,5 @@
 """Serving observability (PR 11): request lifecycle traces, windowed SLO
-burn-rate alarms, decode-loop phase attribution, and the bench regression
-gate.
+burn-rate alarms and decode-loop phase attribution.
 
 The contract under test: every request that enters the engine leaves a
 `kind:"request"` record whose phases sum to its latency, whatever its
@@ -447,95 +446,3 @@ def test_write_status_json_atomic(tmp_path):
     write_status_json(str(p), {"a": 2})
     assert json.loads(p.read_text()) == {"a": 2}
     assert not list(p.parent.glob(".*tmp"))
-
-
-# --------------------------------------------------------------------------
-# bench regression gate
-
-
-def _bench_result(**over):
-    out = {
-        "metric": "img-tokens/sec/chip (CPU smoke)",
-        "backend": "cpu",
-        "proxy_dim2048_depth8": {"img_tok_per_sec": 5000.0, "mfu": 0.0002},
-        "serving": {"ttft_p99_s": 2.0, "latency_p99_s": 4.0,
-                    "queue_wait_p99_s": 0.2,
-                    "images_per_sec_per_chip": 0.8},
-        "health_overhead": {"overhead_frac": 0.3},
-        "gen_seconds_per_image": None,
-    }
-    for k, v in over.items():
-        d, key = k.rsplit(".", 1) if "." in k else (None, k)
-        (out[d] if d else out)[key] = v
-    return out
-
-
-def test_bench_gate_exit_codes(tmp_path):
-    """--gate against a baseline built from the same numbers exits 0; a 2x
-    TTFT regression exits nonzero; improvements merge best-of."""
-    import bench
-
-    baseline = tmp_path / "BENCH_BASELINE.json"
-    cand = tmp_path / "cand.json"
-    cand.write_text("ledger noise line\n" + json.dumps(_bench_result()) + "\n")
-
-    args = ["--candidate", str(cand), "--baseline", str(baseline)]
-    assert bench.main(args + ["--gate", "--update_baseline"]) == 0
-    assert bench.main(args + ["--gate"]) == 0  # self-compare: clean
-
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps(_bench_result(**{"serving.ttft_p99_s": 4.0})))
-    assert bench.main(["--candidate", str(bad), "--baseline", str(baseline),
-                       "--gate"]) == 1
-
-    # an improvement passes the gate and becomes the new best-known number
-    good = tmp_path / "good.json"
-    good.write_text(json.dumps(_bench_result(**{"serving.ttft_p99_s": 1.0})))
-    assert bench.main(["--candidate", str(good), "--baseline", str(baseline),
-                       "--gate", "--update_baseline"]) == 0
-    doc = json.loads(baseline.read_text())
-    assert doc["cpu"]["metrics"]["serving.ttft_p99_s"] == 1.0
-    # ...and a later worse-but-in-tolerance run never regresses the baseline
-    ok = tmp_path / "ok.json"
-    ok.write_text(json.dumps(_bench_result(**{"serving.ttft_p99_s": 1.4})))
-    assert bench.main(["--candidate", str(ok), "--baseline", str(baseline),
-                       "--gate", "--update_baseline"]) == 0
-    doc = json.loads(baseline.read_text())
-    assert doc["cpu"]["metrics"]["serving.ttft_p99_s"] == 1.0
-
-
-def test_bench_gate_backend_keyed(tmp_path):
-    """A degraded CPU rerun neither gates against nor clobbers TPU numbers."""
-    import bench
-
-    baseline = tmp_path / "b.json"
-    baseline.write_text(json.dumps({
-        "tpu": {"metrics": {"flagship_1p3b_depth64.mfu": 0.45}}}))
-    cand = tmp_path / "c.json"
-    cand.write_text(json.dumps(_bench_result()))
-    assert bench.main(["--candidate", str(cand), "--baseline", str(baseline),
-                       "--gate", "--update_baseline"]) == 0
-    doc = json.loads(baseline.read_text())
-    assert doc["tpu"]["metrics"]["flagship_1p3b_depth64.mfu"] == 0.45
-    assert "serving.ttft_p99_s" in doc["cpu"]["metrics"]
-
-
-def test_bench_gate_compare_directions():
-    from bench import gate_compare
-
-    cand = _bench_result(**{"serving.ttft_p99_s": 2.9,
-                            "proxy_dim2048_depth8.img_tok_per_sec": 2600.0})
-    basemetrics = {"serving.ttft_p99_s": 2.0,
-                   "proxy_dim2048_depth8.img_tok_per_sec": 5000.0,
-                   "flagship_1p3b_depth64.mfu": 0.45}  # absent in cand: skip
-    cmp = gate_compare(cand, basemetrics)
-    by = {r["metric"]: r for r in cmp["checked"]}
-    assert set(by) == {"serving.ttft_p99_s",
-                      "proxy_dim2048_depth8.img_tok_per_sec"}
-    # 1.45x slower TTFT is inside the 0.5 tolerance; a 48% throughput drop
-    # is past its 50%... not quite — 2600/5000 = 0.52 survives at tol 0.5
-    assert cmp["regressions"] == []
-    cmp = gate_compare(_bench_result(**{
-        "proxy_dim2048_depth8.img_tok_per_sec": 2400.0}), basemetrics)
-    assert [r["metric"] for r in cmp["regressions"]] == [
-        "proxy_dim2048_depth8.img_tok_per_sec"]

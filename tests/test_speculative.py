@@ -82,8 +82,8 @@ def test_fused_spec_parity_guided(base):
 
 
 def test_fused_spec_parity_scan_layers_and_draft_depth():
-    """scan_layers stacks the layer params; the drafter slices the stacked
-    leaves.  A non-default boundary (d=2 of 3) stays exact; the d sweep
+    """A `scan_layers` config decodes through the per-layer cache like any
+    other.  A non-default boundary (d=2 of 3) stays exact; the d sweep
     lives in the slow matrix."""
     cfg = tiny_cfg(depth=3, scan_layers=True)
     params = dalle_mod.init_dalle(jax.random.PRNGKey(2), cfg)

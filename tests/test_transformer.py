@@ -9,6 +9,8 @@ from dalle_pytorch_tpu.models.transformer import (
     decode_step,
     derive_layer_specs,
     init_cache,
+    init_paged_pool,
+    init_slot_rings,
     init_transformer,
     prefill,
 )
@@ -244,6 +246,24 @@ def test_scan_layers_rejects_sharing():
     x = jax.random.normal(jax.random.PRNGKey(1), (1, cfg.seq_len, cfg.dim))
     with pytest.raises(AssertionError, match="unshared"):
         apply_transformer(params, cfg, x)
+
+
+@pytest.mark.parametrize("build", [
+    lambda cfg: init_cache(cfg, batch=2),
+    lambda cfg: init_paged_pool(cfg, num_blocks=7, block_size=4),
+    lambda cfg: init_paged_pool(cfg, num_blocks=7, block_size=4, quantize="int8"),
+    lambda cfg: init_slot_rings(cfg, num_slots=3),
+], ids=["init_cache", "init_paged_pool", "init_paged_pool_int8", "init_slot_rings"])
+def test_decode_state_layout_ignores_scan_layers(build):
+    """`scan_layers` says how the TRAINING forward is compiled.  The decode
+    state has one format, a list of per-layer dicts, whatever it says: the
+    same tree and the same leaf shapes and dtypes."""
+    kw = dict(attn_types=("full", "axial_row", "conv_like"), depth=3, shift_tokens=True)
+    loop, scan = build(cfg_for(**kw)), build(cfg_for(scan_layers=True, **kw))
+    assert isinstance(scan["layers"], list) and len(scan["layers"]) == 3
+    assert jax.tree_util.tree_structure(scan) == jax.tree_util.tree_structure(loop)
+    assert ([(a.shape, a.dtype) for a in jax.tree_util.tree_leaves(scan)]
+            == [(a.shape, a.dtype) for a in jax.tree_util.tree_leaves(loop)])
 
 
 def test_sparse_layouts_differ_per_layer_and_share_with_ids():

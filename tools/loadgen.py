@@ -19,7 +19,7 @@ finishing second is not a second sample).  On a single engine with no
 chaos, every journey is one hop and these equal the raw per-hop numbers;
 the per-hop percentiles stay available as `hop_*` fields.
 
-Usable as a module (bench.py, tests) or a CLI against a synthetic model:
+Usable as a module (cli/serve.py, tests) or a CLI against a synthetic model:
 
     python tools/loadgen.py --requests 8 --rate 2 --streams 2
 """
