@@ -252,6 +252,28 @@ def _image_table(params: dict, cfg: DALLEConfig) -> jnp.ndarray:
     return maybe_dequant_weight(params["image_emb"]["table"])
 
 
+def image_head(params: dict, cfg: DALLEConfig) -> dict:
+    """The image half of the output head in LOOKUP layout: {"table":
+    (num_image_tokens, dim), "b": its (num_image_tokens,) bias entries when
+    the head has a bias}.  A decode step only ever produces an image position,
+    whose text columns `logits_mask_slice` forbids, and under a shared
+    embedding looks its input up in the same rows — so a serving engine
+    derives this ONCE from weights that do not change under it, where
+    `_image_table` re-derives the slice and the transpose on every call.  A
+    quantized `w` stays as stored: its per-column scales are the table's
+    per-row ones (`quantization.quantize_table`'s format)."""
+    lin = params["logits_linear"]
+    w, ntp = lin["w"], cfg.num_text_tokens_padded
+    if isinstance(w, dict):
+        table = {"qvalue": w["qvalue"][:, ntp:].T, "scale": w["scale"][ntp:, None]}
+    else:
+        table = w[:, ntp:].T
+    head = {"table": table}
+    if "b" in lin:
+        head["b"] = lin["b"][ntp:]
+    return head
+
+
 def remap_and_bos(cfg: DALLEConfig, text: jnp.ndarray) -> jnp.ndarray:
     """Give padding (id 0) a unique per-position id, then prepend <bos>=0.
 
@@ -309,6 +331,21 @@ def logits_mask_slice(cfg: DALLEConfig, n: int) -> jnp.ndarray:
 def to_logits(params: dict, cfg: DALLEConfig, x: jnp.ndarray) -> jnp.ndarray:
     return linear(params["logits_linear"],
                   apply_norm(cfg.transformer_config(), params["logits_norm"], x))
+
+
+def to_image_logits(params: dict, cfg: DALLEConfig, head: dict, x: jnp.ndarray) -> jnp.ndarray:
+    """`to_logits(...)[..., num_text_tokens_padded:]` from `image_head`'s
+    table: per column the same sum of the same `dim` products, contracted
+    against the table's rows."""
+    from dalle_pytorch_tpu.quantization import maybe_dequant_weight
+
+    x = apply_norm(cfg.transformer_config(), params["logits_norm"], x)
+    y = jax.lax.dot_general(
+        x, maybe_dequant_weight(head["table"], x.dtype),
+        (((x.ndim - 1,), (1,)), ((), ())), preferred_element_type=x.dtype)
+    if "b" in head:
+        y = y + head["b"].astype(y.dtype)
+    return y
 
 
 # ---------------------------------------------------------------------------

@@ -61,8 +61,9 @@ import jax.numpy as jnp
 from dalle_pytorch_tpu.models import dalle as dalle_mod
 from dalle_pytorch_tpu.models import sampling as sampling_mod
 from dalle_pytorch_tpu.models.transformer import decode_step, paged_decode_step
-from dalle_pytorch_tpu.ops.sampling import gumbel_sample, top_k_filter
+from dalle_pytorch_tpu.ops.sampling import gumbel_noise, gumbel_sample, top_k_filter
 from dalle_pytorch_tpu.ops.stable import divide_max
+from dalle_pytorch_tpu.quantization import maybe_dequant_weight
 
 
 # ---------------------------------------------------------------------------
@@ -159,26 +160,30 @@ def rollback_slot_rings(new_rings, old_rings, slots, a):
 # ---------------------------------------------------------------------------
 
 @jax.named_scope("sample")
-def lane_sample_pipeline(params, cfg, out, offsets, key_index, state,
+def lane_sample_pipeline(params, cfg, out, key_index, state,
                          filter_thres: float, degraded_filter_thres: float):
     """Transformer output -> per-lane sampled code, exactly the serving
-    engine's emit pipeline: masked logits, poison injection, CFG across lane
+    engine's emit pipeline: image logits, poison injection, CFG across lane
     pairs, nonfinite screen, degrade-capped top-k, per-lane step key, gumbel
-    sample, code clip, feed-source mirror.  `out`: (S, 1, dim); `offsets`:
-    (S,) producing positions; `key_index`: (S,) step-key row per lane.
-    Returns (code (S,) int32 — feed-mirrored so CFG pairs agree — and the
-    per-lane nonfinite `bad` flags).  Extracted from the engine's fused
-    decode step so the speculative draft/verify passes and the sequential
-    step share ONE pipeline and stay bit-identical by construction."""
+    sample, feed-source mirror.  `out`: (S, 1, dim); `key_index`: (S,)
+    step-key row per lane.  Returns (code (S,) int32 — feed-mirrored so CFG
+    pairs agree — and the per-lane nonfinite `bad` flags).  Extracted from
+    the engine's fused decode step so the speculative draft/verify passes and
+    the sequential step share ONE pipeline and stay bit-identical by
+    construction.
+
+    Every position an engine decodes is an IMAGE position (text is prefilled),
+    where `logits_mask_slice` forbids exactly the text columns: the pipeline
+    runs over the `num_image_tokens` columns of `state["head"]`
+    (`dalle.image_head`) alone and emits what the masked full-vocabulary one
+    would — the same k (of the whole vocabulary, clamped to the columns there
+    are: past them a full-width top-k only adds `finfo.min` entries no gumbel
+    draw can lift), the same noise (drawn at the whole vocabulary's shape,
+    image columns used), and the argmax IS the code."""
     S = out.shape[0]
     if cfg.stable:
         out = divide_max(out)
-    logits = dalle_mod.to_logits(params, cfg, out)[:, 0]  # (S, V)
-    rows = jnp.take(
-        dalle_mod.logits_mask_slice(cfg, cfg.total_seq_len),
-        offsets, axis=0, mode="clip",
-    )
-    logits = jnp.where(rows, jnp.finfo(logits.dtype).min, logits)
+    logits = dalle_mod.to_image_logits(params, cfg, state["head"], out)[:, 0]
 
     inject = jnp.arange(S, dtype=jnp.int32) == state["poison_lane"]
     logits = jnp.where(inject[:, None],
@@ -194,8 +199,8 @@ def lane_sample_pipeline(params, cfg, out, offsets, key_index, state,
     bad = ~jnp.isfinite(lg).all(axis=-1) & state["active"]
     lg = jnp.where(bad[:, None], jnp.zeros_like(lg), lg)
 
-    V = lg.shape[-1]
-    k = max(int((1.0 - filter_thres) * V), 1)
+    V, ntp = cfg.total_tokens, cfg.num_text_tokens_padded
+    k = min(max(int((1.0 - filter_thres) * V), 1), lg.shape[-1])
     k_cap = min(max(int((1.0 - degraded_filter_thres) * V), 1), k)
     val, ind = jax.lax.top_k(lg, k)
     keep = jnp.where(state["cand_cap"][:, None], jnp.arange(k) < k_cap, True)
@@ -209,24 +214,28 @@ def lane_sample_pipeline(params, cfg, out, offsets, key_index, state,
     )[:, 0]
 
     def sample_one(lg_row, kk, t):
-        # (1, V) shapes mirror the fused sampler's batch-1 call exactly
-        return gumbel_sample(kk, lg_row[None], temperature=t)[0]
+        # the fused sampler's batch-1 draw, (1, V): a threefry draw at
+        # another shape is another draw
+        noise = gumbel_noise(kk, (1, V), lg_row.dtype)[0, ntp:]
+        return jnp.argmax(lg_row / t + noise, axis=-1)
 
-    toks = jax.vmap(sample_one)(filtered, keys_t,
+    code = jax.vmap(sample_one)(filtered, keys_t,
                                 state["temp"].astype(logits.dtype))
-    code = jnp.clip(
-        toks - cfg.num_text_tokens_padded, 0, cfg.num_image_tokens - 1
-    ).astype(jnp.int32)
-    code = jnp.take(code, state["feed_src"], axis=0)
+    code = jnp.take(code.astype(jnp.int32), state["feed_src"], axis=0)
     return code, bad
 
 
 @jax.named_scope("embed")
-def _embed_prev(params, cfg, prev, img_idx):
+def _embed_prev(params, cfg, head, prev, img_idx):
     """The engine's decode-step embedding of a previous code at per-lane
-    image positions (mode="clip" keeps clamped overflow positions legal)."""
-    emb = jnp.take(dalle_mod._image_table(params, cfg), prev[:, None],
-                   axis=0, mode="clip")
+    image positions (mode="clip" keeps clamped overflow positions legal):
+    rows of `head`'s table under a shared embedding, of `image_emb`'s own
+    otherwise."""
+    table = (head["table"] if cfg.share_input_output_emb
+             else params["image_emb"]["table"])
+    emb = maybe_dequant_weight(
+        jax.tree_util.tree_map(
+            lambda t: jnp.take(t, prev[:, None], axis=0, mode="clip"), table))
     pos = dalle_mod.image_pos_table(params, cfg)
     if pos is not None:
         emb = emb + jnp.take(pos, img_idx, axis=0, mode="clip")[:, None]
@@ -253,13 +262,14 @@ def engine_spec_draft(params, cfg, tcfg, state, *, spec_k: int,
     drafts, hiddens = [], []
     for i in range(k):
         off_i = jnp.minimum(state["offsets"] + i, seq - 1)
-        x = _embed_prev(params, cfg, prev, state["img_prev"] + i)
+        x = _embed_prev(params, cfg, state["head"], prev,
+                        state["img_prev"] + i)
         out, pool, rings = paged_decode_step(
             params["transformer"], tcfg, x, pool, state["block_tables"],
             off_i, rings, block_size, layer_stop=d,
         )
         code, _ = lane_sample_pipeline(
-            params, cfg, out, off_i, state["img_prev"] + i, state,
+            params, cfg, out, state["img_prev"] + i, state,
             filter_thres, degraded_filter_thres,
         )
         drafts.append(code)
@@ -297,20 +307,21 @@ def engine_spec_verify(params, cfg, tcfg, state, draft, *, spec_k: int,
             state["block_tables"], off_i, rings, block_size, layer_start=d,
         )
         code, bad = lane_sample_pipeline(
-            params, cfg, out, off_i, img_prev + i, state,
+            params, cfg, out, img_prev + i, state,
             filter_thres, degraded_filter_thres,
         )
         vs.append(code)
         bads.append(bad)
     # bonus position: feed the last draft token through the FULL stack
     off_k = jnp.minimum(offsets + k, seq - 1)
-    x = _embed_prev(params, cfg, draft["drafts"][k - 1], img_prev + k)
+    x = _embed_prev(params, cfg, state["head"], draft["drafts"][k - 1],
+                    img_prev + k)
     out, pool, rings = paged_decode_step(
         params["transformer"], tcfg, x, pool, state["block_tables"],
         off_k, rings, block_size,
     )
     code, bad = lane_sample_pipeline(
-        params, cfg, out, off_k, img_prev + k, state,
+        params, cfg, out, img_prev + k, state,
         filter_thres, degraded_filter_thres,
     )
     vs.append(code)

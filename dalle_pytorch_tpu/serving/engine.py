@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dalle_pytorch_tpu.models import dalle as dalle_mod
 from dalle_pytorch_tpu.models import sampling as sampling_mod
 from dalle_pytorch_tpu.models import speculative as spec_mod
 from dalle_pytorch_tpu.models.transformer import (
@@ -202,7 +203,17 @@ class GenerationEngine:
 
         S = engine_cfg.num_slots
         nk = max(self.n_gen - 1, 1)
+        # the decode programs' head and lookup table, laid out once from
+        # weights that do not change under the engine (a fleet's reshard
+        # re-places `params`, it does not change them).  It rides in
+        # `_state` so that it reaches every program as an ARGUMENT beside
+        # `params`, which stays the checkpoint's tree; the programs hand it
+        # through, aliased where the state is donated
+        head = jax.jit(lambda p: dalle_mod.image_head(p, cfg))(params)
+        self._head_bytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(head))
+        obs_metrics.gauge("serving/decode_head_bytes").set(self._head_bytes)
         self._state: Dict[str, Any] = {
+            "head": head,
             "pool": self.pool.device_pool(ldtype),
             "rings": init_slot_rings(self.tcfg, S, ldtype),
             "block_tables": jnp.zeros((S, self.pool.blocks_per_seq), jnp.int32),
@@ -300,13 +311,17 @@ class GenerationEngine:
                 )
 
             def serve_spec_verify(params, state, draft):
-                return spec_mod.engine_spec_verify(
+                new_state, a = spec_mod.engine_spec_verify(
                     params, self.cfg, self.tcfg, state, draft, spec_k=k,
                     draft_layers=d, block_size=engine_cfg.block_size,
                     n_gen=self.n_gen,
                     filter_thres=engine_cfg.filter_thres,
                     degraded_filter_thres=engine_cfg.degraded_filter_thres,
                 )
+                # this program's state is not donated, and an entry handed
+                # through it is copied: the table stays with the caller
+                del new_state["head"]
+                return new_state, a
 
             self._spec_draft_fn = jax.jit(serve_spec_draft)
             self._spec_verify_fn = jax.jit(serve_spec_verify)
@@ -332,7 +347,7 @@ class GenerationEngine:
     # ------------------------------------------------------------------ jits
     def _decode_step_impl(self, params, state):
         """One fused decode step for all slots.  The transformer output ->
-        sampled code half (masked logits, poison injection, CFG across lane
+        sampled code half (image logits, poison injection, CFG across lane
         pairs, nonfinite screen, degrade-capped top-k, per-lane step key,
         feed-source mirror) lives in `speculative.lane_sample_pipeline`, the
         single pipeline the speculative draft/verify round also runs — so
@@ -340,7 +355,8 @@ class GenerationEngine:
         cfg, tcfg = self.cfg, self.tcfg
         prev = state["prev_code"]
 
-        emb = spec_mod._embed_prev(params, cfg, prev, state["img_prev"])
+        emb = spec_mod._embed_prev(params, cfg, state["head"], prev,
+                                   state["img_prev"])
 
         paths = {"kernel": 0, "fallback": 0}
         out, pool, rings = paged_decode_step(
@@ -350,10 +366,9 @@ class GenerationEngine:
         )
         self._note_paged_paths(paths)
 
-        # per-slot _logits_at row = producing position = pre-increment offset;
         # per-lane step key row = img_prev (the index of the token being made)
         code, bad = spec_mod.lane_sample_pipeline(
-            params, cfg, out, state["offsets"], state["img_prev"], state,
+            params, cfg, out, state["img_prev"], state,
             self.ecfg.filter_thres, self.ecfg.degraded_filter_thres,
         )
         poisoned = state["poisoned"] | bad
@@ -380,12 +395,14 @@ class GenerationEngine:
 
     def _note_paged_paths(self, paths: Dict[str, int]) -> None:
         """Runs while the decode program is TRACED: how many of its attention
-        layers took the Pallas paged kernel and how many the XLA gather, into
-        the registry (and the status file).  A retrace counts nothing new."""
+        layers took the Pallas paged kernel and how many the XLA gather, and
+        that its head and lookup read the table laid out at build, into the
+        registry (and the status file).  A retrace counts nothing new."""
         if self._paged_paths is None:
             self._paged_paths = dict(paths)
             obs_metrics.counter("serving/paged_attn_kernel_layers").inc(paths["kernel"])
             obs_metrics.counter("serving/paged_attn_fallback_layers").inc(paths["fallback"])
+            obs_metrics.counter("serving/decode_head_prepared").inc()
 
     def _prefill_sample_impl(self, params, text, k0, temperature,
                              cond_scale: float):
@@ -1101,8 +1118,9 @@ class GenerationEngine:
                 jax.block_until_ready(draft["drafts"])  # host-sync-ok: spec/draft_time_frac attribution point
             with telemetry.timed_span("serve/spec.verify",
                                       iter=self._iter) as t_verify:
-                self._state, acc = self._spec_verify_fn(
+                new_state, acc = self._spec_verify_fn(
                     self.params, self._state, draft)
+                self._state = dict(new_state, head=self._state["head"])
                 acc_np = np.asarray(acc)  # host-sync-ok: accepted lengths drive codes_done/eviction
         self._warm_spec = True
         accepted = 0
@@ -1319,6 +1337,7 @@ class GenerationEngine:
                 **spec_fields,
                 **self.quantization_state(),
                 **self.paged_path_state(),
+                **self.decode_head_state(),
             )
         # flight-recorder drain rides the same cadence: pending block-
         # lifecycle events leave the ring as kind:"pool" records, and the
@@ -1350,6 +1369,7 @@ class GenerationEngine:
             "pool_occupancy_frac": self.pool.occupancy_frac,
             "pool_free_blocks": self.pool.free_blocks,
             **self.paged_path_state(),
+            **self.decode_head_state(),
         }
         payload["pool"] = self.pool_observability()
         payload["quantization"] = self.quantization_state()
@@ -1363,6 +1383,14 @@ class GenerationEngine:
         paths = self._paged_paths or {}
         return {"paged_attn_kernel_layers": paths.get("kernel"),
                 "paged_attn_fallback_layers": paths.get("fallback")}
+
+    def decode_head_state(self) -> Dict[str, Optional[int]]:
+        """Whether the decode program was traced on the table laid out at
+        build (None until it is traced) and that table's bytes: the
+        registry's `serving/decode_head_prepared` and
+        `serving/decode_head_bytes`, carried like the path counts."""
+        return {"decode_head_prepared": None if self._paged_paths is None else 1,
+                "decode_head_bytes": self._head_bytes}
 
     def pool_observability(self) -> Dict[str, Any]:
         """Live pool section for status_json and the serve report: the

@@ -9,6 +9,7 @@ compile that passes is not a chip run.  The whole file skips where the
 topology cannot be described.  Plus: the hardware table finds the kind the
 v5e reports and refuses a kind it does not know."""
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -159,25 +160,18 @@ def test_kernel_compiles_for_v5e(one_chip, name):
         f"{name}: expected >= {n_kernels} Pallas custom calls in the compiled program")
 
 
-def test_decode_program_touches_the_pool_only_through_the_kernel(one_chip):
-    """The engine's decode program at serve_batch's width (depth cut to 2),
-    compiled for the chip: both layers take the kernel, and no operation but
-    the kernel has a pool array for an operand or a result — no gather, no
-    relayout `copy` (XLA's scatter wants the pool in another layout: two
-    copies of each pool array a step), and no staging of a pool through VMEM
-    by XLA's memory-space assignment (`slice-start` / `copy-start`), which a
-    cost estimate on the kernel brings on.  The kernel cases above compile
-    WITHOUT donating the pool, where pinning it to HBM aborted the compiler.
-    Nothing runs."""
-    import re
-
+@pytest.fixture(scope="module")
+def decode_program(one_chip):
+    """(engine, text of its decode program compiled for the chip) at
+    serve_batch's widths and flags, depth cut to 2.  Nothing runs."""
     from dalle_pytorch_tpu.models import dalle as dalle_mod
     from dalle_pytorch_tpu.models.dalle import DALLEConfig
     from dalle_pytorch_tpu.serving.engine import EngineConfig, GenerationEngine
 
     cfg = DALLEConfig(dim=H * D, depth=2, heads=H, dim_head=D, num_text_tokens=16384,
                       text_seq_len=128, num_image_tokens=8192, image_fmap_size=32,
-                      attn_types=("full", "axial_row"), shift_tokens=True)
+                      attn_types=("full", "axial_row"), shift_tokens=True,
+                      rotary_emb=True, share_input_output_emb=True)
     params = jax.jit(lambda k: dalle_mod.init_dalle(k, cfg))(jax.random.PRNGKey(0))
     eng = GenerationEngine(params, cfg, engine_cfg=EngineConfig(num_slots=8, block_size=64))
 
@@ -186,15 +180,55 @@ def test_decode_program_touches_the_pool_only_through_the_kernel(one_chip):
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
 
     text = eng._decode_fn.lower(described(eng.params), described(eng._state)).compile().as_text()
+    return eng, text
+
+
+def _op_of(line):
+    m = re.search(r"= .*? ([a-z][a-z\-]*)\(", line)
+    return m.group(1) if m else None
+
+
+def test_decode_program_touches_the_pool_only_through_the_kernel(decode_program):
+    """The engine's decode program compiled for the chip: both layers take
+    the kernel, and no operation but the kernel has a pool array for an
+    operand or a result — no gather, no relayout `copy` (XLA's scatter wants
+    the pool in another layout: two copies of each pool array a step), and no
+    staging of a pool through VMEM by XLA's memory-space assignment
+    (`slice-start` / `copy-start`), which a cost estimate on the kernel
+    brings on.  The kernel cases above compile WITHOUT donating the pool,
+    where pinning it to HBM aborted the compiler."""
+    eng, text = decode_program
     assert eng._paged_paths == {"kernel": 2, "fallback": 0}
     pool = eng._state["pool"]["layers"][0]["k"]
     shape = "f32[" + ",".join(map(str, pool.shape)) + "]"
     ops = {}
     for line in text[text.index("ENTRY "):].splitlines()[1:]:
         if shape in line:
-            op = re.search(r"= .*? ([a-z][a-z\-]*)\(", line).group(1)
-            ops[op] = ops.get(op, 0) + 1
+            ops[_op_of(line)] = ops.get(_op_of(line), 0) + 1
     assert ops == {"parameter": 4, "custom-call": 2, "get-tuple-element": 4, "tuple": 1}, ops
+
+
+def test_decode_program_reads_the_image_table_where_it_lies(decode_program):
+    """The same program: the whole head (`f32[2048,24704]`, 202 MB) is no
+    operand and no result of any instruction — jit does not even pass it in —
+    there is no `transpose`, `copy` or `slice` of a table-sized array (the 67
+    MB slice-and-transpose a step that the shared embedding's lookup cost),
+    `top_k`'s sort is 8,192 wide, and the table laid out at engine build goes
+    in as a parameter and comes out aliased to it."""
+    eng, text = decode_program
+    cfg = eng.cfg
+    whole = f"f32[{cfg.dim},{cfg.total_tokens}]"
+    assert [ln for ln in text.splitlines() if whole in ln and _op_of(ln) != "parameter"] == []
+    table = (f"[{cfg.num_image_tokens},{cfg.dim}]", f"[{cfg.dim},{cfg.num_image_tokens}]")
+    moved = [ln[:160] for ln in text.splitlines()
+             if _op_of(ln) in ("transpose", "copy", "slice") and any(t in ln for t in table)]
+    assert moved == [], moved
+    sorts = [ln for ln in text.splitlines() if _op_of(ln) == "sort"]
+    widths = [max(int(n) for dims in re.findall(r"\[([\d,]+)\]", ln.split(" sort(")[0])
+                  for n in dims.split(",")) for ln in sorts]
+    assert sorts and max(widths) == cfg.num_image_tokens, widths
+    (param,) = re.findall(r"parameter\((\d+)\)[^\n]*op_name=\"state\[\\'head\\'\]\[\\'table\\'\]\"", text)
+    assert re.search(r"\{\d+\}: \(%s, \{\}, may-alias\)" % param, text.splitlines()[0]), param
 
 
 @pytest.mark.parametrize("lookup", ["flops", "hbm", "ici"])

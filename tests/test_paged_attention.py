@@ -378,6 +378,10 @@ def test_engine_counters_status_and_delivered_codes(dim_head, bs, want, tmp_path
         eng._status_path = str(tmp_path / "status.json")
         assert eng.paged_path_state() == {"paged_attn_kernel_layers": None,
                                           "paged_attn_fallback_layers": None}
+        # dim x 32 image codes and their 32 bias entries, float32
+        head_bytes = 4 * (32 * cfg.dim + 32)
+        assert eng.decode_head_state() == {"decode_head_prepared": None,
+                                           "decode_head_bytes": head_bytes}
         reqs = eng.generate(text, keys=keys)  # three requests through two slots
         eng.close()
     finally:
@@ -392,6 +396,13 @@ def test_engine_counters_status_and_delivered_codes(dim_head, bs, want, tmp_path
         assert (names["paged_attn_kernel_layers"], names["paged_attn_fallback_layers"]) == want
     assert (flushed["serving/paged_attn_kernel_layers"]["total"],
             flushed["serving/paged_attn_fallback_layers"]["total"]) == want
+    # the decode program was traced once, on the table laid out at build
+    snap = obs_metrics.REGISTRY.snapshot(reset_window=False)
+    for names in (window, serving, eng.decode_head_state()):
+        assert (names["decode_head_prepared"], names["decode_head_bytes"]) == (1, head_bytes)
+    for reg in (snap, flushed):
+        assert reg["serving/decode_head_prepared"]["total"] == 1
+        assert reg["serving/decode_head_bytes"]["last"] == head_bytes
 
     k = max(int((1.0 - eng.ecfg.filter_thres) * cfg.total_tokens), 1)
     for i, req in enumerate(reqs):

@@ -135,6 +135,149 @@ def test_engine_ignores_scan_layers(kw, bs, want):
                                     "paged_attn_fallback_layers": want[1]}
 
 
+def _full_width_pipeline(params, cfg, out, offsets, key_index, state,
+                         filter_thres, degraded_filter_thres):
+    """`speculative.lane_sample_pipeline` as it stood before the engine laid
+    the head's image half out once: the whole vocabulary's logits, the mask
+    row of each lane's position, top-k, gumbel and the code's offset over all
+    `total_tokens` columns.  Kept as the narrowed pipeline's definition."""
+    from dalle_pytorch_tpu.ops.sampling import gumbel_sample
+    from dalle_pytorch_tpu.ops.stable import divide_max
+
+    S = out.shape[0]
+    if cfg.stable:
+        out = divide_max(out)
+    logits = dalle_mod.to_logits(params, cfg, out)[:, 0]  # (S, V)
+    rows = jnp.take(dalle_mod.logits_mask_slice(cfg, cfg.total_seq_len),
+                    offsets, axis=0, mode="clip")
+    logits = jnp.where(rows, jnp.finfo(logits.dtype).min, logits)
+    inject = jnp.arange(S, dtype=jnp.int32) == state["poison_lane"]
+    logits = jnp.where(inject[:, None], jnp.asarray(jnp.nan, logits.dtype), logits)
+    null_lg = jnp.take(logits, state["partner"], axis=0)
+    lg = jnp.where(
+        state["guided"][:, None],
+        null_lg + (logits - null_lg) * state["cscale"][:, None].astype(logits.dtype),
+        logits)
+    bad = ~jnp.isfinite(lg).all(axis=-1) & state["active"]
+    lg = jnp.where(bad[:, None], jnp.zeros_like(lg), lg)
+    V = lg.shape[-1]
+    k = max(int((1.0 - filter_thres) * V), 1)
+    k_cap = min(max(int((1.0 - degraded_filter_thres) * V), 1), k)
+    val, ind = jax.lax.top_k(lg, k)
+    keep = jnp.where(state["cand_cap"][:, None], jnp.arange(k) < k_cap, True)
+    val = jnp.where(keep, val, -jnp.inf)
+    filtered = jnp.put_along_axis(
+        jnp.full_like(lg, -jnp.inf), ind, val, axis=-1, inplace=False)
+    keys_t = jnp.take_along_axis(
+        state["keys"],
+        jnp.clip(key_index, 0, state["keys"].shape[1] - 1)[:, None, None], axis=1)[:, 0]
+
+    def sample_one(lg_row, kk, t):
+        return gumbel_sample(kk, lg_row[None], temperature=t)[0]
+
+    toks = jax.vmap(sample_one)(filtered, keys_t, state["temp"].astype(logits.dtype))
+    code = jnp.clip(toks - cfg.num_text_tokens_padded, 0,
+                    cfg.num_image_tokens - 1).astype(jnp.int32)
+    return jnp.take(code, state["feed_src"], axis=0), bad
+
+
+@pytest.mark.parametrize("poison_lane", [-1, 3], ids=["healthy", "poisoned_lane"])
+@pytest.mark.parametrize("weights", ["tied", "untied", "tied_int8"])
+@pytest.mark.parametrize("thres", [0.9, 0.5, 0.0, 0.6384],
+                         ids=["k_under", "k_over", "k_all", "k_equal"])
+def test_image_width_pipeline_emits_the_full_width_codes(thres, weights, poison_lane):
+    """The decode pipeline over the image columns of the table laid out at
+    engine build emits, bit for bit, the codes of the masked full-vocabulary
+    pipeline it replaced, fed the same transformer output: k of the whole
+    vocabulary under, over and equal to the image columns' count (11, 56, 112
+    and 40 of 112 against 40), a guided lane pair at scale 3, temperatures
+    either side of 1, a degrade-capped lane, tied, untied and int8 weights —
+    and screens the same lanes as nonfinite.  A screened lane's code is not
+    compared: its request fails as poisoned, and where the full-width
+    pipeline sampled its zeroed row among text columns (code 0) this one
+    samples it among the first k image codes.  Lane 5 is idle, as an evicted
+    lane is (offset 0, a TEXT row of the mask): its code is discarded by the
+    step, and differs.  The lookup reads the same rows."""
+    from dalle_pytorch_tpu import quantization
+    from dalle_pytorch_tpu.models import speculative as spec_mod
+
+    cfg = tiny_cfg(num_image_tokens=40, share_input_output_emb=weights != "untied")
+    params = dalle_mod.init_dalle(jax.random.PRNGKey(0), cfg)
+    if weights == "tied_int8":
+        params = quantization.quantize_tree(params)
+    V, n_img = cfg.total_tokens, cfg.num_image_tokens
+    assert (V, n_img) == (112, 40)
+    assert max(int((1.0 - thres) * V), 1) == {0.9: 11, 0.5: 56, 0.0: 112, 0.6384: 40}[thres]
+    S, nk = 6, 5
+    state = {
+        "head": dalle_mod.image_head(params, cfg),
+        "poison_lane": jnp.asarray(poison_lane, jnp.int32),
+        "partner": jnp.asarray([1, 1, 2, 3, 4, 5], jnp.int32),
+        "guided": jnp.asarray([True, False, False, False, False, False]),
+        "feed_src": jnp.asarray([0, 0, 2, 3, 4, 5], jnp.int32),
+        "cscale": jnp.asarray([3.0, 3.0, 1.0, 1.0, 1.0, 1.0], jnp.float32),
+        "temp": jnp.asarray([1.0, 1.0, 0.7, 1.3, 1.0, 1.0], jnp.float32),
+        "cand_cap": jnp.asarray([False, False, True, False, True, False]),
+        "active": jnp.asarray([True, True, True, True, True, False]),
+        "keys": jax.random.bits(jax.random.PRNGKey(7), (S, nk, 2), jnp.uint32),
+    }
+    offsets = jnp.asarray([9, 9, 12, 24, 15, 0], jnp.int32)
+    narrow = jax.jit(lambda out, ki: spec_mod.lane_sample_pipeline(
+        params, cfg, out, ki, state, thres, 0.98))
+    full = jax.jit(lambda out, ki: _full_width_pipeline(
+        params, cfg, out, offsets, ki, state, thres, 0.98))
+    live = np.array(state["active"])
+    if poison_lane >= 0:
+        live[poison_lane] = False
+    seen = set()
+    for draw in range(6):
+        out = 3.0 * jax.random.normal(jax.random.PRNGKey(100 + draw), (S, 1, cfg.dim))
+        ki = jnp.asarray([draw, draw, draw + 1, draw + 2, draw, draw], jnp.int32)
+        (code, bad), (want, want_bad) = narrow(out, ki), full(out, ki)
+        np.testing.assert_array_equal(np.asarray(bad), np.asarray(want_bad))
+        np.testing.assert_array_equal(np.asarray(bad), ~live & np.asarray(state["active"]))
+        np.testing.assert_array_equal(np.asarray(code)[live], np.asarray(want)[live])
+        assert code.dtype == jnp.int32 and int(code.min()) >= 0 and int(code.max()) < n_img
+        assert int(code[0]) == int(code[1])  # the pair is fed one code
+        seen.update(np.asarray(code)[live].tolist())
+    assert len(seen) > 4, seen  # the comparison was not of one constant
+
+    prev = jnp.asarray([0, 39, 7, 45, 13, 2], jnp.int32)  # 45: past the table, clipped
+    got = spec_mod._embed_prev(params, cfg, state["head"], prev, jnp.zeros((S,), jnp.int32))
+    want = jnp.take(dalle_mod._image_table(params, cfg), prev[:, None], axis=0, mode="clip")
+    pos = dalle_mod.image_pos_table(params, cfg)
+    if pos is not None:
+        want = want + pos[0][None, None]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_decode_program_reads_the_image_table_where_it_lies():
+    """The lowered decode program at tiny sizes (the twin of
+    tests/test_chip_compile.py's check of the program compiled for the chip):
+    the whole head (32 x 112) is not in it at all, the table laid out at build
+    (40 x 32) is an ARGUMENT — never a constant — that only the lookup's
+    gather and the head's contraction read, with no transpose or slice of it,
+    `top_k` sees the 40 image columns, and the table leaves the donated state
+    aliased to the argument it came in by."""
+    import re
+
+    cfg = tiny_cfg(num_image_tokens=40, share_input_output_emb=True)
+    params = dalle_mod.init_dalle(jax.random.PRNGKey(0), cfg)
+    eng = GenerationEngine(params, cfg, engine_cfg=EngineConfig(num_slots=2, block_size=4))
+    text = eng._decode_fn.lower(params, eng._state).as_text()
+    assert f"tensor<{cfg.dim}x{cfg.total_tokens}x" not in text
+    ops = set()
+    for line in text.splitlines():
+        if "tensor<40x32x" in line or "tensor<32x40x" in line:
+            ops.add(re.match(r"\s*(?:%[\w:#]+(?:, %[\w:#]+)* = )?\"?([\w.]+)", line).group(1))
+    assert ops == {"func.func", "call", "stablehlo.gather", "stablehlo.dot_general", "return"}, ops
+    assert re.findall(r"top_k\(.*?\) : tensor<2x(\d+)xf32>", text) == ["40"]
+    (out_index,) = re.findall(
+        r"tensor<40x32xf32> \{tf.aliasing_output = (\d+) : i32\}", text)
+    results = re.findall(r'jax.result_info = "(.*?)"', text)
+    assert results[int(out_index)] == "result['head']['table']"
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("kw,sample_kw", [
     (dict(rotary_emb=False), {}),
