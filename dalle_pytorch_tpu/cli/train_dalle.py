@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses as _dc
+import json
 import time
 from glob import glob
 from pathlib import Path
@@ -121,6 +122,12 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--loss_img_weight", type=int, default=7)
     parser.add_argument("--attn_types", type=str, default="full",
                         help="comma-separated cycle of full,axial_row,axial_col,conv_like,sparse")
+    parser.add_argument("--block_json", type=str, default=None,
+                        help="a JSON file whose keys that name DALLEConfig fields describe the "
+                             "block (norm, kv_heads, gdn_*, moe_*, mla_*, dense_*, mtp_*, "
+                             "attn_types, ...): a hybrid trunk from the normal entry point; "
+                             "a benchmark configuration file is one.  Sizes the command line "
+                             "also sets (dim, depth, heads, ...) are the file's")
     parser.add_argument("--sparse_per_head", action="store_true",
                         help="'sparse' layers draw a random block layout PER HEAD "
                              "(DeepSpeed sparse-attention parity); costs heads x seq^2 "
@@ -726,6 +733,11 @@ def main(argv=None):
             shared_ff_ids=_parse_ids(args.shared_ff_ids),
             share_input_output_emb=args.share_input_output_emb,
         )
+        if args.block_json:
+            block = json.loads(Path(args.block_json).read_text())
+            block = {f.name: block[f.name] for f in _dc.fields(DALLEConfig)
+                     if f.name in block and f.name not in ("num_image_tokens", "image_fmap_size")}
+            dalle_cfg = _dc.replace(dalle_cfg, **dalle_mod.tupled_hparams(block))
         start_params = dalle_mod.init_dalle(jax.random.PRNGKey(args.seed), dalle_cfg)
 
     # pipeline engagement follows THIS run's mesh, not the checkpoint's: a
@@ -835,7 +847,8 @@ def main(argv=None):
         # that ride in the step's metrics beside the loss)
         return dalle_mod.forward(
             params, dalle_cfg, batch["text"], jax.lax.stop_gradient(codes),
-            return_loss=True, key=key, return_aux=dalle_cfg.moe_experts > 0,
+            return_loss=True, key=key,
+            return_aux=dalle_cfg.moe_experts > 0 or dalle_cfg.mtp_depth > 0,
         )
 
     optimizer = optax.adam(args.learning_rate)
@@ -931,6 +944,7 @@ def main(argv=None):
         state, step_fn, _, _ = be.distribute(
             loss_fn=loss_fn, params=start_params, optimizer=optimizer,
             mesh_config=mesh_cfg, settings=settings, registry=registry,
+            param_rule=dalle_mod.param_rule(dalle_cfg),
         )
     except Exception as e:
         if memory_mod.is_oom_error(e):
@@ -962,6 +976,7 @@ def main(argv=None):
             state, step_fn, _, _ = be.distribute(
                 loss_fn=loss_fn, params=migrated, optimizer=optimizer,
                 mesh_config=mesh_cfg, settings=settings, registry=registry,
+                param_rule=dalle_mod.param_rule(dalle_cfg),
             )
             state = TrainState(jnp.asarray(restored["step"]), state.params, state.opt_state)
     elif resume_meta is not None and "opt_state" in trees:
@@ -1456,8 +1471,10 @@ def main(argv=None):
                             dt = time.time() - t_window
                             steps_done = global_step - window_start + 1
                             record = {"loss": float(be.average_all(metrics["loss"])), "epoch": epoch}
-                            for name in ("moe_load_max_over_mean", "moe_pairs_here", "moe_overflow_share"):
-                                if name in metrics:  # a routed trunk's load, fetched with the loss
+                            for name in ("moe_load_max_over_mean", "moe_pairs_here", "moe_overflow_share",
+                                         "moe_bias_abs_max", "main_loss", "mtp_loss"):
+                                if name in metrics:  # a routed trunk's load, a prediction module's
+                                    # two losses: fetched with the loss
                                     record[name] = float(metrics[name])
                             if not first_window:
                                 # the process's first window spans jit compilation —

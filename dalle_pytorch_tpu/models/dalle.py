@@ -35,6 +35,9 @@ _BLOCK_FIELDS = (
     "gdn_key_heads", "gdn_value_heads", "gdn_key_dim", "gdn_value_dim", "gdn_conv_kernel",
     "moe_experts", "moe_top_k", "moe_ff_dim", "moe_shared_ff_dim",
     "moe_experts_held", "moe_first_expert",
+    "moe_router", "moe_routed_scale", "moe_bias_rate", "moe_shared_gated",
+    "dense_layers", "dense_ff_dim",
+    "mla_q_rank", "mla_kv_rank", "mla_nope_dim", "mla_rope_dim", "mla_v_dim",
 )
 
 
@@ -104,6 +107,24 @@ class DALLEConfig:
     moe_shared_ff_dim: int = 0
     moe_experts_held: Optional[int] = None
     moe_first_expert: int = 0
+    moe_router: str = "softmax"
+    moe_routed_scale: float = 1.0
+    moe_bias_rate: float = 0.001
+    moe_shared_gated: bool = True
+    dense_layers: int = 0
+    dense_ff_dim: int = 0
+    mla_q_rank: int = 0
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
+    # the multi-token-prediction module [`num_nextn_predict_layers`]: after the
+    # trunk, one more block of the trunk's LAST layer's kind over
+    # W_m [N(h_i) ; N(e_{i+1})], its own final norm, the trunk's own embedding
+    # and head; its logits at i predict token i + 2 and its loss is added
+    # `mtp_loss_weight` times (DeepSeek-V3 report, section 2.2).  0 = none.
+    mtp_depth: int = 0
+    mtp_loss_weight: float = 0.3
 
     # -- derived ----------------------------------------------------------
     @property
@@ -164,6 +185,15 @@ class DALLEConfig:
             **{k: getattr(self, k) for k in _BLOCK_FIELDS},
         )
 
+    def mtp_block_config(self) -> TransformerConfig:
+        """The prediction module's one block: the trunk's last layer's kind
+        (its mixer, a routed feed-forward where the trunk has one)."""
+        if self.mtp_depth != 1:
+            raise ValueError(f"mtp_depth {self.mtp_depth} is not supported; 0 (none) or 1")
+        last = self.attn_types[(self.depth - 1) % len(self.attn_types)]
+        return dataclasses.replace(self.transformer_config(), depth=1, attn_types=(last,),
+                                   dense_layers=0, shared_attn_ids=None, shared_ff_ids=None)
+
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
 
@@ -223,6 +253,15 @@ def init_dalle(key: jax.Array, cfg: DALLEConfig) -> dict:
         # axial positional embedding: summed per-row and per-column tables
         params["image_pos_h"] = embedding_init(keys.next(), cfg.image_fmap_size, cfg.dim)
         params["image_pos_w"] = embedding_init(keys.next(), cfg.image_fmap_size, cfg.dim)
+    if cfg.mtp_depth:
+        block_cfg = cfg.mtp_block_config()
+        params["mtp"] = {
+            "h_norm": norm_init(block_cfg),
+            "e_norm": norm_init(block_cfg),
+            "merge": linear_init(keys.next(), 2 * cfg.dim, cfg.dim, bias=False),
+            "block": init_transformer(keys.next(), block_cfg),
+            "norm": norm_init(block_cfg),
+        }
     return params
 
 
@@ -361,6 +400,7 @@ def forward(
     null_cond_prob: float = 0.0,
     key: Optional[jax.Array] = None,
     return_aux: bool = False,
+    with_mtp_logits: bool = False,
 ):
     """Training/scoring forward.
 
@@ -370,7 +410,11 @@ def forward(
     Returns logits (b, n, total_tokens) or the weighted CE loss; with
     `return_aux`, a pair of that and a dict of device scalars beside it (a
     routed trunk's `moe_load_max_over_mean`, `moe_pairs_here` and
-    `moe_overflow_share`; {} for a dense one)."""
+    `moe_overflow_share`, with a prediction module the `main_loss` and
+    `mtp_loss` the loss is the sum of; {} for a dense trunk), and under
+    `rule_inputs` what `param_rule` reads, keyed by the parameter's path.
+    `with_mtp_logits` (not with `return_loss`): the first of the pair is
+    (logits, the prediction module's (b, n - 1, total_tokens) logits)."""
     assert text.shape[-1] == cfg.text_seq_len, (
         f"text length {text.shape[-1]} != text_seq_len {cfg.text_seq_len}"
     )
@@ -406,6 +450,23 @@ def forward(
     if cfg.stable:
         out = divide_max(out)
 
+    # a bias-balanced router's choice counts, by the path of the bias they move
+    rule_inputs = {f"transformer/shared_ff/{ff_id}/router/bias": counts
+                   for ff_id, counts in aux.pop("moe_choice_counts", {}).items()}
+    mtp_logits = None
+    if cfg.mtp_depth:
+        mtp_logits, block_aux = _mtp_logits(params, cfg, tokens, out, drop_key)
+        rule_inputs.update({f"mtp/block/shared_ff/{ff_id}/router/bias": counts
+                            for ff_id, counts in block_aux.pop("moe_choice_counts", {}).items()})
+        # the load scalars are means over routed layers: the trunk's and the module's one
+        routed = sum(cfg.transformer_config().ff_type(i) == "moe" for i in range(cfg.depth))
+        aux = {k: (aux[k] * routed + v) / (routed + 1) if k in aux else v
+               for k, v in block_aux.items()} if block_aux else aux
+    if rule_inputs:
+        aux["rule_inputs"] = rule_inputs
+        aux["moe_bias_abs_max"] = jnp.max(jnp.stack([
+            jnp.max(jnp.abs(_leaf_at(params, path).astype(jnp.float32))) for path in rule_inputs]))
+
     with jax.named_scope("logits_loss"):
         logits = to_logits(params, cfg, out)
         logits = jnp.where(
@@ -426,6 +487,8 @@ def forward(
         )
 
     if not return_loss:
+        if with_mtp_logits:
+            logits = (logits, mtp_logits)
         return (logits, aux) if return_aux else logits
 
     assert image_codes is not None, "when training, image codes must be supplied"
@@ -434,15 +497,76 @@ def forward(
             [text_ids[:, 1:], image_codes + cfg.num_text_tokens_padded], axis=1
         )
         assert labels.shape[1] == cfg.total_seq_len
-
-        # CE as gather - logsumexp: same math as log_softmax+gather but never
-        # materializes a second (b, n, vocab) f32 tensor (XLA streams the
-        # reduction over the bf16 logits)
-        logits32 = logits.astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits32, axis=-1)
-        label_logit = jnp.take_along_axis(logits32, labels[..., None], axis=-1)[..., 0]
-        token_ll = label_logit - lse
-        loss_text = -jnp.mean(token_ll[:, : cfg.text_seq_len])
-        loss_img = -jnp.mean(token_ll[:, cfg.text_seq_len :])
-        loss = (loss_text + cfg.loss_img_weight * loss_img) / (cfg.loss_img_weight + 1)
+        loss = _weighted_ce(cfg, logits, labels, cfg.text_seq_len)
+    if mtp_logits is not None:
+        with jax.named_scope("mtp"), jax.named_scope("mtp_head"):
+            # position i's target is token i + 2: the main labels one further on
+            mtp_loss = _weighted_ce(cfg, mtp_logits, labels[:, 1:], cfg.text_seq_len - 1)
+        aux = dict(aux, main_loss=loss, mtp_loss=mtp_loss)
+        loss = loss + cfg.mtp_loss_weight * mtp_loss
     return (loss, aux) if return_aux else loss
+
+
+def _leaf_at(params: dict, path: str):
+    for key in path.split("/"):
+        params = params[key]
+    return params
+
+
+def param_rule(cfg: DALLEConfig):
+    """What `make_train_step(param_rule=...)` takes for this configuration: the
+    rule that moves each parameter `forward`'s `rule_inputs` names (a
+    bias-balanced router's bias, by its layer's choice counts of the step),
+    or None where the loss names none."""
+    if cfg.moe_router != "sigmoid_bias":
+        return None
+    from dalle_pytorch_tpu.models.moe import balance_bias
+
+    def rule(path: str, bias, counts):
+        with jax.named_scope("moe_bias_update"):
+            return balance_bias(cfg, bias, counts)
+
+    return rule
+
+
+def _weighted_ce(cfg: DALLEConfig, logits, labels, n_text: int):
+    """(CE over the first `n_text` positions, whose targets are text, +
+    loss_img_weight x CE over the rest) / (loss_img_weight + 1)."""
+    # CE as gather - logsumexp: same math as log_softmax+gather but never
+    # materializes a second (b, n, vocab) f32 tensor (XLA streams the
+    # reduction over the bf16 logits)
+    logits32 = logits.astype(jnp.float32)
+    lse = jax.scipy.special.logsumexp(logits32, axis=-1)
+    label_logit = jnp.take_along_axis(logits32, labels[..., None], axis=-1)[..., 0]
+    token_ll = label_logit - lse
+    loss_text = -jnp.mean(token_ll[:, :n_text])
+    loss_img = -jnp.mean(token_ll[:, n_text:])
+    return (loss_text + cfg.loss_img_weight * loss_img) / (cfg.loss_img_weight + 1)
+
+
+@jax.named_scope("mtp")
+def _mtp_logits(params: dict, cfg: DALLEConfig, tokens, out, drop_key):
+    """The prediction module over the trunk's output `out` (before the final
+    norm) and the input embeddings `tokens`, both (b, n, dim).  Returns (its
+    (b, n - 1, total_tokens) logits, whose row i predicts token i + 2 and is
+    masked by that target's position; its block's `apply_transformer`
+    stats).  The block runs over all n positions, so that
+    the sequence keeps the length the attention kernel takes: the last has no
+    next embedding (zeros) and no target, is routed like any token, reaches
+    no earlier position (causal) and is cut from the logits."""
+    from dalle_pytorch_tpu.observability import metrics as obs_metrics
+
+    mtp, block_cfg = params["mtp"], cfg.mtp_block_config()
+    obs_metrics.counter("train/mtp_layers").inc(cfg.mtp_depth)
+    n = tokens.shape[1]
+    with jax.named_scope("mtp_merge"):
+        e_next = jnp.concatenate([tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1)
+        merged = linear(mtp["merge"], jnp.concatenate(
+            [apply_norm(block_cfg, mtp["h_norm"], out), apply_norm(block_cfg, mtp["e_norm"], e_next)],
+            axis=-1))
+    h, block_aux = apply_transformer(mtp["block"], block_cfg, merged, dropout_key=drop_key,
+                                     return_stats=True)
+    with jax.named_scope("mtp_head"):
+        logits = linear(params["logits_linear"], apply_norm(block_cfg, mtp["norm"], h[:, : n - 1]))
+        logits = jnp.where(logits_mask_slice(cfg, n)[None, 1:], jnp.finfo(logits.dtype).min, logits)
+    return logits, block_aux

@@ -4,6 +4,13 @@
     largest, renormalised to sum 1;  E(x) = W_d (silu(W_g x) * (W_u x));
     MoE(x) = sum_{e in top-k, e held here} p_e E_e(x) + sigmoid(w_s . x) E_shared(x)
 
+With `moe_router` 'sigmoid_bias': s = sigmoid(W_r x); the experts are the
+top-k of s + b, b a balancing bias that no gradient trains; their weights are
+s (without b) of the chosen over their sum, times `moe_routed_scale`.  After
+an optimizer step b_e += `moe_bias_rate` * sign(mean(c) - c_e), c_e the step's
+count of tokens that chose e (`balance_bias`; arXiv:2408.15664).  With
+`moe_shared_gated` false the shared expert is added as it is.
+
 The layer holds experts [moe_first_expert, moe_first_expert + moe_experts_held)
 of a stated expert-parallel deployment: it routes over every expert, computes
 the terms of the sum that its own experts give (and the shared expert, which
@@ -52,8 +59,13 @@ _HEAD_ROOM = 2
 def init_moe(key: jax.Array, cfg) -> dict:
     keys = KeyChain(key)
     held, dim, width = cfg.moe_held, cfg.dim, cfg.moe_ff_dim
+    router = linear_init(keys.next(), dim, cfg.moe_experts, bias=False)
+    if cfg.moe_router == "sigmoid_bias":
+        router["bias"] = jnp.zeros((cfg.moe_experts,), jnp.float32)
+    elif cfg.moe_router != "softmax":
+        raise ValueError(f"moe_router {cfg.moe_router!r} is not valid; choose 'softmax' or 'sigmoid_bias'")
     params = {
-        "router": linear_init(keys.next(), dim, cfg.moe_experts, bias=False),
+        "router": router,
         "experts": {
             "wg": Initializer.uniform_fan_in(keys.next(), (held, dim, width), dim),
             "wu": Initializer.uniform_fan_in(keys.next(), (held, dim, width), dim),
@@ -66,8 +78,9 @@ def init_moe(key: jax.Array, cfg) -> dict:
             "wg": linear_init(keys.next(), dim, sw, bias=False),
             "wu": linear_init(keys.next(), dim, sw, bias=False),
             "wd": linear_init(keys.next(), sw, dim, bias=False),
-            "gate": linear_init(keys.next(), dim, 1, bias=False),
         }
+        if cfg.moe_shared_gated:
+            params["shared"]["gate"] = linear_init(keys.next(), dim, 1, bias=False)
     return params
 
 
@@ -78,10 +91,26 @@ def route(router: dict, cfg, x2: jnp.ndarray):
     step, and a flipped choice is a different expert's whole output."""
     logits = jnp.dot(x2.astype(jnp.float32), router["w"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
+    if cfg.moe_router == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+        # the bias decides WHO is chosen and never what a chosen expert weighs
+        biased = scores + jax.lax.stop_gradient(router["bias"].astype(jnp.float32))
+        _, ids = jax.lax.top_k(biased, cfg.moe_top_k)  # ties: the lower id first
+        weights = jnp.take_along_axis(scores, ids, axis=-1)
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True) * cfg.moe_routed_scale
+        return weights, ids.astype(jnp.int32)
     probs = jax.nn.softmax(logits, axis=-1)
     weights, ids = jax.lax.top_k(probs, cfg.moe_top_k)  # ties: the lower id first
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights, ids.astype(jnp.int32)
+
+
+def balance_bias(cfg, bias, counts):
+    """The bias's rule, once an optimizer step: toward the experts that fewer
+    tokens than the mean chose, by `moe_bias_rate` each (arXiv:2408.15664,
+    the sign form).  `counts`: (moe_experts,) choices of the step in this layer."""
+    counts = counts.astype(jnp.float32)
+    return bias + cfg.moe_bias_rate * jnp.sign(jnp.mean(counts) - counts).astype(bias.dtype)
 
 
 def _use_gmm_kernel() -> bool:
@@ -227,7 +256,9 @@ def moe_feed_forward(params: dict, cfg, x: jnp.ndarray,
     scalars: `moe_pairs_here` (pairs routed to held experts),
     `moe_load_max_over_mean` (the busiest held expert's rows over the mean) and
     `moe_overflow_share` (1 where the pairs outgrew `pair_rows` and more than
-    one chunk was walked, else 0: averaged over layer calls, a share)."""
+    one chunk was walked, else 0: averaged over layer calls, a share); under
+    a bias-balanced router also `moe_choice_counts`, the (moe_experts,) tokens
+    that chose each expert, held here or not (the router is whole here)."""
     b, n, dim = x.shape
     x2 = x.reshape(b * n, dim)
     held = cfg.moe_held
@@ -241,7 +272,7 @@ def moe_feed_forward(params: dict, cfg, x: jnp.ndarray,
     if "shared" in params:
         with jax.named_scope("shared_expert"):
             sh = params["shared"]
-            gate = jax.nn.sigmoid(linear(sh["gate"], x2).astype(jnp.float32))
+            gate = jax.nn.sigmoid(linear(sh["gate"], x2).astype(jnp.float32)) if "gate" in sh else 1.0
             out = out.astype(jnp.float32) \
                 + gate * _swiglu(sh["wg"], sh["wu"], sh["wd"], x2).astype(jnp.float32)
 
@@ -250,4 +281,7 @@ def moe_feed_forward(params: dict, cfg, x: jnp.ndarray,
         "moe_load_max_over_mean": jnp.max(sizes) / jnp.maximum(pairs_here / held, 1.0),
         "moe_overflow_share": (pairs_here > pair_rows(cfg, b * n)).astype(jnp.float32),
     }
+    if cfg.moe_router == "sigmoid_bias":
+        with jax.named_scope("moe_router"):
+            stats["moe_choice_counts"] = jnp.bincount(ids.reshape(-1), length=cfg.moe_experts)
     return out.astype(x.dtype).reshape(b, n, dim), stats

@@ -44,7 +44,7 @@ from dalle_pytorch_tpu.ops.shift import token_shift
 
 
 PATTERN_ATTN_TYPES = ("full", "axial_row", "axial_col", "conv_like", "sparse")
-HYBRID_ATTN_TYPES = ("gated_full", "gated_delta")
+HYBRID_ATTN_TYPES = ("gated_full", "gated_delta", "mla")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,7 +129,8 @@ class TransformerConfig:
     sparse_decode: bool = True
     # ---- the block as a parameter (hybrid trunks; training path only) ----
     # 'layernorm' (LayerNorm, the DALL-E block) | 'rmsnorm_zc' (zero-centred
-    # RMSNorm: x / sqrt(mean(x^2) + norm_eps) * (1 + w))
+    # RMSNorm: x / sqrt(mean(x^2) + norm_eps) * (1 + w)) | 'rmsnorm' (plain:
+    # ... * w, w initialised 1)
     norm: str = "layernorm"
     norm_eps: float = 1e-6  # RMSNorm only; LayerNorm keeps its 1e-5
     layer_scale: bool = True  # the per-channel LayerScale on each branch
@@ -155,6 +156,30 @@ class TransformerConfig:
     moe_shared_ff_dim: int = 0
     moe_experts_held: Optional[int] = None
     moe_first_expert: int = 0
+    # the router's form: 'softmax' (probabilities, top-k renormalised) |
+    # 'sigmoid_bias' (`topk_method` noaux_tc with one group: scores
+    # sigmoid(W_r x), the top-k of score + a balancing bias that no gradient
+    # trains, weights = the chosen scores over their sum x `moe_routed_scale`
+    # [`routed_scaling_factor`]); the bias moves by +-`moe_bias_rate` a step
+    # toward the under-loaded experts (models/moe.balance_bias)
+    moe_router: str = "softmax"
+    moe_routed_scale: float = 1.0
+    moe_bias_rate: float = 0.001
+    # the shared expert behind a sigmoid gate (True) or added as it is
+    moe_shared_gated: bool = True
+    # the first `dense_layers` [`first_k_dense_replace`] layers' feed-forward
+    # is a dense SwiGLU of width `dense_ff_dim` [`intermediate_size`], no router
+    dense_layers: int = 0
+    dense_ff_dim: int = 0
+    # `mla` layers (models/latent_attention.py): ranks of the query and the
+    # key/value latents [`q_lora_rank`, `kv_lora_rank`], a head's key width
+    # without and with rotary [`qk_nope_head_dim`, `qk_rope_head_dim`] and its
+    # value width [`v_head_dim`]
+    mla_q_rank: int = 0
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
 
     @property
     def inner_dim(self) -> int:
@@ -171,9 +196,17 @@ class TransformerConfig:
     @property
     def hybrid(self) -> bool:
         """Anything of the block that only the full-sequence training path
-        computes (see `refuse_hybrid`)."""
-        return (self.moe_experts > 0 or self.norm != "layernorm"
+        computes (see `refuse_hybrid`): `gated_delta` / `gated_full` / `mla`
+        layers, routed experts, leading dense SwiGLU layers, an RMSNorm."""
+        return (self.moe_experts > 0 or self.norm != "layernorm" or self.dense_layers > 0
                 or any(t in HYBRID_ATTN_TYPES for t in self.attn_types))
+
+    def ff_type(self, index: int) -> str:
+        """What layer `index`'s feed-forward IS: 'swiglu' (a leading dense
+        layer), 'moe' (routed experts) or 'geglu' (the DALL-E block's)."""
+        if index < self.dense_layers:
+            return "swiglu"
+        return "moe" if self.moe_experts else "geglu"
 
     @property
     def text_len(self) -> int:
@@ -216,15 +249,18 @@ def derive_layer_specs(cfg: TransformerConfig) -> List[LayerSpec]:
 
 def refuse_hybrid(cfg: TransformerConfig, what: str) -> None:
     """The one error of every entry point that cannot run a hybrid block
-    (`gated_delta` / `gated_full` layers, routed experts, RMSNorm): they are
-    computed by the full-sequence training path alone.  Serving them needs a
-    recurrent state beside the K/V cache and a one-token form of the delta
-    rule (ROADMAP.md, Queue 2); a wrong picture is worse than none."""
+    (`gated_delta` / `gated_full` / `mla` layers, routed experts, leading
+    dense SwiGLU layers, RMSNorm): they are computed by the full-sequence
+    training path alone.  Serving them needs a recurrent state beside the K/V
+    cache and a one-token form of the delta rule, a latent cache and the
+    absorbed decode form of `mla` (ROADMAP.md, Queue 2); a wrong picture is
+    worse than none."""
     if cfg.hybrid:
         raise NotImplementedError(
             f"{what} does not support this block (attn_types {cfg.attn_types}, "
-            f"norm {cfg.norm!r}, {cfg.moe_experts} routed experts): gated_delta / "
-            "gated_full layers, routed experts and RMSNorm run on the training path "
+            f"norm {cfg.norm!r}, {cfg.moe_experts} routed experts, {cfg.dense_layers} "
+            "leading dense layers): gated_delta / gated_full / mla layers, routed "
+            "experts, dense SwiGLU layers and RMSNorm run on the training path "
             "only (execution 'sequential' or 'remat', scan_layers off, no pipeline)")
 
 
@@ -238,12 +274,16 @@ def _note_hybrid_layers(cfg: TransformerConfig, specs, gmm_paths: Dict[str, int]
 
     obs_metrics.counter("train/gdn_layers").inc(
         sum(s.attn_type == "gated_delta" for s in specs))
-    if cfg.moe_experts:
-        obs_metrics.counter("train/moe_layers").inc(len(specs))
-        obs_metrics.counter("train/moe_experts_held").inc(cfg.moe_held * len(specs))
+    obs_metrics.counter("train/mla_layers").inc(sum(s.attn_type == "mla" for s in specs))
+    obs_metrics.counter("train/dense_ff_layers").inc(
+        sum(cfg.ff_type(s.index) == "swiglu" for s in specs))
+    routed = sum(cfg.ff_type(s.index) == "moe" for s in specs)  # by what each layer IS
+    if routed:
+        obs_metrics.counter("train/moe_layers").inc(routed)
+        obs_metrics.counter("train/moe_experts_held").inc(cfg.moe_held * routed)
         from dalle_pytorch_tpu.models.moe import pair_rows
 
-        obs_metrics.counter("train/moe_pair_rows").inc(pair_rows(cfg, tokens) * len(specs))
+        obs_metrics.counter("train/moe_pair_rows").inc(pair_rows(cfg, tokens) * routed)
         obs_metrics.counter("train/moe_gmm_kernel_calls").inc(gmm_paths["kernel"])
         obs_metrics.counter("train/moe_gmm_fallback_calls").inc(gmm_paths["fallback"])
 
@@ -287,11 +327,12 @@ def norm_init(cfg: TransformerConfig) -> dict:
     """The block's norm over `dim` (cfg.norm)."""
     if cfg.norm == "layernorm":
         return layer_norm_init(cfg.dim)
-    if cfg.norm != "rmsnorm_zc":
-        raise ValueError(f"norm {cfg.norm!r} is not valid; choose 'layernorm' or 'rmsnorm_zc'")
+    if cfg.norm not in ("rmsnorm_zc", "rmsnorm"):
+        raise ValueError(
+            f"norm {cfg.norm!r} is not valid; choose 'layernorm', 'rmsnorm_zc' or 'rmsnorm'")
     from dalle_pytorch_tpu.models.gated_layers import rms_norm_init
 
-    return rms_norm_init(cfg.dim)
+    return rms_norm_init(cfg.dim, zero_centered=cfg.norm == "rmsnorm_zc")
 
 
 def apply_norm(cfg: TransformerConfig, params: dict, x):
@@ -299,7 +340,7 @@ def apply_norm(cfg: TransformerConfig, params: dict, x):
         return layer_norm(params, x)
     from dalle_pytorch_tpu.models.gated_layers import rms_norm
 
-    return rms_norm(params, x, cfg.norm_eps)
+    return rms_norm(params, x, cfg.norm_eps, zero_centered=cfg.norm == "rmsnorm_zc")
 
 
 def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
@@ -313,13 +354,23 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
         if spec.attn_type in HYBRID_ATTN_TYPES and spec.attn_id not in shared_attn:
             from dalle_pytorch_tpu.models import gated_layers
 
-            init = (gated_layers.init_gated_delta if spec.attn_type == "gated_delta"
-                    else gated_layers.init_gated_full)
+            from dalle_pytorch_tpu.models.latent_attention import init_mla
+
+            init = {"gated_delta": gated_layers.init_gated_delta,
+                    "gated_full": gated_layers.init_gated_full,
+                    "mla": init_mla}[spec.attn_type]
             shared_attn[spec.attn_id] = init(keys.next(), cfg)
-        if cfg.moe_experts and spec.ff_id not in shared_ff:
+        ff_type = cfg.ff_type(spec.index)
+        if ff_type == "moe" and spec.ff_id not in shared_ff:
             from dalle_pytorch_tpu.models.moe import init_moe
 
             shared_ff[spec.ff_id] = init_moe(keys.next(), cfg)
+        if ff_type == "swiglu" and spec.ff_id not in shared_ff:
+            shared_ff[spec.ff_id] = {
+                "wg": linear_init(keys.next(), cfg.dim, cfg.dense_ff_dim, bias=False),
+                "wu": linear_init(keys.next(), cfg.dim, cfg.dense_ff_dim, bias=False),
+                "wd": linear_init(keys.next(), cfg.dense_ff_dim, cfg.dim, bias=False),
+            }
         if spec.attn_id not in shared_attn:
             # qkv columns are HEAD-MAJOR: [h0:(q|k|v), h1:(q|k|v), ...] — the
             # head axis carries the tp sharding, so splitting into q/k/v is
@@ -677,6 +728,7 @@ def _residual_branch(
     text_mode: bool = False,
     attn_type: str = "full",
     aux: Optional[dict] = None,
+    ff_type: Optional[str] = None,  # TransformerConfig.ff_type of the layer; None = the trunk's one kind
 ):
     """THE residual branch — PreShiftToken? -> PreNorm -> attn/ff -> sandwich?
     -> LayerScale — shared by full-sequence apply (unrolled and scanned), prefill and
@@ -706,6 +758,8 @@ def _residual_branch(
                 h = token_shift(h, cfg.seq_len, cfg.image_fmap_size)
     if kind == "attn" and attn_type in HYBRID_ATTN_TYPES:
         h = _hybrid_mixer(attn_params, cfg, h, attn_type)
+    elif kind == "ff" and ff_type == "swiglu":
+        h = _dense_swiglu(ff_params, h)
     elif kind == "ff" and cfg.moe_experts:
         h = _routed_feed_forward(ff_params, cfg, h, aux)
     elif kind == "attn":
@@ -741,8 +795,19 @@ def _hybrid_mixer(shared, cfg, x, attn_type: str):
 
     if attn_type == "gated_delta":
         return gated_layers.gated_delta_net(shared, cfg, x)
-    return gated_layers.gated_full_attention(
-        shared, cfg, x, use_flash=_use_flash(cfg, x.shape[1], None), mesh=_kernel_mesh(cfg))
+    use_flash, mesh = _use_flash(cfg, x.shape[1], None), _kernel_mesh(cfg)
+    if attn_type == "mla":
+        from dalle_pytorch_tpu.models.latent_attention import mla_attention
+
+        return mla_attention(shared, cfg, x, use_flash=use_flash, mesh=mesh)
+    return gated_layers.gated_full_attention(shared, cfg, x, use_flash=use_flash, mesh=mesh)
+
+
+@jax.named_scope("ff")
+def _dense_swiglu(shared, x):
+    """A leading dense layer's feed-forward: W_d (silu(W_g x) * (W_u x))."""
+    with jax.named_scope("dense_ff"):
+        return linear(shared["wd"], jax.nn.silu(linear(shared["wg"], x)) * linear(shared["wu"], x))
 
 
 @jax.named_scope("ff")
@@ -773,6 +838,7 @@ def _branch(params, cfg, spec, x, kind, rotary, pattern, key_mask, dkey, aux=Non
         dkey=dkey,
         attn_type=spec.attn_type,
         aux=aux,
+        ff_type=cfg.ff_type(spec.index),
     )
     return out
 
@@ -791,7 +857,9 @@ def apply_transformer(
 ):
     """x: (batch, n, dim) with n <= seq_len.  Full-sequence (training) mode.
     `return_stats`: also return the routed layers' load scalars, averaged
-    over the layers ({} for a dense feed-forward)."""
+    over the layers ({} for a dense feed-forward), and under a bias-balanced
+    router `moe_choice_counts`: {ff_id: (moe_experts,) tokens that chose each
+    expert in that layer}, what the bias's rule reads."""
     if cfg.hybrid and (cfg.scan_layers or cfg.pipeline_axis is not None
                        or cfg.seq_shard_axis is not None
                        or cfg.execution not in ("sequential", "remat")):
@@ -864,7 +932,7 @@ def apply_transformer(
 
     x = seq_constraint(x)
     gmm_paths = {"kernel": 0, "fallback": 0}
-    layer_stats = []
+    layer_stats, choice_counts = [], {}
     for spec in specs:
         akey = layer_keys[spec.index, 0] if has_dropout else None
         fkey = layer_keys[spec.index, 1] if has_dropout else None
@@ -882,13 +950,19 @@ def apply_transformer(
             x, stats = _remat_wrap(block, cfg)(x)
         else:
             x, stats = block(x)
+        for s in stats:
+            if "moe_choice_counts" in s:  # a vector of this layer's own, not a scalar to average
+                choice_counts[spec.ff_id] = choice_counts.get(spec.ff_id, 0) + s.pop("moe_choice_counts")
         layer_stats += stats
     if cfg.hybrid:
         _note_hybrid_layers(cfg, specs, gmm_paths, tokens=x.shape[0] * x.shape[1])
     if not return_stats:
         return x
-    return x, {k: sum(s[k] for s in layer_stats) / len(layer_stats)
-               for k in (layer_stats[0] if layer_stats else {})}
+    stats = {k: sum(s[k] for s in layer_stats) / len(layer_stats)
+             for k in (layer_stats[0] if layer_stats else {})}
+    if choice_counts:
+        stats["moe_choice_counts"] = choice_counts
+    return x, stats
 
 
 def _assert_scannable(cfg, specs):

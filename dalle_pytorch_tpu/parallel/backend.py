@@ -127,10 +127,11 @@ class DummyBackend(DistributedBackend):
 
     def _distribute(self, loss_fn, params, optimizer, training_data, lr_scheduler,
                     mesh_config, settings, use_mesh: bool = True,
-                    registry=None, **kwargs):
+                    registry=None, param_rule=None, **kwargs):
         mesh = make_mesh(mesh_config or MeshConfig()) if use_mesh else None
         init_fn, step_fn = make_train_step(
-            loss_fn, optimizer, mesh=mesh, settings=settings, registry=registry)
+            loss_fn, optimizer, mesh=mesh, settings=settings, registry=registry,
+            param_rule=param_rule)
         return init_fn(params), step_fn, training_data, lr_scheduler
 
     def _average_all(self, value):
@@ -186,10 +187,11 @@ class JaxBackend(DistributedBackend):
         multihost_utils.sync_global_devices("dalle_pytorch_tpu.barrier")
 
     def _distribute(self, loss_fn, params, optimizer, training_data, lr_scheduler,
-                    mesh_config, settings, registry=None, **kwargs):
+                    mesh_config, settings, registry=None, param_rule=None, **kwargs):
         mesh = make_mesh(mesh_config or MeshConfig())
         init_fn, step_fn = make_train_step(
-            loss_fn, optimizer, mesh=mesh, settings=settings, registry=registry)
+            loss_fn, optimizer, mesh=mesh, settings=settings, registry=registry,
+            param_rule=param_rule)
         return init_fn(params), step_fn, training_data, lr_scheduler
 
     def _average_all(self, value):

@@ -112,6 +112,23 @@ def _apply_updates_lowp(params, updates, key, dtype, stochastic: bool):
     return jax.tree_util.tree_unflatten(treedef, new)
 
 
+def _apply_param_rule(rule, old_params, new_params, rule_inputs: dict, finite):
+    """`new_params` with each parameter `rule_inputs` names ("a/b/c": a path
+    of dict keys) set to `rule(path, its value in old_params, its input)`;
+    where `finite` (None = always) is false, to its old value."""
+    def put(tree, keys, value):
+        return value if not keys else {**tree, keys[0]: put(tree[keys[0]], keys[1:], value)}
+
+    for path, rule_input in rule_inputs.items():
+        keys = path.split("/")
+        old = old_params
+        for k in keys:
+            old = old[k]
+        new = rule(path, old, rule_input).astype(old.dtype)
+        new_params = put(new_params, keys, new if finite is None else jnp.where(finite, new, old))
+    return new_params
+
+
 def make_train_step(
     loss_fn: Callable,  # (params, batch, key) -> scalar loss
     optimizer: optax.GradientTransformation,
@@ -119,8 +136,18 @@ def make_train_step(
     settings: StepSettings = StepSettings(),
     pspecs: Any = None,
     registry: Any = None,
+    param_rule: Optional[Callable] = None,
 ):
     """Build (init_fn, step_fn).
+
+    `param_rule(path, value, rule_input) -> new value`: a loss may name
+    parameters that no gradient trains and a rule moves once an optimizer
+    step.  It names them in its aux under `rule_inputs`, {the parameter's
+    path of dict keys in the tree, joined by "/": what its rule reads}, SUMMED over the
+    step's microbatches; after the optimizer update each named parameter
+    becomes `param_rule` of the value it had BEFORE the update and its input
+    (so whatever the optimizer did to it, nothing for a zero gradient under
+    Adam, is replaced), and a step the bad-step guard skips leaves it alone.
 
     `registry` (parallel/registry.PartitionRegistry, default the process
     default) is the ONE source of truth for where params and optimizer
@@ -248,7 +275,11 @@ def make_train_step(
         g = jax.tree_util.tree_map(
             lambda x: (x * mean).astype(settings.grad_dtype), g
         )
-        return g, l * mean, jax.tree_util.tree_map(lambda a: jnp.mean(a, axis=0), aux)
+        rule_inputs = aux.pop("rule_inputs", None)
+        aux = jax.tree_util.tree_map(lambda a: jnp.mean(a, axis=0), aux)
+        if rule_inputs is not None:  # a rule reads the whole step's, not a microbatch's mean
+            aux["rule_inputs"] = jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), rule_inputs)
+        return g, l * mean, aux
 
     # allow schedules that consume the loss (e.g. reduce_on_plateau)
     optimizer = optax.with_extra_args_support(optimizer)
@@ -295,6 +326,10 @@ def make_train_step(
         # labelled regions in xprof/TensorBoard traces of the step
         with jax.named_scope("fwd_bwd"):
             grads, loss, aux = grads_and_loss(state.params, batch, key, scale=scale)
+        rule_inputs = aux.pop("rule_inputs", {})
+        if rule_inputs and param_rule is None:
+            raise ValueError(f"the loss names parameters for a rule ({sorted(rule_inputs)}) "
+                             "and make_train_step was given no param_rule")
         with jax.named_scope("grad_norm"):
             # norm in f32 regardless of grad_dtype (per-leaf fused reductions,
             # no f32 copy of the gradient buffer is materialized)
@@ -344,6 +379,8 @@ def make_train_step(
             params, opt_state = do_update(
                 grads, inner_opt_state, state.params, round_key
             )
+        if rule_inputs:
+            params = _apply_param_rule(param_rule, state.params, params, rule_inputs, finite)
 
         if not ls_enabled:
             new_state = TrainState(state.step + 1, params, opt_state)

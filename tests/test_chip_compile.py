@@ -152,12 +152,87 @@ CASES["moe_grouped_down_proj_pair_rows"] = (
                     ((32,), jnp.int32)], 2)
 
 
+# the latent-attention trunk's cell (train_glm47_ep8), one microbatch of one
+# sequence: an `mla` layer's core, 20 heads of key width 192 + 64 = value width
+# 256 (the kernel takes one width for both) over 4,224 positions ...
+CASES["dense_full_d256_h20_seq4224"] = (
+    lambda: _grad_of(grid="dense"), [((1, 20, 4224, 256), jnp.bfloat16)] * 3, 3)
+# ... and the 8 held experts' grouped products over one chunk of the ranking
+# (`moe.pair_rows`: twice the 2,112 pairs expected at 8 of 64 experts top-4,
+# in row tiles: 4,224), width 1,536: the down projection's 1,536 inner columns
+# are no multiple of the kernel's 1,024-wide tile
+CASES["moe_grouped_up_proj_glm"] = (
+    _grouped_grad, [((4224, 2048), jnp.bfloat16), ((8, 2048, 1536), jnp.bfloat16),
+                    ((8,), jnp.int32)], 2)
+CASES["moe_grouped_down_proj_glm"] = (
+    _grouped_grad, [((4224, 1536), jnp.bfloat16), ((8, 1536, 2048), jnp.bfloat16),
+                    ((8,), jnp.int32)], 2)
+
+
 @pytest.mark.parametrize("name", list(CASES))
 def test_kernel_compiles_for_v5e(one_chip, name):
     build, shapes, n_kernels = CASES[name]
     text = _compile(build(), one_chip, *shapes)
     assert text.count("tpu_custom_call") >= n_kernels, (
         f"{name}: expected >= {n_kernels} Pallas custom calls in the compiled program")
+
+
+@pytest.mark.parametrize("microbatch", [1, 2])
+def test_glm_cell_step_fits_the_chip_at_microbatch_1_and_not_at_2(one_chip, microbatch):
+    """`train_glm47_ep8`'s own optimizer step (`make_train_step` on the cell's
+    configuration file, recipe and `param_rule`, as `kinds/train_steps_mtp.py`
+    builds it) over an `eval_shape`'d state: the compiler takes it at the
+    cell's microbatch, 1 x 4 (15.86e9 bytes of the chip's 16.9e9: a later
+    change to the block that tips it over fails HERE and not on the chip),
+    and refuses microbatch 2 x 2 for the chip's memory, which is why the
+    traffic file says 1 (the largest of 4, 2, 1 that compiles; when 2 starts
+    to fit, that file is due a change).  Nothing runs and nothing is allocated."""
+    import json
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmark.harness import build
+    from benchmark.kinds.train_steps import _optimizer
+    from dalle_pytorch_tpu.models import dalle as dalle_mod
+    from dalle_pytorch_tpu.parallel.train_step import StepSettings, make_train_step
+
+    sizes = json.loads((root / "benchmark" / "configs" / "glm47_flash_ep8_d5.json").read_text())
+    traffic = json.loads((root / "benchmark" / "traffic" / "steps_adam_b4_fresh.json").read_text())
+    assert (traffic["microbatch"], traffic["grad_accum"]) == (1, 4)
+    recipe = sizes["train_recipe"]
+    # what `attn_kernel` "auto" answers on a TPU backend
+    cfg = build.dalle_config(sizes, execution=recipe["execution"], scan_layers=recipe["scan_layers"],
+                             attn_kernel="flash")
+    assert cfg.execution == "sequential"
+
+    def loss_fn(p, b, key):
+        return dalle_mod.forward(p, cfg, b["text"], b["image_codes"], return_loss=True, return_aux=True)
+
+    settings = StepSettings(compute_dtype=build.dtype(recipe["compute_dtype"]),
+                            grad_dtype=build.dtype(recipe["grad_dtype"]), grad_accum=4 // microbatch)
+    init_fn, step_fn = make_train_step(loss_fn, _optimizer(recipe), settings=settings,
+                                       param_rule=dalle_mod.param_rule(cfg))
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        lambda a: described(a.shape, a.dtype),
+        jax.eval_shape(lambda k: init_fn(dalle_mod.init_dalle(k, cfg)), jax.random.PRNGKey(0)))
+    batch = {"text": described((4, cfg.text_seq_len), jnp.int32),
+             "image_codes": described((4, cfg.image_seq_len), jnp.int32)}
+    with jax.default_matmul_precision("default"):  # the suite's "highest" is for CPU comparisons
+        lowered = step_fn.lower(state, batch, described((2,), jnp.uint32))
+        if microbatch == 1:
+            text = lowered.compile().as_text()
+            # six latent-attention blocks x (forward, dq, dkv) + five routed layers' grouped products
+            assert text.count("tpu_custom_call") >= 18 + 15
+        else:
+            with pytest.raises(Exception, match="Ran out of memory in memory space hbm"):
+                lowered.compile()
 
 
 @pytest.fixture(scope="module")
