@@ -13,6 +13,16 @@ accumulating over key tiles) and a dk/dv pass (grid over key tiles,
 accumulating over query tiles), both recomputing probabilities from the saved
 logsumexp.
 
+Operands: every tile product (`_dot`) takes its operands in the type q, k, v
+and dO arrived in and accumulates in float32.  bfloat16 in: the tiles go to the
+matrix unit as read, the softmax scale multiplies the float32 scores (and dq /
+dk when they are written), and p and dS are rounded to the operand type once,
+right before the products that consume them, as `ops.attention.attend` rounds
+its probabilities.  float32 in: float32 operands.  Row maxima, sums, `lse`,
+`delta` and every accumulator are float32 either way.  Nothing selects this
+but `q.dtype`; `flash_attention` counts the calls of each kind while it is
+traced (`kernels/flash_calls_16bit_operands`, `kernels/flash_calls_32bit_operands`).
+
 On CPU (tests) kernels run in interpret mode; any platform other than cpu or
 tpu is an error, never an interpreter.
 """
@@ -29,9 +39,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dalle_pytorch_tpu.observability import health as health_mod
+from dalle_pytorch_tpu.observability import metrics as obs_metrics
 
-DEFAULT_BLOCK_Q = 256  # 256x256 tiles measured ~5% faster per train step than
-DEFAULT_BLOCK_K = 256  # 128x128 at seq 1280 on v5e (block shrinks to divide n)
+# 256x256 tiles measured ~5% faster per train step than 128x128 at seq 1280 on
+# v5e.  `resolve_block` halves a block until it divides n, so every sequence
+# the benchmark's cells train on runs 128x128 tiles: 1,152 = 9 x 128 and
+# 4,224 = 33 x 128 have no divisor 256.
+DEFAULT_BLOCK_Q = 256
+DEFAULT_BLOCK_K = 256
 _LANES = 128  # TPU lane width; lse/delta rows are stored broadcast over lanes
 _NEG = -1e30
 
@@ -74,22 +89,46 @@ def resolve_block(n: int, block: int) -> int:
     )
 
 
-def _tile_live(causal: bool, use_mask: bool, live_ref, i, j, block_q: int,
-               block_k: int, head=None):
+def _when_live(compute, live_ref, i, j, head, *, causal, use_mask, block_q,
+               block_k, **_):
+    """Run `compute` on the dense grid's tile (i, j) unless causality or the
+    pattern's liveness table kills it."""
+    if not (causal or use_mask):
+        return compute()
     live = True
     if causal:
         live = j * block_k <= i * block_q + block_q - 1
     if use_mask:
         cell = live_ref[i, j] if head is None else live_ref[head, i, j]
         live = jnp.logical_and(live, cell > 0)
-    return live
+    pl.when(live)(compute)
 
 
-def _masked_scores(q32, k32, mask_ref, kmask_ref, i, j, *, causal, block_q,
+_NT = ((1,), (1,))  # a @ b.T
+_NN = ((1,), (0,))  # a @ b
+_TN = ((0,), (0,))  # a.T @ b
+
+
+def _is_16bit(dtype) -> bool:
+    return jnp.dtype(dtype).itemsize < 4
+
+
+def _dot(a, b, contract):
+    """One tile product on the matrix unit: operands in the type they have,
+    float32 result.  A 16-bit product states its own precision: the ambient
+    default may be "highest" (tests/conftest.py), which Mosaic's 16-bit matmul
+    refuses, and the program must not depend on it.  float32 operands take
+    the ambient precision, as they always did."""
+    precision = jax.lax.Precision.DEFAULT if _is_16bit(a.dtype) else None
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _masked_scores(q, k, mask_ref, kmask_ref, i, j, *, scale, causal, block_q,
                    block_k, use_mask, use_kmask):
-    s = jax.lax.dot_general(
-        q32, k32, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    """Scaled, masked float32 scores of one (block_q, block_k) tile; q and k
+    as read.  Every kernel, dense and compacted, builds its scores here."""
+    s = _dot(q, k, _NT) * scale
     if causal:
         q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
@@ -127,12 +166,69 @@ def _live_tile_fraction(live, nq: int, nk: int, block_q: int, block_k: int,
 
 
 # ---------------------------------------------------------------------------
+# one live tile's work, shared by the dense and the compacted kernels: the two
+# grids differ in how a step finds its tile, never in what it does there (which
+# is what keeps them bit-exact against each other at any operand type).
+# `sk` is the static score keywords of `_masked_scores`.
+# ---------------------------------------------------------------------------
+
+def _online_softmax_tile(q_ref, k_ref, v_ref, mask_ref, kmask_ref, i, j,
+                         m_scr, l_scr, acc_scr, **sk):
+    s = _masked_scores(q_ref[0], k_ref[0], mask_ref, kmask_ref, i, j, **sk)
+    m_prev = m_scr[:, :1]
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur)
+    l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    v = v_ref[0]
+    acc_scr[:] = acc_scr[:] * alpha + _dot(p.astype(v.dtype), v, _NN)
+    m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def _write_out(o_ref, lse_ref, row_max, l_scr, acc_scr):
+    l = jnp.maximum(l_scr[:, :1], 1e-30)
+    o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+    lse_ref[0] = jnp.broadcast_to(row_max + jnp.log(l), lse_ref.shape[1:])
+
+
+def _probs_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                  kmask_ref, i, j, **sk):
+    """(p, dS / scale) of one tile, float32, from the saved logsumexp."""
+    s = _masked_scores(q_ref[0], k_ref[0], mask_ref, kmask_ref, i, j, **sk)
+    p = jnp.exp(s - lse_ref[0][:, :1])
+    dp = _dot(do_ref[0], v_ref[0], _NT)
+    return p, p * (dp - delta_ref[0][:, :1])
+
+
+def _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+             kmask_ref, i, j, dq_scr, **sk):
+    _, ds = _probs_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          mask_ref, kmask_ref, i, j, **sk)
+    k = k_ref[0]
+    dq_scr[:] = dq_scr[:] + _dot(ds.astype(k.dtype), k, _NN)
+
+
+def _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+              kmask_ref, i, j, dk_scr, dv_scr, **sk):
+    p, ds = _probs_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          mask_ref, kmask_ref, i, j, **sk)
+    q, do = q_ref[0], do_ref[0]
+    dv_scr[:] = dv_scr[:] + _dot(p.astype(do.dtype), do, _TN)
+    dk_scr[:] = dk_scr[:] + _dot(ds.astype(q.dtype), q, _TN)
+
+
+def _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, scale):
+    dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, live_ref, kmask_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, causal, block_q, block_k, scale,
-                use_mask, use_kmask, h, per_head):
+                m_scr, l_scr, acc_scr, *, h, per_head, **sk):
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -145,30 +241,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, live_ref, kmask_ref, o_ref, lse_r
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def _compute():
-        q32 = q_ref[0].astype(jnp.float32) * scale
-        s = _masked_scores(q32, k_ref[0].astype(jnp.float32), mask_ref, kmask_ref, i, j,
-                           causal=causal, block_q=block_q, block_k=block_k,
-                           use_mask=use_mask, use_kmask=use_kmask)
-        m_prev = m_scr[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        _online_softmax_tile(q_ref, k_ref, v_ref, mask_ref, kmask_ref, i, j,
+                             m_scr, l_scr, acc_scr, **sk)
 
-    pl.when(_tile_live(causal, use_mask, live_ref, i, j, block_q, block_k, head))(_compute) \
-        if (causal or use_mask) else _compute()
+    _when_live(_compute, live_ref, i, j, head, **sk)
 
     @pl.when(j == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(m_scr[:, :1] + jnp.log(l), lse_ref.shape[1:])
+        _write_out(o_ref, lse_ref, m_scr[:, :1], l_scr, acc_scr)
 
 
 def _dummy_specs_args(use_mask, mask, live, nq, nk, block_q, block_k,
@@ -285,8 +365,7 @@ def _flash_fwd(q, k, v, mask, live, kmask, h, causal, scale, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, live_ref,
-               kmask_ref, dq_ref, dq_scr, *, causal, block_q, block_k, scale,
-               use_mask, use_kmask, h, per_head):
+               kmask_ref, dq_ref, dq_scr, *, h, per_head, **sk):
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -297,32 +376,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, live_r
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def _compute():
-        q32 = q_ref[0].astype(jnp.float32) * scale
-        s = _masked_scores(q32, k_ref[0].astype(jnp.float32), mask_ref, kmask_ref, i, j,
-                           causal=causal, block_q=block_q, block_k=block_k,
-                           use_mask=use_mask, use_kmask=use_kmask)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jax.lax.dot_general(
-            do_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0][:, :1])
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds, k_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                 kmask_ref, i, j, dq_scr, **sk)
 
-    pl.when(_tile_live(causal, use_mask, live_ref, i, j, block_q, block_k, head))(_compute) \
-        if (causal or use_mask) else _compute()
+    _when_live(_compute, live_ref, i, j, head, **sk)
 
     @pl.when(j == nk - 1)
     def _finalize():
-        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[:] * sk["scale"]).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, live_ref,
-                kmask_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, causal, block_q,
-                block_k, scale, use_mask, use_kmask, h, per_head):
+                kmask_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, h, per_head, **sk):
     # grid: (bh, key tile j, query tile i) — accumulate over query tiles
     j = pl.program_id(1)
     i = pl.program_id(2)
@@ -335,31 +400,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, live_
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def _compute():
-        q32 = q_ref[0].astype(jnp.float32) * scale
-        s = _masked_scores(q32, k_ref[0].astype(jnp.float32), mask_ref, kmask_ref, i, j,
-                           causal=causal, block_q=block_q, block_k=block_k,
-                           use_mask=use_mask, use_kmask=use_kmask)
-        p = jnp.exp(s - lse_ref[0][:, :1])  # (bq, bk)
-        do32 = do_ref[0].astype(jnp.float32)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do32, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do32, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0][:, :1])
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q32, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
+        _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                  kmask_ref, i, j, dk_scr, dv_scr, **sk)
 
-    pl.when(_tile_live(causal, use_mask, live_ref, i, j, block_q, block_k, head))(_compute) \
-        if (causal or use_mask) else _compute()
+    _when_live(_compute, live_ref, i, j, head, **sk)
 
     @pl.when(i == nq - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sk["scale"])
 
 
 @jax.named_scope("flash_attn_bwd")
@@ -537,8 +585,7 @@ def _mask_args(mask, use_kmask, kmask):
 
 def _fwd_kernel_compact(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
                         q_ref, k_ref, v_ref, mask_ref, kmask_ref, o_ref, lse_ref,
-                        m_scr, l_scr, acc_scr, *, causal, block_q, block_k,
-                        scale, use_mask, use_kmask, h, per_head):
+                        m_scr, l_scr, acc_scr, *, h, per_head, **sk):
     t = pl.program_id(1)
     hid = pl.program_id(0) % h if per_head else 0
     i = _tab(qr_ref, hid, t)
@@ -552,33 +599,17 @@ def _fwd_kernel_compact(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
 
     @pl.when(_tab(va_ref, hid, t) == 1)
     def _compute():
-        q32 = q_ref[0].astype(jnp.float32) * scale
-        s = _masked_scores(q32, k_ref[0].astype(jnp.float32), mask_ref, kmask_ref, i, j,
-                           causal=causal, block_q=block_q, block_k=block_k,
-                           use_mask=use_mask, use_kmask=use_kmask)
-        m_prev = m_scr[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        _online_softmax_tile(q_ref, k_ref, v_ref, mask_ref, kmask_ref, i, j,
+                             m_scr, l_scr, acc_scr, **sk)
 
     @pl.when(_tab(la_ref, hid, t) == 1)
     def _finalize():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(m_scr[:, :1] + jnp.log(l), lse_ref.shape[1:])
+        _write_out(o_ref, lse_ref, m_scr[:, :1], l_scr, acc_scr)
 
 
 def _max_kernel_compact(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
                         q_ref, k_ref, mask_ref, kmask_ref, gmax_ref, m_scr, *,
-                        causal, block_q, block_k, scale, use_mask, use_kmask,
-                        h, per_head):
+                        h, per_head, **sk):
     """VFA pass 1: per-row global score maxima over the live set (scores
     only — no exp, no PV matmul)."""
     t = pl.program_id(1)
@@ -592,10 +623,7 @@ def _max_kernel_compact(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
 
     @pl.when(_tab(va_ref, hid, t) == 1)
     def _compute():
-        q32 = q_ref[0].astype(jnp.float32) * scale
-        s = _masked_scores(q32, k_ref[0].astype(jnp.float32), mask_ref, kmask_ref, i, j,
-                           causal=causal, block_q=block_q, block_k=block_k,
-                           use_mask=use_mask, use_kmask=use_kmask)
+        s = _masked_scores(q_ref[0], k_ref[0], mask_ref, kmask_ref, i, j, **sk)
         m_scr[:] = jnp.broadcast_to(
             jnp.maximum(m_scr[:, :1], jnp.max(s, axis=-1, keepdims=True)),
             m_scr.shape,
@@ -608,8 +636,7 @@ def _max_kernel_compact(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
 
 def _fwd_kernel_compact_vfa(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
                             q_ref, k_ref, v_ref, mask_ref, kmask_ref, gmax_ref,
-                            o_ref, lse_ref, l_scr, acc_scr, *, causal, block_q,
-                            block_k, scale, use_mask, use_kmask, h, per_head):
+                            o_ref, lse_ref, l_scr, acc_scr, *, h, per_head, **sk):
     """VFA pass 2: accumulation against the precomputed global maximum — the
     running max is global from the start, so the per-tile accumulator rescale
     (alpha) drops out entirely."""
@@ -625,24 +652,16 @@ def _fwd_kernel_compact_vfa(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
 
     @pl.when(_tab(va_ref, hid, t) == 1)
     def _compute():
-        q32 = q_ref[0].astype(jnp.float32) * scale
-        s = _masked_scores(q32, k_ref[0].astype(jnp.float32), mask_ref, kmask_ref, i, j,
-                           causal=causal, block_q=block_q, block_k=block_k,
-                           use_mask=use_mask, use_kmask=use_kmask)
+        s = _masked_scores(q_ref[0], k_ref[0], mask_ref, kmask_ref, i, j, **sk)
         p = jnp.exp(s - gmax_ref[0][:, :1])
         l_scr[:] = jnp.broadcast_to(
             l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True), l_scr.shape)
-        acc_scr[:] = acc_scr[:] + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        v = v_ref[0]
+        acc_scr[:] = acc_scr[:] + _dot(p.astype(v.dtype), v, _NN)
 
     @pl.when(_tab(la_ref, hid, t) == 1)
     def _finalize():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(
-            gmax_ref[0][:, :1] + jnp.log(l), lse_ref.shape[1:])
+        _write_out(o_ref, lse_ref, gmax_ref[0][:, :1], l_scr, acc_scr)
 
 
 @jax.named_scope("flash_attn_fwd_compact")
@@ -738,8 +757,7 @@ def _flash_fwd_compact(q, k, v, mask, kmask, tabs, h, causal, scale, block_q,
 
 def _dq_kernel_compact(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
                        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       mask_ref, kmask_ref, dq_ref, dq_scr, *, causal, block_q,
-                       block_k, scale, use_mask, use_kmask, h, per_head):
+                       mask_ref, kmask_ref, dq_ref, dq_scr, *, h, per_head, **sk):
     t = pl.program_id(1)
     hid = pl.program_id(0) % h if per_head else 0
     i = _tab(qr_ref, hid, t)
@@ -751,31 +769,18 @@ def _dq_kernel_compact(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
 
     @pl.when(_tab(va_ref, hid, t) == 1)
     def _compute():
-        q32 = q_ref[0].astype(jnp.float32) * scale
-        s = _masked_scores(q32, k_ref[0].astype(jnp.float32), mask_ref, kmask_ref, i, j,
-                           causal=causal, block_q=block_q, block_k=block_k,
-                           use_mask=use_mask, use_kmask=use_kmask)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jax.lax.dot_general(
-            do_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0][:, :1])
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds, k_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                 kmask_ref, i, j, dq_scr, **sk)
 
     @pl.when(_tab(la_ref, hid, t) == 1)
     def _finalize():
-        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[:] * sk["scale"]).astype(dq_ref.dtype)
 
 
 def _dkv_kernel_compact(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
                         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         mask_ref, kmask_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                        causal, block_q, block_k, scale, use_mask, use_kmask,
-                        h, per_head):
+                        h, per_head, **sk):
     """Column-major traversal: the scalars are the TRANSPOSED tables
     (qrowT..validT) — first/last mark a key column's first/last live query
     tile, and dk/dv accumulate per key tile exactly like the dense kernel."""
@@ -791,28 +796,12 @@ def _dkv_kernel_compact(qr_ref, kc_ref, fr_ref, la_ref, va_ref,
 
     @pl.when(_tab(va_ref, hid, t) == 1)
     def _compute():
-        q32 = q_ref[0].astype(jnp.float32) * scale
-        s = _masked_scores(q32, k_ref[0].astype(jnp.float32), mask_ref, kmask_ref, i, j,
-                           causal=causal, block_q=block_q, block_k=block_k,
-                           use_mask=use_mask, use_kmask=use_kmask)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        do32 = do_ref[0].astype(jnp.float32)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do32, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do32, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0][:, :1])
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q32, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
+        _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                  kmask_ref, i, j, dk_scr, dv_scr, **sk)
 
     @pl.when(_tab(la_ref, hid, t) == 1)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        _write_dkv(dk_ref, dv_ref, dk_scr, dv_scr, sk["scale"])
 
 
 @jax.named_scope("flash_attn_bwd_compact")
@@ -1024,6 +1013,10 @@ def flash_attention(
     b, h, n, d = q.shape
     if scale is None:
         scale = d ** -0.5
+    # which operands the kernels' products take is decided here, by the input
+    # alone, while the program is traced: one count a call
+    bits = 16 if _is_16bit(q.dtype) else 32
+    obs_metrics.counter(f"kernels/flash_calls_{bits}bit_operands").inc()
     block_q = resolve_block(n, block_q)
     block_k = resolve_block(n, block_k)
     if grid not in ("auto", "dense", "compact"):

@@ -5,11 +5,15 @@ topology (on-chip-measurement guide §2, rehearsal 3): interpret mode cannot
 see what it refuses — a block not aligned to the tiling, a kernel over its
 VMEM budget.  Shapes are chip_smoke.py's real ones (b4, h16, n1280, d128,
 bf16, 256-tiles).  Nothing runs, so nothing here is a result or a time; a
-compile that passes is not a chip run.  The whole file skips where the
-topology cannot be described.  Plus: the hardware table finds the kind the
-v5e reports and refuses a kind it does not know."""
+compile that passes is not a chip run.  Every test that compiles skips where
+the topology cannot be described.  Plus: each train cell's step traced at its
+real sizes for the operands its flash calls take, and the hardware table,
+which finds the kind the v5e reports and refuses a kind it does not know."""
+import base64
+import json
 import os
 import re
+from pathlib import Path
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -26,6 +30,7 @@ from dalle_pytorch_tpu.models import moe
 from dalle_pytorch_tpu.models.transformer import TransformerConfig, _pattern_for
 
 B, H, N, D = 4, 16, 1280, 128  # the smoke's attention shape (fmap 32, text 256)
+ROOT = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(scope="module")
@@ -53,9 +58,48 @@ def _pattern(kind, heads=H, per_head=False):
     return np.asarray(_pattern_for(cfg, kind), bool)
 
 
-def _compile(fn, sharding, *shapes_dtypes):
+def _compile(fn, sharding, *shapes_dtypes, precision="default"):
+    """Compiled under the matmul precision the cells run with, the default:
+    tests/conftest.py's "highest" is for comparisons on the CPU, and a kernel
+    compiled under it (`contract_precision<fp32>` on every float32 product;
+    refused outright by a bfloat16 one that does not state its own) is not the
+    program that runs."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes_dtypes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    with jax.default_matmul_precision(precision):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash_bodies(text):
+    """[(kernel name, its Mosaic body as generic MLIR text)] for every flash
+    kernel of a compiled program: the custom call carries the body as base64
+    of MLIR bytecode in the `stable_mosaic` dialect, which parses as
+    unregistered operations."""
+    from jax.extend.mlir import ir
+
+    out = []
+    for name, body in re.findall(r'%(flash_\w+?)[.\d]* = [^\n]*?"body":"([A-Za-z0-9+/=]+)"', text):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            out.append((name, str(ir.Module.parse(base64.b64decode(body)))))
+    return out
+
+
+def _check_16bit_products(text, n_kernels):
+    """What tests/test_flash.py checks on the jaxpr, on the program the chip's
+    compiler was handed: every `tpu.matmul` of every flash kernel takes
+    bfloat16 operands and gives float32, with no precision attribute, and no
+    bfloat16 vector is widened (`arith.extf`) anywhere in a body."""
+    bodies = _flash_bodies(text)
+    assert len(bodies) >= n_kernels, [n for n, _ in bodies]
+    for name, mlir in bodies:
+        products = [ln for ln in mlir.splitlines() if '"stable_mosaic.tpu.matmul"' in ln]
+        assert products, name
+        for ln in products:
+            lhs, rhs, acc, res = re.findall(r"vector<[\dx]+x(\w+)>", ln[ln.rindex(" : "):])
+            assert (lhs, rhs, acc, res) == ("bf16", "bf16", "f32", "f32"), (name, ln[-160:])
+            assert "precision" not in ln, (name, ln[:200])
+        assert [ln for ln in mlir.splitlines() if "arith.extf" in ln and "bf16" in ln] == [], name
 
 
 def _grad_of(**kw):
@@ -126,13 +170,7 @@ def _grouped_grad():
     def loss(lhs, rhs, sizes):
         return moe.grouped_matmul(lhs, rhs, sizes).astype(jnp.float32).sum()
 
-    def grads(lhs, rhs, sizes):
-        # tests/conftest.py asks every matmul for "highest", which the kernel's
-        # bfloat16 product refuses; the program runs with the default
-        with jax.default_matmul_precision("default"):
-            return jax.grad(loss, argnums=(0, 1))(lhs, rhs, sizes)
-
-    return grads
+    return jax.grad(loss, argnums=(0, 1))
 
 
 CASES["moe_grouped_up_proj"] = (
@@ -175,64 +213,112 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     text = _compile(build(), one_chip, *shapes)
     assert text.count("tpu_custom_call") >= n_kernels, (
         f"{name}: expected >= {n_kernels} Pallas custom calls in the compiled program")
+    if name.startswith(("dense_", "compact_")):  # the flash cases on bfloat16 inputs
+        assert shapes[0][1] == jnp.bfloat16
+        _check_16bit_products(text, n_kernels)
 
 
-@pytest.mark.parametrize("microbatch", [1, 2])
-def test_glm_cell_step_fits_the_chip_at_microbatch_1_and_not_at_2(one_chip, microbatch):
-    """`train_glm47_ep8`'s own optimizer step (`make_train_step` on the cell's
-    configuration file, recipe and `param_rule`, as `kinds/train_steps_mtp.py`
-    builds it) over an `eval_shape`'d state: the compiler takes it at the
-    cell's microbatch, 1 x 4 (15.86e9 bytes of the chip's 16.9e9: a later
-    change to the block that tips it over fails HERE and not on the chip),
-    and refuses microbatch 2 x 2 for the chip's memory, which is why the
-    traffic file says 1 (the largest of 4, 2, 1 that compiles; when 2 starts
-    to fit, that file is due a change).  Nothing runs and nothing is allocated."""
-    import json
+def test_flash_16bit_products_state_their_own_precision(one_chip):
+    """Under this suite's ambient "highest" the bfloat16 kernels still compile
+    (Mosaic refuses a 16-bit product asked for fp32 contraction: "Bad lhs
+    type"), because `_dot` states DEFAULT on 16-bit operands: the program does
+    not depend on the ambient setting.  The float32 kernels follow it, as
+    they always did."""
+    _check_16bit_products(_compile(_grad_of(grid="dense"), one_chip, *QKV, precision="highest"), 3)
+    f32 = [((B, 8, 256, 64), jnp.float32)] * 3
+    text = _compile(_grad_of(grid="dense"), one_chip, *f32, precision="highest")
+    assert all("contract_precision<fp32>" in mlir for _, mlir in _flash_bodies(text))
+
+
+def _cell_step(workload, described, microbatch=None):
+    """(step_fn, state, batch, key) of a train cell of BENCHMARK.json: the
+    program's own `make_train_step` on the cell's configuration file, recipe,
+    traffic and `param_rule`, as `benchmark/kinds/train_steps*.py` build it,
+    over an `eval_shape`'d state whose leaves `described(shape, dtype)` makes.
+    `attn_kernel` "flash" is what "auto" answers on a TPU backend."""
     import sys
-    from pathlib import Path
 
-    root = Path(__file__).resolve().parents[1]
-    if str(root) not in sys.path:
-        sys.path.insert(0, str(root))
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
     from benchmark.harness import build
     from benchmark.kinds.train_steps import _optimizer
     from dalle_pytorch_tpu.models import dalle as dalle_mod
     from dalle_pytorch_tpu.parallel.train_step import StepSettings, make_train_step
 
-    sizes = json.loads((root / "benchmark" / "configs" / "glm47_flash_ep8_d5.json").read_text())
-    traffic = json.loads((root / "benchmark" / "traffic" / "steps_adam_b4_fresh.json").read_text())
-    assert (traffic["microbatch"], traffic["grad_accum"]) == (1, 4)
+    manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cell = next(w for w in manifest["workloads"] if w["name"] == workload)
+    config = next(c for c in manifest["configs"] if c["name"] == cell["config"])
+    sizes = json.loads((ROOT / config["file"]).read_text())
+    traffic = json.loads((ROOT / "benchmark" / "traffic" / (cell["traffic"] + ".json")).read_text())
     recipe = sizes["train_recipe"]
-    # what `attn_kernel` "auto" answers on a TPU backend
     cfg = build.dalle_config(sizes, execution=recipe["execution"], scan_layers=recipe["scan_layers"],
-                             attn_kernel="flash")
-    assert cfg.execution == "sequential"
+                             remat_policy=recipe.get("remat_policy", "full"), attn_kernel="flash")
+    batch_size = int(traffic["microbatch"]) * int(traffic["grad_accum"])
+    microbatch = microbatch or int(traffic["microbatch"])
+    with_aux = traffic["kind"] == "train_steps_mtp"
 
     def loss_fn(p, b, key):
-        return dalle_mod.forward(p, cfg, b["text"], b["image_codes"], return_loss=True, return_aux=True)
+        return dalle_mod.forward(p, cfg, b["text"], b["image_codes"], return_loss=True,
+                                 return_aux=with_aux)
 
+    param_dtype = build.dtype(recipe["param_dtype"])
     settings = StepSettings(compute_dtype=build.dtype(recipe["compute_dtype"]),
-                            grad_dtype=build.dtype(recipe["grad_dtype"]), grad_accum=4 // microbatch)
+                            grad_dtype=build.dtype(recipe["grad_dtype"]),
+                            grad_accum=batch_size // microbatch,
+                            param_dtype=param_dtype if param_dtype != jnp.float32 else None)
     init_fn, step_fn = make_train_step(loss_fn, _optimizer(recipe), settings=settings,
-                                       param_rule=dalle_mod.param_rule(cfg))
-
-    def described(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+                                       param_rule=dalle_mod.param_rule(cfg) if with_aux else None)
     state = jax.tree_util.tree_map(
         lambda a: described(a.shape, a.dtype),
         jax.eval_shape(lambda k: init_fn(dalle_mod.init_dalle(k, cfg)), jax.random.PRNGKey(0)))
-    batch = {"text": described((4, cfg.text_seq_len), jnp.int32),
-             "image_codes": described((4, cfg.image_seq_len), jnp.int32)}
+    batch = {"text": described((batch_size, cfg.text_seq_len), jnp.int32),
+             "image_codes": described((batch_size, cfg.image_seq_len), jnp.int32)}
+    return cfg, step_fn, state, batch, described((2,), jnp.uint32)
+
+
+@pytest.mark.parametrize("microbatch", [1, 2])
+def test_glm_cell_step_fits_the_chip_at_microbatch_1_and_not_at_2(one_chip, microbatch):
+    """`train_glm47_ep8`'s own optimizer step over an `eval_shape`'d state: the
+    compiler takes it at the cell's microbatch, 1 x 4 (15.86e9 bytes of the
+    chip's 16.9e9: a later change to the block that tips it over fails HERE
+    and not on the chip), and refuses microbatch 2 x 2 for the chip's memory,
+    which is why the traffic file says 1 (the largest of 4, 2, 1 that
+    compiles; when 2 starts to fit, that file is due a change).  Nothing runs
+    and nothing is allocated."""
+    cfg, step_fn, state, batch, key = _cell_step(
+        "train_glm47_ep8", lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip),
+        microbatch=microbatch)
+    assert cfg.execution == "sequential" and batch["text"].shape[0] == 4
     with jax.default_matmul_precision("default"):  # the suite's "highest" is for CPU comparisons
-        lowered = step_fn.lower(state, batch, described((2,), jnp.uint32))
+        lowered = step_fn.lower(state, batch, key)
         if microbatch == 1:
             text = lowered.compile().as_text()
             # six latent-attention blocks x (forward, dq, dkv) + five routed layers' grouped products
             assert text.count("tpu_custom_call") >= 18 + 15
+            _check_16bit_products(text, 18)
         else:
             with pytest.raises(Exception, match="Ran out of memory in memory space hbm"):
                 lowered.compile()
+
+
+# flash_attention calls a traced step holds: d8's eight layers, d24's one
+# scanned layer body, the hybrid period's one `gated_full` layer, the latent
+# trunk's five blocks and its prediction module's
+@pytest.mark.parametrize("workload,calls", [("train_d8", 8), ("train_d24", 1),
+                                            ("train_q3n_ep16", 1), ("train_glm47_ep8", 6)])
+def test_train_cells_feed_the_flash_kernels_16bit_operands(workload, calls):
+    """Each train cell's step, TRACED at its real sizes (`eval_shape`: nothing
+    compiles, runs or is allocated, and no chip is described): every
+    `flash_attention` call it holds takes 16-bit operands,
+    `kernels/flash_calls_32bit_operands` stays where it was."""
+    from dalle_pytorch_tpu.observability import metrics as obs_metrics
+
+    names = ("kernels/flash_calls_16bit_operands", "kernels/flash_calls_32bit_operands")
+    cfg, step_fn, state, batch, key = _cell_step(workload, jax.ShapeDtypeStruct)
+    before = [obs_metrics.counter(n).value for n in names]
+    jax.eval_shape(step_fn, state, batch, key)
+    calls16, calls32 = (obs_metrics.counter(n).value - b for n, b in zip(names, before))
+    assert (calls16, calls32) == (calls, 0)
 
 
 @pytest.fixture(scope="module")

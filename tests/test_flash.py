@@ -218,3 +218,157 @@ def test_flash_per_head_mask_matches_dense():
     g_d = jax.grad(loss_d, argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g_f, g_d):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=5e-5)
+
+
+# ---------------------------------------------------------------------------
+# operand types: the kernels' tile products take q, k, v and dO as they arrive
+# ---------------------------------------------------------------------------
+
+def _kernel_jaxprs(dtype, **kw):
+    """{kernel name: its jaxpr} for every pallas_call of flash_attention's
+    forward and gradient on `dtype` inputs."""
+    n, d = 256, 128
+    x = jnp.zeros((1, 2, n, d), dtype)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, block_q=128, block_k=128, **kw).astype(jnp.float32).sum()
+
+    found = {}
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                found[e.params["name"]] = e.params["jaxpr"]
+                continue
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x).jaxpr)
+    return found
+
+
+def _eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+def _compact_kw(vfa=False):
+    return dict(mask=jnp.asarray(build_pattern_mask("axial_row", 256, 8)), grid="compact", vfa=vfa)
+
+
+# kernel body -> (how to reach it, the pallas_call's name, its tile products)
+_KERNELS = {
+    "dense_fwd": (lambda: dict(grid="dense"), "flash_fwd", 2),
+    "dense_dq": (lambda: dict(grid="dense"), "flash_dq", 3),
+    "dense_dkv": (lambda: dict(grid="dense"), "flash_dkv", 4),
+    "compact_fwd": (_compact_kw, "flash_compact_fwd", 2),
+    "compact_max": (lambda: _compact_kw(vfa=True), "flash_compact_max", 1),
+    "compact_vfa_fwd": (lambda: _compact_kw(vfa=True), "flash_compact_fwd", 2),
+    "compact_dq": (_compact_kw, "flash_compact_dq", 3),
+    "compact_dkv": (_compact_kw, "flash_compact_dkv", 4),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("kernel", list(_KERNELS))
+def test_kernel_products_take_the_inputs_type(kernel, dtype):
+    """Every `dot_general` of every kernel body has operands of the type q, k
+    and v arrived in and a float32 result; on bfloat16 inputs nothing 16-bit
+    is widened to float32 anywhere in a body (no float32 copy of a q, k, v or
+    dO tile: the only converts are p and dS going DOWN, and the outputs), and
+    each product states DEFAULT precision itself, whatever the ambient default
+    (this suite's is "highest", which Mosaic's 16-bit matmul refuses)."""
+    kw, name, n_products = _KERNELS[kernel]
+    body = _kernel_jaxprs(dtype, **kw())[name]
+    dots = [e for e in _eqns(body) if e.primitive.name == "dot_general"]
+    assert len(dots) == n_products
+    ambient = jax.make_jaxpr(jnp.dot)(jnp.eye(8), jnp.eye(8)).eqns[0].params["precision"]
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [dtype, dtype], e
+        assert e.outvars[0].aval.dtype == jnp.float32
+        assert e.params["preferred_element_type"] == jnp.float32
+        if dtype == jnp.bfloat16:
+            assert e.params["precision"] in (jax.lax.Precision.DEFAULT,
+                                             (jax.lax.Precision.DEFAULT,) * 2), e.params
+        else:  # float32 operands take the ambient precision, as they always did
+            assert e.params["precision"] == ambient
+    widened = [e for e in _eqns(body) if e.primitive.name == "convert_element_type"
+               and e.invars[0].aval.dtype == jnp.bfloat16]
+    assert widened == []
+    narrowed = [e for e in _eqns(body) if e.primitive.name == "convert_element_type"
+                and e.params["new_dtype"] == jnp.bfloat16]
+    # p before p v / p^T dO, dS before dS k / dS^T q, and each output written
+    expect = {"fwd": 2, "max": 0, "dq": 2, "dkv": 4}[kernel.rsplit("_", 1)[1]]
+    assert len(narrowed) == (expect if dtype == jnp.bfloat16 else 0)
+
+
+@pytest.mark.parametrize("pattern", [None, "axial_row"], ids=["causal", "axial_row"])
+@pytest.mark.parametrize("d", [128, 256])
+def test_flash_bf16_forward_and_gradients(d, pattern):
+    """bfloat16 in, at the head widths the cells train (128, 256): output and
+    all three gradients against (i) the dense path on the SAME bfloat16
+    inputs (`attend`: bfloat16 operands, probabilities rounded to bfloat16
+    before the value product, float32 accumulation: the kernels' own rule)
+    and (ii) float32 dense attention on the inputs widened.
+
+    Tolerance, as RMS error over the reference's RMS: 1 % against either.
+    Read on this test (CPU, interpret mode): against (ii) 0.19 % for the
+    output and 0.23-0.27 % for the gradients, which is what writing a result
+    in bfloat16 costs (2^-9); against (i) 0.28-0.41 %, since (i) rounds its
+    own results too and, at width 128, its scaled q (at 256 the scale is a
+    power of two and dv agrees to the last bit).  A dropped tile, a wrong
+    mask or a scale applied twice reads tens of per cent."""
+    fmap = 8
+    n = 64 + fmap * fmap  # 128: a 2 x 2 grid of 64 x 64 tiles
+    ks = jax.random.split(jax.random.PRNGKey(d), 4)
+    q, k, v, do = (jax.random.normal(kk, (1, 2, n, d), jnp.float32).astype(jnp.bfloat16)
+                   for kk in ks)
+    mask = None if pattern is None else build_pattern_mask(pattern, n, fmap)
+    dense = causal_mask(n) if mask is None else jnp.asarray(mask) & causal_mask(n)
+    f32 = jnp.float32
+
+    def run(fn, *xs):
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(out.astype(f32) * do.astype(f32)), out
+
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(*xs)
+        return [np.asarray(t, np.float32) for t in (out, *grads)]
+
+    got = run(lambda q, k, v: flash_attention(q, k, v, mask=mask, causal=True,
+                                              block_q=64, block_k=64), q, k, v)
+    assert got[0].dtype == np.float32 and np.isfinite(got[0]).all()
+    same_inputs = run(lambda q, k, v: attend(q * jnp.asarray(d ** -0.5, q.dtype), k, v, mask=dense),
+                      q, k, v)
+    widened = run(lambda q, k, v: attend(q * d ** -0.5, k, v, mask=dense),
+                  q.astype(f32), k.astype(f32), v.astype(f32))
+    for which, want in (("attend_bf16", same_inputs), ("float32", widened)):
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            err = np.sqrt(np.mean((a - b) ** 2)) / np.sqrt(np.mean(b ** 2))
+            assert err < 1e-2, (which, name, err)
+
+
+def test_flash_counts_its_calls_by_operand_type():
+    """`kernels/flash_calls_{16,32}bit_operands`: one count a `flash_attention`
+    call, made while the call is traced (a jitted function counts once, not
+    once a run), by the input's type alone."""
+    from dalle_pytorch_tpu.observability import metrics as obs_metrics
+
+    names = ("kernels/flash_calls_16bit_operands", "kernels/flash_calls_32bit_operands")
+
+    def grew(fn, *xs):
+        before = [obs_metrics.counter(n).value for n in names]
+        fn(*xs)
+        return [obs_metrics.counter(n).value - b for n, b in zip(names, before)]
+
+    q, k, v = qkv(b=1, h=1, n=64, d=64)
+    step = jax.jit(lambda q, k, v: flash_attention(q, k, v) + flash_attention(q, v, k))
+    assert grew(step, q, k, v) == [0, 2]
+    assert grew(step, q, k, v) == [0, 0]  # traced once
+    h16 = [t.astype(jnp.bfloat16) for t in (q, k, v)]
+    assert grew(step, *h16) == [2, 0]
+    assert grew(jax.grad(lambda q: flash_attention(q, h16[1], h16[2]).astype(jnp.float32).sum()),
+                h16[0]) == [1, 0]
+    assert grew(flash_attention, *(t.astype(jnp.float16) for t in (q, k, v))) == [1, 0]

@@ -53,12 +53,12 @@ def _tcfg(**kw):
     return TransformerConfig(**base)
 
 
-def qkv(b=1, h=1, n=N, d=DIM, seed=0):
+def qkv(b=1, h=1, n=N, d=DIM, seed=0, dtype=jnp.float32):
     # h=1 default: the grid is (b*h, T), so single-head halves interpret-mode
     # work; multi-head broadcast/layout is covered by the per-head test
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    q, k, v = (jax.random.normal(ks[i], (b, h, n, d), jnp.float32) for i in range(3))
-    do = jax.random.normal(ks[3], (b, h, n, d), jnp.float32)
+    q, k, v, do = (jax.random.normal(ks[i], (b, h, n, d), jnp.float32).astype(dtype)
+                   for i in range(4))
     return q, k, v, do
 
 
@@ -72,6 +72,7 @@ def _run(grid, mask, q, k, v, do, **kw):
         return jnp.sum(out * do), out
 
     (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert all(t.dtype == q.dtype for t in (out, *grads))
     return (np.asarray(out),) + tuple(np.asarray(g) for g in grads)
 
 
@@ -82,18 +83,21 @@ def _run(grid, mask, q, k, v, do, **kw):
 _SLOW_PATTERNS = ("full", "axial_col", "conv_like")
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize(
     "attn_type",
     [pytest.param(t, marks=pytest.mark.slow) if t in _SLOW_PATTERNS else t
      for t in ATTN_TYPES],
 )
-def test_compact_matches_dense_grid_bitexact(attn_type):
+def test_compact_matches_dense_grid_bitexact(attn_type, dtype):
     """Forward + dq + dk/dv bit-parity for every pattern ('full' runs the
-    causal-only tables: mask=None, liveness = the causal triangle)."""
+    causal-only tables: mask=None, liveness = the causal triangle), on
+    float32 operands and on the bfloat16 ones the train cells feed: the two
+    grids share every line that touches a tile, whatever its type."""
     mask = _pattern_for(_tcfg(), attn_type)
     if mask is not None:
         mask = jnp.asarray(mask)
-    q, k, v, do = qkv()
+    q, k, v, do = qkv(dtype=dtype)
     dense = _run("dense", mask, q, k, v, do)
     compact = _run("compact", mask, q, k, v, do)
     for a, b in zip(dense, compact):
