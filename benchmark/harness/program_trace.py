@@ -152,12 +152,18 @@ def _device_planes(path: str) -> Dict[str, dict]:
 
 def load_xplane(path: str) -> dict:
     """{"devices": {plane: {"ops": [[name, start, dur, path]], "modules":
-    [[name, start, dur]]}}, "host": [[name, start, dur, thread, stats]]}
-    with the host events named `serve/...` or `bench/...` only."""
+    [[name, start, dur]]}}, "recorded": {plane: [first start, last end]},
+    "host": [[name, start, dur, thread, stats]]} with the host events named
+    `serve/...` or `bench/...` only.  `recorded` is what the profiler's session
+    caught of each device: an execution in flight when it started or stopped is
+    there clipped, beginning with the first or ending with the last event."""
     from jax.profiler import ProfileData
 
     path = tr.find_xplane(path)
     out = {"devices": _device_planes(path), "host": []}
+    for plane, dev in out["devices"].items():
+        every = dev["ops"] + dev["modules"]
+        out.setdefault("recorded", {})[plane] = [min(e[1] for e in every), max(e[1] + e[2] for e in every)]
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/host:"):
             for thread, line in enumerate(plane.lines):
@@ -171,9 +177,10 @@ def load_xplane(path: str) -> dict:
 
 def cut(events: dict, lo: float, hi: float) -> dict:
     """What a test fixture holds of [lo, hi]: the device events that lie wholly
-    inside (so every execution kept is whole), the host spans clipped to it,
-    times from `lo` in whole ns, and the scope paths once, in a table (`paths`;
-    an operation's fourth field is its index there)."""
+    inside (so every execution kept is whole, and the piece carries no
+    `recorded` extent to clip by), the host spans clipped to it, times from
+    `lo` in whole ns, and the scope paths once, in a table (`paths`; an
+    operation's fourth field is its index there)."""
     paths: Dict[str, int] = {}
     table = events.get("paths")  # a piece cut before: an operation holds an index
 
@@ -255,7 +262,10 @@ class ProgramTrace:
     def __init__(self, events: dict):
         paths = events.get("paths")
         self.devices = events["devices"]
-        dev = self.devices[sorted(self.devices)[0]] if self.devices else {"ops": [], "modules": []}
+        first = sorted(self.devices)[0] if self.devices else None
+        dev = self.devices[first] if self.devices else {"ops": [], "modules": []}
+        # (first start, last end) of what the profiler recorded of the device, where the loader says
+        self.recorded: Optional[Sequence[float]] = events.get("recorded", {}).get(first)
         self.modules: List[Tuple[str, float, float]] = sorted(
             ((program_of(n), s, d) for n, s, d in dev["modules"]), key=lambda e: e[1])
         self.ops: List[Tuple[str, float, float, str]] = sorted(
@@ -284,9 +294,15 @@ class ProgramTrace:
         return sorted({m[0] for m in self.modules})
 
     def executions(self, program: str) -> List[Tuple[float, float]]:
-        """(start, duration) of each whole execution of `program` inside the stretch."""
+        """(start, duration) of each WHOLE execution of `program` inside the
+        stretch.  The host runs ahead of the device, so an execution is in
+        flight when the profiler starts and another when it stops; each is
+        recorded clipped, from the session's first event or up to its last,
+        and may still lie inside the harness's spans (the one cut 2 ms after
+        its start that `flash_device_ms` used to divide by).  Neither is whole."""
+        rec_lo, rec_hi = self.recorded or (float("-inf"), float("inf"))
         return [(s, d) for n, s, d in self.modules
-                if n == program and s >= self.lo and s + d <= self.hi]
+                if n == program and s >= self.lo and s + d <= self.hi and s > rec_lo and s + d < rec_hi]
 
     def op_self_times(self, start: float, dur: float) -> List[Tuple[str, str, float]]:
         """(operation, scope path, self ns) of the operations of one execution."""
@@ -333,6 +349,18 @@ class ProgramTrace:
         per = self.time_by(program, scope_of)
         total = sum(sum(by.values()) for by in per)
         return 100.0 * sum(by.get(UNSCOPED, 0.0) for by in per) / total if total else None
+
+    def op_ms(self, program: str, name_pattern: str) -> Optional[float]:
+        """Median over the whole executions of `program` of the device ms (self
+        time) of its operations whose NAME begins with a match of `name_pattern`
+        (`flash_` finds `%flash_fwd.3`, a Pallas call named after its kernel).
+        None where no whole execution holds such an operation."""
+        named = re.compile(name_pattern)
+        per = [sum(t for name, _, t in self.op_self_times(start, dur) if named.match(name.lstrip("%")))
+               for start, dur in self.executions(program)]
+        if not any(per):
+            return None
+        return stats_mod.median(per) * 1e-6
 
     def remat_ms(self, program: str) -> Optional[float]:
         per = [by.get("remat", 0.0)

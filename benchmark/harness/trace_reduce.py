@@ -204,9 +204,3 @@ class TraceView:
 
     def spans_named(self, what: str) -> List[Event]:
         return [s for s in self.spans if s[0] == SPAN_PREFIX + what]
-
-    def op_seconds_matching(self, pattern: str, lo: float, hi: float) -> float:
-        """Device seconds inside [lo, hi] of the operations whose HLO text matches."""
-        rx = re.compile(pattern)
-        return sum((b - a) for name, a, b in clip(self.first_device()["ops"], lo, hi)
-                   if rx.search(name)) * 1e-9

@@ -60,12 +60,16 @@ def train_step_flops(sizes: dict, batch: int) -> float:
 
 def decode_step_bytes(sizes: dict, positions: Sequence[int], weight_itemsize: int,
                       kv_itemsize: int) -> float:
-    """Bytes one fused decode step has to read: every weight once, and for
-    each active lane, whose query sits at sequence position p, the keys and
-    values its layers' patterns let it see (causal: at most p + 1), in the
-    pool's storage type.  Writes (one K/V column a lane and layer) and
+    """Bytes one fused decode step has to read: every layer's weights once;
+    of the shared embedding and output table the rows a decode step can EMIT,
+    `num_image_tokens` of them, once (the step's lookup and its head read the
+    same image half: a step neither feeds nor draws a text or pad column);
+    and for each active lane, whose query sits at sequence position p, the
+    keys and values its layers' patterns let it see (causal: at most p + 1),
+    in the pool's storage type.  Writes (one K/V column a lane and layer) and
     activations are left out: they are a thousandth of this."""
-    weights = matmul_params(sizes) * weight_itemsize
+    never_read = int(sizes["dim"]) * (vocabulary(sizes) - int(sizes["num_image_tokens"]))
+    weights = (matmul_params(sizes) - never_read) * weight_itemsize
     heads, dh = int(sizes["heads"]), int(sizes["dim_head"])
     kv = 0.0
     for t in layer_types(sizes):

@@ -1,10 +1,12 @@
 """The decode step's share of its roofline, which is BANDWIDTH-bound: one
 token a lane against every weight, so the least time a step can take is the
 bytes it must read over the chip's HBM bandwidth (819e9 bytes/s on a v5e).
-Bytes (benchmark/harness/work.decode_step_bytes): every matmul weight once, in
-the type it is stored in, plus, for each active lane at its position in each
-traced poll, the keys and values its layers' patterns let it see, in the
-pool's type.  Divided by the median device time of the decode program."""
+Bytes (benchmark/harness/work.decode_step_bytes): every layer's matmul weights
+once, in the type they are stored in; of the shared table the rows a decode
+step can emit (`num_image_tokens`, which its lookup and its head both read),
+once; plus, for each active lane at its position in each traced poll, the keys
+and values its layers' patterns let it see, in the pool's type.  Divided by
+the median device time of the decode program."""
 from benchmark.harness import stats, work
 
 

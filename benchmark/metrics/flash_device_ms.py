@@ -1,17 +1,14 @@
-"""Device time per optimizer step of the flash-attention kernels: the events
-of the trace whose HLO text names a `flash_*` Pallas call (the seven kernels
-of kernels/flash_attention.py carry such names), summed over the executions
-of the step program that lie wholly inside the traced window (the step
-program is the one with the most device time there), divided by their count."""
+"""Device time per optimizer step of the flash-attention kernels: the self
+time of the operations named `flash_*` (the seven Pallas calls of
+kernels/flash_attention.py are named after their kernels) inside each WHOLE
+execution of the program named `train_step`, median over the traced stretch's
+whole executions (`ProgramTrace.executions`).  An execution that the stretch
+cuts, or that the profiler's start or stop clipped, counts for nothing, above
+or below the line; a scanned model's kernels run inside its `while`, whose
+own time self time leaves out."""
+from benchmark.harness import program_trace
 
 
 def read(ctx):
-    t = ctx.trace
-    if t is None:
-        return None
-    step = t.heaviest_module()
-    runs = [m for m in t.modules_inside() if m[0] == step]
-    if not runs:
-        return None
-    total = sum(t.op_seconds_matching(r"flash_[a-z_]+", s, s + d) for _, s, d in runs)
-    return 1e3 * total / len(runs)
+    t = program_trace.of(ctx)
+    return None if t is None else t.op_ms("train_step", "flash_")

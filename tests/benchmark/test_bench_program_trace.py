@@ -17,6 +17,7 @@ for p in (ROOT, ROOT / "tests"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
+import bench_rules  # noqa: E402
 from benchmark.harness import manifest, program_trace as pt  # noqa: E402
 from benchmark.tools import program_report  # noqa: E402
 
@@ -27,6 +28,13 @@ NEW_SERVE = ["decode_kv_gather_device_ms", "decode_compute_device_ms", "decode_s
              "idle_in_program_spans_pct"]
 NEW_TRAIN = ["train_attn_device_ms", "train_shift_device_ms", "train_logits_loss_device_ms",
              "train_remat_device_ms", "train_stack_device_ms", "train_unscoped_pct"]
+PR23 = ["window_compiles.train", "window_compiles.serve", "host_dispatch_ms", "mfu_pct",
+        "flash_device_ms", "lane_occupancy_pct", "gen_tok_per_s_median", "queue_wait_p50_ms",
+        "ttft_p50_ms", "image_latency_done_p50_s", "decode_step_device_ms", "prefill_device_ms",
+        "decode_step_roofline"]
+# by scope of the named program, whatever block the step runs: every training cell's (ISSUEs 26, 32)
+EVERY_TRUNKS = ["train_attn_device_ms", "train_logits_loss_device_ms", "train_remat_device_ms",
+                "train_unscoped_pct", "flash_device_ms"]
 
 
 # ---- the programs' names and scopes, at tiny sizes on the CPU ---------------
@@ -215,6 +223,77 @@ def test_fixture_train_while_encloses_its_body():
     assert t.remat_ms("train_step") > 0 and t.scope_ms("train_step", ("stack_layers",)) > 0
 
 
+def test_op_ms_is_the_median_over_whole_executions_of_the_named_operations_self_time():
+    """`flash_device_ms` reads through this accessor.  The committed train
+    fixture was cut from a step too small for the flash kernels, so the
+    operations asked for here are the ones it has."""
+    t = _trace("train")
+    runs = t.executions("train_step")
+    assert len(runs) == 3
+    per = [sum(own for name, _, own in t.op_self_times(*run) if name.startswith("%custom-call"))
+           for run in runs]
+    assert min(per) > 0 and t.op_ms("train_step", "custom-call") == pytest.approx(sorted(per)[1] * 1e-6)
+    # by the NAME's beginning, the `%` aside: not by a word further on in it
+    assert t.op_ms("train_step", "call") is None
+    # a `while` keeps only what its body does not cover
+    spanned = [sum(d for n, a, d, _ in t.ops if n.startswith("%while") and s <= a < s + dur)
+               for s, dur in runs]
+    assert 0 < t.op_ms("train_step", "while") < 0.5 * sorted(spanned)[1] * 1e-6
+    # nothing of that name, no such program, no device plane: nothing, and no raise
+    assert t.op_ms("train_step", "flash_") is None and t.op_ms("serve_decode_step", "fusion") is None
+    assert manifest.reader("flash_device_ms")(_ctx(object(), t)) is None
+    assert manifest.reader("flash_device_ms")(_ctx(object(), _trace("serve"))) is None
+    assert manifest.reader("flash_device_ms")(_ctx(None)) is None
+
+
+@pytest.mark.parametrize("cut_at,want_ms", [(31.0, 6.0), (29.0, 5.0), (19.5, 4.0)])
+def test_flash_device_ms_counts_whole_executions_only(cut_at, want_ms):
+    """Three steps of 10 us whose kernels take 4, 6 and 8 us, forward inside a
+    scanned layer's `while`: the stretch's end cuts the third step or not, and a
+    cut step's kernels count neither above nor below the line (the reader this
+    one replaces divided five steps' kernels by six executions)."""
+    ops, modules = [], []
+    for i, kernels in enumerate((4.0, 6.0, 8.0)):
+        t0 = 10.0 * i + 1.0
+        modules.append(["jit_train_step(7)", t0 * 1e3, 9.0e3])
+        ops += [["%while.1", t0 * 1e3, (kernels / 2 + 1.0) * 1e3, "jit(train_step)/fwd_bwd/jvp()/while"],
+                ["%flash_compact_fwd.3", (t0 + 0.5) * 1e3, kernels / 2 * 1e3,
+                 "jit(train_step)/fwd_bwd/jvp()/while/body/attn/flash_attn"],
+                ["%flash_dkv.9", (t0 + 4.5) * 1e3, kernels / 2 * 1e3,
+                 "jit(train_step)/fwd_bwd/transpose(jvp(attn))/flash_attn"],
+                ["%fusion.2", (t0 + 8.6) * 1e3, 0.3e3, "jit(train_step)/optimizer_update/add"]]
+    events = {"devices": {"/device:TPU:0": {"ops": ops, "modules": modules}},
+              "host": [["bench/steps", 0.0, cut_at * 1e3, 0, {}]]}
+    ctx = _ctx(object(), pt.ProgramTrace(events))
+    assert manifest.reader("flash_device_ms")(ctx) == pytest.approx(want_ms * 1e-3)
+
+
+@pytest.mark.parametrize("loader_knows,whole,want_ms", [(True, 2, 4.0), (False, 4, 3.9)])
+def test_an_execution_the_profiler_clipped_is_not_whole(loader_knows, whole, want_ms):
+    """As `train_d8`'s traced stretch looks on the chip (PR 32): the step in
+    flight when the profiler starts is recorded from the session's first event,
+    3.8 of its 4 us of kernels left; two whole steps; the step in flight when it
+    stops ends with the last event, 2 us in, no kernel yet.  The harness's spans
+    cover all four.  `load_xplane` says what the session recorded, and the two
+    clipped ones are then not whole; a piece cut for a fixture says nothing,
+    since `cut` keeps whole executions only."""
+    ops, modules = [], []
+    for t0, dur, kernels in ((5.0, 9.5, 3.8), (14.5, 10.0, 4.0), (24.5, 10.0, 4.0), (34.5, 0.2, 0.0)):
+        modules.append(["jit_train_step(7)", t0 * 1e3, dur * 1e3])
+        ops.append(["%fusion.1", t0 * 1e3, 0.2e3, "jit(train_step)/fwd_bwd/jvp(ff)/mul"])
+        if kernels:
+            ops.append(["%flash_fwd.3", (t0 + 1.0) * 1e3, kernels * 1e3,
+                        "jit(train_step)/fwd_bwd/jvp(attn)/flash_attn"])
+    events = {"devices": {"/device:TPU:0": {"ops": ops, "modules": modules}},
+              "host": [["bench/dispatch", 4.0e3, 31.0e3, 0, {}]]}
+    if loader_knows:
+        events["recorded"] = {"/device:TPU:0": [5.0e3, 34.7e3]}
+    t = pt.ProgramTrace(events)
+    assert len(t.executions("train_step")) == whole
+    assert manifest.reader("flash_device_ms")(_ctx(object(), t)) == pytest.approx(want_ms * 1e-3)
+    assert t.program_ms("train_step") == pytest.approx(0.010 if loader_knows else 0.00975)
+
+
 def _msg(*fields):
     """A protobuf message from (field number, int | bytes | str) pairs."""
     def varint(x):
@@ -273,15 +352,38 @@ def _ctx(trace, program_trace=None):
     return ctx
 
 
-@pytest.mark.parametrize("name", NEW_SERVE + NEW_TRAIN)
-def test_new_entry_of_the_manifest_names_its_cells_and_layer(name):
-    m = next(m for m in MAN["per_layer"] if m["name"] == name)
+def new_entry_of_the_manifest_names_its_cells_and_layer(man, name):
+    m = bench_rules.entry(man, "per_layer", name)
     serve = name in NEW_SERVE
-    assert m["workloads"] == (["serve_batch", "serve_guided"] if serve else ["train_d8", "train_d24"])
+    # BEGINS with the two cells PR 24 named; a later cell of the same kind may follow
+    assert m["workloads"][:2] == (["serve_batch", "serve_guided"] if serve else ["train_d8", "train_d24"])
     assert m["moves"] in (("gen_img_tok_per_s", "image_latency_p50_s") if serve
                           else ("train_img_tok_per_s",))
-    # appended: the thirteen entries PR 23 accepted come first, untouched
-    assert [e["name"] for e in MAN["per_layer"]].index(name) >= 13
+
+
+def pr24s_entries_follow_pr23s_and_the_scope_readers_serve_every_trunk(man):
+    """A rule of the manifest (bench_rules.py)."""
+    readers = bench_rules.names(man["per_layer"])
+    # appended: the thirteen entries PR 23 accepted come first, then PR 24's as one run
+    assert readers[:13] == PR23 and bench_rules.run_of(NEW_SERVE + NEW_TRAIN, readers)
+    for name in NEW_SERVE + NEW_TRAIN:
+        new_entry_of_the_manifest_names_its_cells_and_layer(man, name)
+    for name in EVERY_TRUNKS:
+        assert bench_rules.in_order(["train_d8", "train_d24", "train_q3n_ep16", "train_glm47_ep8"],
+                                    bench_rules.entry(man, "per_layer", name)["workloads"]), name
+
+
+MANIFEST_RULES = [pr24s_entries_follow_pr23s_and_the_scope_readers_serve_every_trunk]
+
+
+@pytest.mark.parametrize("name", NEW_SERVE + NEW_TRAIN)
+def test_new_entry_of_the_manifest_names_its_cells_and_layer(name):
+    new_entry_of_the_manifest_names_its_cells_and_layer(MAN, name)
+    assert bench_rules.names(MAN["per_layer"]).index(name) >= 13
+
+
+def test_pr24s_entries_follow_pr23s_and_the_scope_readers_serve_every_trunk():
+    pr24s_entries_follow_pr23s_and_the_scope_readers_serve_every_trunk(MAN)
 
 
 @pytest.mark.parametrize("name", NEW_SERVE + NEW_TRAIN)

@@ -189,7 +189,10 @@ def test_required_work_counts():
         dalle_step_flops(cfg, 4, work.matmul_params(SIZES), granularity="element"), rel=1e-6)
     # decode bytes: weights once, plus what each lane's patterns let it see
     base = work.decode_step_bytes(SIZES, [], 4, 4)
-    assert base == work.matmul_params(SIZES) * 4
+    # of the shared table, the rows a decode step can emit: its lookup and its head read the image half
+    text_and_pad_rows = cfg.num_text_tokens + cfg.text_seq_len
+    assert work.vocabulary(SIZES) == text_and_pad_rows + cfg.num_image_tokens
+    assert base == (work.matmul_params(SIZES) - cfg.dim * text_and_pad_rows) * 4
     n_pre = cfg.text_seq_len + 1
     one = work.decode_step_bytes(SIZES, [n_pre], 4, 4) - base
     # the first image position sees all text and itself, in every layer's pattern

@@ -3,9 +3,9 @@ kinds of layer (a dense layer, two `mla` layers with 16 bias-balanced experts
 of which 4 are held, the prediction module) through `run.py --rehearse` with
 `--trace 1`, as the driver would run the cell `train_glm47_ep8`; the new kind's
 records; the new per-layer readers where a trace names nothing; what
-`train_glm_mfu_pct` is measured against; and the cells as the manifest now
-names them (with what `test_bench_rehearsal_q3n.py` says of its own cell, minus
-its stale count: tests/benchmark/conftest.py); that the two train kinds keep one
+`train_glm_mfu_pct` is measured against; the cell as the manifest names it,
+stated as rules a later cell, configuration or reader does not break
+(`MANIFEST_RULES`, bench_rules.py); that the two train kinds keep one
 loop; and that `correct_mtp`'s comparison fails what it is there to fail."""
 import json
 import os
@@ -19,6 +19,7 @@ ROOT = Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
+import bench_rules  # noqa: E402
 from benchmark.harness import manifest, work_glm, work_q3n  # noqa: E402
 
 MANIFEST = ROOT / "benchmark" / "rehearsal" / "manifest_glm.json"
@@ -28,6 +29,15 @@ NEW = ["train_glm_mfu_pct", "train_mla_device_ms", "train_mla_core_device_ms",
        "train_mtp_device_ms", "train_dense_ff_device_ms"]
 SHARED = ["train_img_tok_per_s", "window_compiles.train", "host_dispatch_ms",
           "train_moe_device_ms", "train_moe_experts_device_ms"]
+# what the benchmark held when PR 32 turned these tests into rules: later entries FOLLOW them
+ACCEPTED_CELLS = ["serve_batch", "train_d24", "train_d8", "serve_guided", "train_q3n_ep16",
+                  "train_glm47_ep8"]
+ACCEPTED_CONFIGS = ["dalle_2048_d8", "dalle_2048_d24", "qwen3_next_ep16_p1", "glm47_flash_ep8_d5"]
+Q3N_NEW = ["train_q3n_mfu_pct", "train_moe_device_ms", "train_moe_experts_device_ms",
+           "train_gdn_device_ms", "train_gdn_scan_device_ms"]
+# the other blocks' arithmetic and the other trunk's scope readers: never on this cell
+NOT_THIS_TRUNKS = {"mfu_pct", "train_q3n_mfu_pct", "train_shift_device_ms", "train_stack_device_ms",
+                   "train_gdn_device_ms", "train_gdn_scan_device_ms"}
 
 
 @pytest.fixture(scope="module")
@@ -229,48 +239,57 @@ def test_required_operations_of_the_cell():
                                       + work_glm.attention_flops(sizes, 4223)))
 
 
-def test_the_new_cell_and_its_metrics_are_in_the_manifest_as_the_issue_names_them():
-    cell = manifest.cell(BENCH, "train_glm47_ep8")
+# ---- the manifest, as rules (functions of a manifest: bench_rules.py) ---------
+def the_accepted_entries_keep_their_order(bench):
+    """Append-only: the cells and configurations the benchmark held keep their
+    places; each PR's per-layer readers stay one run, PR 26's before PR 30's."""
+    assert bench_rules.names(bench["workloads"])[:6] == ACCEPTED_CELLS
+    assert bench_rules.names(bench["configs"])[:4] == ACCEPTED_CONFIGS
+    readers = bench_rules.names(bench["per_layer"])
+    assert bench_rules.run_of(Q3N_NEW, readers) and bench_rules.in_order(Q3N_NEW + NEW, readers)
+
+
+def the_glm_cell_and_its_metrics_are_as_the_issue_names_them(bench):
+    cell = manifest.cell(bench, "train_glm47_ep8")
     assert cell["config"] == "glm47_flash_ep8_d5" and cell["traffic"] == "steps_adam_b4_fresh"
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
     traffic = manifest.traffic(cell["traffic"])
     assert traffic["kind"] == "train_steps_mtp"
     assert traffic["microbatch"] * traffic["grad_accum"] == 4
     assert traffic["distinct_batches"] == 128 and traffic["trace_steps"] == 4
-    per_layer = {m["name"] for m in manifest.metrics_for(BENCH, "per_layer", "train_glm47_ep8")}
-    # not `flash_device_ms`: tried on the chip after review, the accepted reader gave five traced
-    # steps' kernel time over six executions in this cell (PERF.md section 6); `mla_core` is by scope
-    assert set(NEW) | set(SHARED[1:]) == per_layer
-    assert "train_img_tok_per_s" in {m["name"] for m in
-                                     manifest.metrics_for(BENCH, "end_to_end", "train_glm47_ep8")}
-    # appended: nothing that was there moved, and the shared lists end with the new cell
-    assert [w["name"] for w in BENCH["workloads"]] == [
-        "serve_batch", "train_d24", "train_d8", "serve_guided", "train_q3n_ep16", "train_glm47_ep8"]
-    assert [c["name"] for c in BENCH["configs"]][-1] == "glm47_flash_ep8_d5"
-    assert [m["name"] for m in BENCH["per_layer"]][-5:] == NEW
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+    per_layer = bench_rules.per_layer_of(bench, "train_glm47_ep8")
+    # held from both sides by name: what it must carry, and what it must not
+    assert set(NEW) | set(SHARED[1:]) <= per_layer
+    assert not per_layer & NOT_THIS_TRUNKS, "the other blocks' arithmetic, the other trunk's scopes"
+    assert "train_img_tok_per_s" in bench_rules.names(
+        manifest.metrics_for(bench, "end_to_end", "train_glm47_ep8"))
+    # its readers are one run; a shared list holds both hybrid cells in their order, and may go on
+    assert bench_rules.run_of(NEW, bench_rules.names(bench["per_layer"]))
+    for m in bench["end_to_end"] + bench["per_layer"]:
         if m["name"] in SHARED:
-            assert m["workloads"][-2:] == ["train_q3n_ep16", "train_glm47_ep8"], m["name"]
-    assert all(w["chips"] == 1 for w in BENCH["workloads"])
+            assert bench_rules.in_order(["train_q3n_ep16", "train_glm47_ep8"], m["workloads"]), m["name"]
+
+
+def the_other_hybrid_cell_is_still_named_as_its_issue_named_it(bench):
+    """`test_bench_rehearsal_q3n.py` holds its own cell to its traffic and to
+    what it must and must not carry of what PR 26 knew; what only this file
+    knows is that the latent-attention trunk's readers stay off it."""
+    cell = manifest.cell(bench, "train_q3n_ep16")
+    assert cell["config"] == "qwen3_next_ep16_p1" and cell["traffic"] == "steps_adam_b4"
+    per_layer = bench_rules.per_layer_of(bench, "train_q3n_ep16")
+    assert set(Q3N_NEW) <= per_layer
+    assert not per_layer & set(NEW), "the latent-attention trunk's readers"
+
+
+MANIFEST_RULES = [the_accepted_entries_keep_their_order,
+                  the_glm_cell_and_its_metrics_are_as_the_issue_names_them,
+                  the_other_hybrid_cell_is_still_named_as_its_issue_named_it]
+
+
+def test_the_new_cell_and_its_metrics_are_in_the_manifest_as_the_issue_names_them():
+    the_glm_cell_and_its_metrics_are_as_the_issue_names_them(BENCH)
+    the_accepted_entries_keep_their_order(BENCH)
 
 
 def test_the_other_hybrid_cell_is_still_named_as_its_issue_named_it():
-    """Every assertion of `test_bench_rehearsal_q3n.py`'s last test but its count
-    of cells, on the benchmark as it now is (that test still runs them all:
-    tests/benchmark/conftest.py excuses its last line's count and nothing else)."""
-    cell = manifest.cell(BENCH, "train_q3n_ep16")
-    assert cell["config"] == "qwen3_next_ep16_p1" and cell["traffic"] == "steps_adam_b4"
-    assert cell["chips"] == 1 and len(cell["why"]) <= 200
-    traffic = manifest.traffic(cell["traffic"])
-    assert traffic["kind"] == "train_steps"
-    assert traffic["microbatch"] * traffic["grad_accum"] == 4
-    assert traffic["distinct_batches"] == 4 and traffic["trace_steps"] == 4
-    per_layer = {m["name"] for m in manifest.metrics_for(BENCH, "per_layer", "train_q3n_ep16")}
-    assert {"train_q3n_mfu_pct", "train_moe_device_ms", "train_moe_experts_device_ms",
-            "train_gdn_device_ms", "train_gdn_scan_device_ms"} <= per_layer
-    assert not per_layer & {"mfu_pct", "flash_device_ms", "train_shift_device_ms",
-                            "train_stack_device_ms"}, "the DALL-E block's arithmetic"
-    assert not per_layer & set(NEW), "the latent-attention trunk's readers"
-    assert [w["name"] for w in BENCH["workloads"]][:4] == [
-        "serve_batch", "train_d24", "train_d8", "serve_guided"]
-    assert all(w["chips"] == 1 for w in BENCH["workloads"]) and len(BENCH["workloads"]) >= 5
+    the_other_hybrid_cell_is_still_named_as_its_issue_named_it(BENCH)

@@ -15,6 +15,7 @@ ROOT = Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
+import bench_rules  # noqa: E402
 from benchmark.harness import manifest, work_q3n  # noqa: E402
 
 MANIFEST = ROOT / "benchmark" / "rehearsal" / "manifest_q3n.json"
@@ -112,18 +113,29 @@ def test_required_operations_of_the_cell():
     assert extra == pytest.approx(4 * 10 * 32 / 512 * 3 * 2048 * 512)
 
 
-def test_the_new_cell_and_its_metrics_are_in_the_manifest_as_the_issue_names_them():
-    cell = manifest.cell(BENCH, "train_q3n_ep16")
+def the_q3n_cell_and_its_metrics_are_as_the_issue_names_them(bench):
+    """A rule of the manifest (bench_rules.py): what this cell must carry and
+    must not, and that the four cells it was appended to come first.  How many
+    cells follow, and on how many chips, is not this test's to say."""
+    cell = manifest.cell(bench, "train_q3n_ep16")
     assert cell["config"] == "qwen3_next_ep16_p1" and cell["traffic"] == "steps_adam_b4"
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
     traffic = manifest.traffic(cell["traffic"])
     assert traffic["kind"] == "train_steps"
     assert traffic["microbatch"] * traffic["grad_accum"] == 4
     assert traffic["distinct_batches"] == 4 and traffic["trace_steps"] == 4
-    per_layer = {m["name"] for m in manifest.metrics_for(BENCH, "per_layer", "train_q3n_ep16")}
+    per_layer = bench_rules.per_layer_of(bench, "train_q3n_ep16")
     assert set(NEW) <= per_layer
-    assert not per_layer & {"mfu_pct", "flash_device_ms", "train_shift_device_ms",
-                            "train_stack_device_ms"}, "the DALL-E block's arithmetic"
-    assert [w["name"] for w in BENCH["workloads"]][:4] == [
+    assert not per_layer & {"mfu_pct", "train_shift_device_ms", "train_stack_device_ms"}, \
+        "the DALL-E block's arithmetic"
+    assert bench_rules.names(bench["workloads"])[:4] == [
         "serve_batch", "train_d24", "train_d8", "serve_guided"]
-    assert all(w["chips"] == 1 for w in BENCH["workloads"]) and len(BENCH["workloads"]) == 5
+    assert bench_rules.in_order(["train_d8", "train_d24", "train_q3n_ep16"],
+                                bench_rules.entry(bench, "end_to_end", "train_img_tok_per_s")["workloads"])
+
+
+MANIFEST_RULES = [the_q3n_cell_and_its_metrics_are_as_the_issue_names_them]
+
+
+def test_the_new_cell_and_its_metrics_are_in_the_manifest_as_the_issue_names_them():
+    the_q3n_cell_and_its_metrics_are_as_the_issue_names_them(BENCH)

@@ -91,7 +91,6 @@ def test_program_views():
     v = tr.TraceView(_events())
     assert [m[0] for m in v.modules_inside()] == ["jit_step(1)", "jit_small(2)", "jit_step(1)"]
     assert v.heaviest_module() == "jit_step(1)"
-    assert v.op_seconds_matching(r"flash_[a-z_]+", 0, 10 * MS) == pytest.approx(0.003)
     assert len(v.spans_named("poll")) == 2
 
 
@@ -100,7 +99,19 @@ def test_readers_on_the_handmade_trace():
     ctx = manifest.Context(sizes={}, traffic={}, records={}, trace=v, peaks=None, end_to_end={})
     assert manifest.reader("decode_step_device_ms")(ctx) == pytest.approx(2.5)  # median of 2, 3 ms
     assert manifest.reader("prefill_device_ms")(ctx) == pytest.approx(1.0)  # jit_small in poll.admit
-    assert manifest.reader("flash_device_ms")(ctx) == pytest.approx(1.5)  # 3 ms over two steps
+    # the flash reader goes by the program's NAME (harness/program_trace.py): the same events with
+    # the step named, and a kernel call inside the execution the stretch cuts, which counts nowhere
+    from benchmark.harness import program_trace
+
+    ev = _events()["devices"]["/device:TPU:0"]
+    named = {"devices": {"/device:TPU:0": {
+        "ops": [[n.split(" ")[0], s, d, ""] for n, s, d in ev["ops"]]
+        + [["%flash_dq.5", 9.6 * MS, 0.3 * MS, ""]],
+        "modules": [[n.replace("jit_step", "jit_train_step"), s, d] for n, s, d in ev["modules"]]}},
+        "host": [[n, s, d, 0, {}] for n, s, d in _events()["spans"]]}
+    ctx.program_trace = program_trace.ProgramTrace(named)
+    assert len(ctx.program_trace.executions("train_step")) == 2  # the third is cut at 10 ms
+    assert manifest.reader("flash_device_ms")(ctx) == pytest.approx(1.5)  # median of 0 and 3 ms
     empty = manifest.Context(sizes={}, traffic={}, records={}, trace=None, peaks=None, end_to_end={})
     for name in ("decode_step_device_ms", "prefill_device_ms", "flash_device_ms",
                  "decode_step_roofline", "mfu_pct", "lane_occupancy_pct", "gen_tok_per_s_median"):
