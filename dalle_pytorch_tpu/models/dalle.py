@@ -33,6 +33,7 @@ from dalle_pytorch_tpu.ops.stable import divide_max
 _BLOCK_FIELDS = (
     "norm", "norm_eps", "layer_scale", "kv_heads", "partial_rotary_factor", "rotary_theta",
     "gdn_key_heads", "gdn_value_heads", "gdn_key_dim", "gdn_value_dim", "gdn_conv_kernel",
+    "gdn_neg_eigval", "pre_norm", "qk_norm", "attn_bias",
     "moe_experts", "moe_top_k", "moe_ff_dim", "moe_shared_ff_dim",
     "moe_experts_held", "moe_first_expert",
     "moe_router", "moe_routed_scale", "moe_bias_rate", "moe_shared_gated",
@@ -88,8 +89,10 @@ class DALLEConfig:
     pp_interleave: int = 1  # circular pipeline chunks per device (bubble / v)
     pp_num_micro: Optional[int] = None  # GPipe microbatches (None = auto)
     # the block as a parameter (TransformerConfig has each field's meaning):
-    # `attn_types` may cycle `gated_delta` / `gated_full`; a hybrid trunk is
-    # trained through forward() and refused by every sampling entry point
+    # `attn_types` may cycle `gated_delta` / `gated_full` / `mla`; a hybrid trunk
+    # is trained through forward(), and served where `transformer.refuse_hybrid`
+    # lets it (`gated_delta` beside pattern layers: yes; `gated_full`, `mla`,
+    # routed experts: no)
     norm: str = "layernorm"
     norm_eps: float = 1e-6
     layer_scale: bool = True
@@ -101,6 +104,14 @@ class DALLEConfig:
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
     gdn_conv_kernel: int = 4
+    gdn_neg_eigval: bool = False
+    pre_norm: bool = True
+    qk_norm: bool = False
+    attn_bias: bool = True
+    # with `rotary_emb` off: the learned text and axial image position tables
+    # (True, the DALL-E stream's), or no position signal beside what the
+    # layers carry themselves (False: a trunk whose recurrent layers do)
+    axial_pos_emb: bool = True
     moe_experts: int = 0
     moe_top_k: int = 1
     moe_ff_dim: int = 0
@@ -142,6 +153,10 @@ class DALLEConfig:
     @property
     def total_tokens(self) -> int:
         return self.num_text_tokens_padded + self.num_image_tokens
+
+    @property
+    def learned_positions(self) -> bool:
+        return not self.rotary_emb and self.axial_pos_emb
 
     @property
     def resolved_execution(self) -> str:
@@ -248,7 +263,7 @@ def init_dalle(key: jax.Array, cfg: DALLEConfig) -> dict:
     if not cfg.share_input_output_emb:
         params["text_emb"] = embedding_init(keys.next(), cfg.num_text_tokens_padded, cfg.dim)
         params["image_emb"] = embedding_init(keys.next(), cfg.num_image_tokens, cfg.dim)
-    if not cfg.rotary_emb:
+    if cfg.learned_positions:
         params["text_pos"] = embedding_init(keys.next(), cfg.text_seq_len + 1, cfg.dim)
         # axial positional embedding: summed per-row and per-column tables
         params["image_pos_h"] = embedding_init(keys.next(), cfg.image_fmap_size, cfg.dim)
@@ -331,7 +346,7 @@ def remap_and_bos(cfg: DALLEConfig, text: jnp.ndarray) -> jnp.ndarray:
 def embed_text_ids(params: dict, cfg: DALLEConfig, text_ids: jnp.ndarray) -> jnp.ndarray:
     """text_ids: (b, n) post-remap ids incl. bos, positions 0..n-1."""
     emb = jnp.take(_text_table(params, cfg), text_ids, axis=0)
-    if not cfg.rotary_emb:
+    if cfg.learned_positions:
         pos = jnp.take(params["text_pos"]["table"], jnp.arange(text_ids.shape[1]), axis=0)
         emb = emb + pos
     return emb
@@ -339,7 +354,7 @@ def embed_text_ids(params: dict, cfg: DALLEConfig, text_ids: jnp.ndarray) -> jnp
 
 def image_pos_table(params: dict, cfg: DALLEConfig) -> Optional[jnp.ndarray]:
     """(image_seq_len, dim) axial positional embeddings, or None under rotary."""
-    if cfg.rotary_emb:
+    if not cfg.learned_positions:
         return None
     fmap = cfg.image_fmap_size
     h = jnp.repeat(params["image_pos_h"]["table"], fmap, axis=0)

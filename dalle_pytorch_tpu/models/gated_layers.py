@@ -18,13 +18,20 @@ Layer equations (x a token's hidden vector, no bias anywhere):
                 (q, k, v) <- silu(causal depthwise conv(q, k, v));
                 q, k L2-normalised per head, q scaled by dk^-0.5; each key
                 head serves value_heads / key_heads value heads;
-                beta = sigmoid(b), alpha = exp(-exp(A_log) * softplus(a + dt_bias));
+                beta = sigmoid(b), or 2 sigmoid(b) under `gdn_neg_eigval`
+                [`linear_allow_neg_eigval`: the transition I - beta k k^T then
+                has eigenvalues in (-1, 1]];
+                alpha = exp(-exp(A_log) * softplus(a + dt_bias));
                 o = the gated delta rule (ops/delta_rule.py);
                 out = W_o (rms(o) * w_n * silu(z)), rms over each head
 
 Statistics (every norm, the L2 normalisation, decay and beta, the delta
-rule's state) are float32 whatever the compute type.  Training path only: no
-cache, no decode step (models/transformer.refuse_hybrid).
+rule's state) are float32 whatever the compute type.  `gated_delta` has two
+forms of one layer: the full sequence (training, and a serving prefill, which
+also takes the state after the last position and the last taps - 1 inputs of
+the convolution) and `gated_delta_step`, one token of every slot on that
+state and those taps (the serving decode step).  `gated_full` runs on the
+training path only (models/transformer.refuse_hybrid).
 """
 from __future__ import annotations
 
@@ -36,8 +43,9 @@ import numpy as np
 
 from dalle_pytorch_tpu.core.module import linear, linear_init
 from dalle_pytorch_tpu.core.rng import KeyChain
+from dalle_pytorch_tpu.kernels import delta_step
 from dalle_pytorch_tpu.ops.attention import attend
-from dalle_pytorch_tpu.ops.delta_rule import gated_delta_rule
+from dalle_pytorch_tpu.ops.delta_rule import gated_delta_rule, gated_delta_step as delta_rule_step
 
 F32 = jnp.float32
 
@@ -159,39 +167,87 @@ def _l2_normalize(t, eps: float = 1e-6):
     return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + eps)
 
 
-def _conv_and_gates(cfg, qkv_in, ba, conv_w, a_log, dt_bias):
-    """The elementwise chain between the projections and the rule: conv, silu,
-    L2 normalisation, key heads spread over their value heads, beta and the
-    log of the decay; all (b, heads, n, ...) float32."""
-    b, n, _ = qkv_in.shape
+def _heads_and_gates(cfg, conv, ba, a_log, dt_bias):
+    """The elementwise chain between the convolution and the rule: silu, L2
+    normalisation, key heads spread over their value heads, beta and the log
+    of the decay.  conv: (b, n, 2 kd + vd) float32, ba: (b, n, 2 hv) float32;
+    returns q, k, v: (b, n, hv, .) and log_decay, beta: (b, n, hv), float32."""
+    b, n, _ = conv.shape
     hk, hv, dk, dv = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
     kd = hk * dk
-    qkv = jax.nn.silu(causal_depthwise_conv(conv_w, qkv_in))
+    qkv = jax.nn.silu(conv)
     q = _l2_normalize(qkv[..., :kd].reshape(b, n, hk, dk)) * (dk ** -0.5)
     k = _l2_normalize(qkv[..., kd:2 * kd].reshape(b, n, hk, dk))
     v = qkv[..., 2 * kd:].reshape(b, n, hv, dv)
     beta = jax.nn.sigmoid(ba[..., :hv])
+    if cfg.gdn_neg_eigval:
+        beta = 2.0 * beta
     log_decay = -jnp.exp(a_log.astype(F32)) * jax.nn.softplus(ba[..., hv:] + dt_bias.astype(F32))
-    heads_first = lambda t: jnp.moveaxis(t, 2, 1)
-    q, k = (heads_first(expand_kv_heads(t, hv)) for t in (q, k))
-    return q, k, heads_first(v), heads_first(log_decay), heads_first(beta)
+    q, k = (expand_kv_heads(t, hv) for t in (q, k))
+    return q, k, v, log_decay, beta
 
 
-def gated_delta_net(p, cfg, x):
-    """x: (b, n, dim) -> (b, n, dim)."""
-    b, n, _ = x.shape
-    hk, hv, dk, dv = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
-    kd, vd = hk * dk, hv * dv
+def _gate_norm(p, cfg, o, z, dtype):
+    """rms(o) * w_n * silu(z) per head: o (..., hv, dv) float32, z the gate's
+    (..., hv * dv) columns; returns (..., hv * dv) in `dtype`."""
+    z = z.reshape(o.shape)
+    o = rms_norm(p["norm"], o, cfg.norm_eps, zero_centered=False)
+    return (o * jax.nn.silu(z.astype(F32))).astype(dtype).reshape(*o.shape[:-2], -1)
+
+
+def gated_delta_net(p, cfg, x, return_state: bool = False):
+    """x: (b, n, dim) -> (b, n, dim).  `return_state` (a serving prefill):
+    also {"state": the rule's state after position n - 1 (b, hv, dk, dv)
+    float32, "taps": the convolution's last taps - 1 INPUTS (b, taps - 1,
+    2 kd + vd), zeros where the sequence is shorter}: what
+    `gated_delta_step` continues from."""
+    kd, vd = cfg.gdn_key_heads * cfg.gdn_key_dim, cfg.gdn_value_heads * cfg.gdn_value_dim
     with jax.named_scope("gdn_proj"):
         qkvz = linear(p["qkvz"], x)
         ba = linear(p["ba"], x).astype(F32)
     with jax.named_scope("gdn_conv"):
-        q, k, v, log_decay, beta = _conv_and_gates(
-            cfg, qkvz[..., :2 * kd + vd], ba, p["conv"]["w"], p["A_log"], p["dt_bias"])
+        conv_in = qkvz[..., :2 * kd + vd]
+        q, k, v, log_decay, beta = (jnp.moveaxis(t, 2, 1) for t in _heads_and_gates(
+            cfg, causal_depthwise_conv(p["conv"]["w"], conv_in), ba, p["A_log"], p["dt_bias"]))
     with jax.named_scope("gdn_scan"):
-        o = gated_delta_rule(q, k, v, log_decay, beta)
+        o, state = gated_delta_rule(q, k, v, log_decay, beta)
     with jax.named_scope("gdn_gate_norm"):
-        z = qkvz[..., 2 * kd + vd:].reshape(b, n, hv, dv)
-        o = rms_norm(p["norm"], jnp.moveaxis(o, 1, 2), cfg.norm_eps, zero_centered=False)
-        o = (o * jax.nn.silu(z.astype(F32))).astype(x.dtype).reshape(b, n, vd)
-    return linear(p["out"], o)
+        o = _gate_norm(p, cfg, jnp.moveaxis(o, 1, 2), qkvz[..., 2 * kd + vd:], x.dtype)
+    out = linear(p["out"], o)
+    if not return_state:
+        return out
+    keep = cfg.gdn_conv_kernel - 1
+    taps = jnp.pad(conv_in, ((0, 0), (keep, 0), (0, 0)))[:, -keep:]
+    return out, {"state": state, "taps": taps}
+
+
+def _use_delta_kernel(cfg) -> bool:
+    """Whether the one-token rule takes the Pallas kernel (kernels/delta_step.py:
+    one read and one write of the state, where XLA makes two reads and a
+    write), from what the code can observe, as `transformer._use_flash` does:
+    on a TPU, at head shapes a tile holds.  Everywhere else the definition
+    (`ops/delta_rule.gated_delta_step`) runs."""
+    return jax.default_backend() == "tpu" and delta_step.supports(
+        cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim)
+
+
+def gated_delta_step(p, cfg, x, carried):
+    """One token of every slot.  x: (s, 1, dim); carried: {"state": (s, hv, dk,
+    dv) float32, "taps": (s, taps - 1, 2 kd + vd)} as `gated_delta_net` (or the
+    step before) left them.  Returns (out (s, 1, dim), the new carried)."""
+    kd, vd = cfg.gdn_key_heads * cfg.gdn_key_dim, cfg.gdn_value_heads * cfg.gdn_value_dim
+    with jax.named_scope("gdn_proj"):
+        qkvz = linear(p["qkvz"], x)
+        ba = linear(p["ba"], x).astype(F32)
+    with jax.named_scope("gdn_conv_step"):
+        taps = carried["taps"]
+        window = jnp.concatenate([taps, qkvz[..., :2 * kd + vd].astype(taps.dtype)], axis=1)
+        conv = jnp.sum(window.astype(F32) * p["conv"]["w"].astype(F32), axis=1, keepdims=True)
+        q, k, v, log_decay, beta = (t[:, 0] for t in _heads_and_gates(
+            cfg, conv, ba, p["A_log"], p["dt_bias"]))
+    with jax.named_scope("gdn_step"):
+        step = delta_step.gated_delta_step_kernel if _use_delta_kernel(cfg) else delta_rule_step
+        o, state = step(q, k, v, log_decay, beta, carried["state"])
+    with jax.named_scope("gdn_gate_norm"):
+        o = _gate_norm(p, cfg, o[:, None], qkvz[..., 2 * kd + vd:], x.dtype)
+    return linear(p["out"], o), {"state": state, "taps": window[:, 1:]}

@@ -540,7 +540,7 @@ def generate_texts(
     O(text_len^2 * depth) re-forward per token (its own generate_texts never
     caches).  use_cache=False keeps the reference-shaped re-forward loop;
     both paths consume the identical RNG stream, so outputs agree."""
-    refuse_hybrid(cfg.transformer_config(), "generate_texts")
+    refuse_hybrid(cfg.transformer_config(), "generate_texts", recurrent_state=False)
     if text is None:
         text = jnp.zeros((1, 1), jnp.int32)
     text = text.astype(jnp.int32)
@@ -562,7 +562,7 @@ def generate_texts(
         buf, key = carry
         key, sk = jax.random.split(key)
         emb = jnp.take(dalle_mod._text_table(params, cfg), buf, axis=0, mode="clip")
-        if not cfg.rotary_emb:
+        if cfg.learned_positions:
             emb = emb + jnp.take(params["text_pos"]["table"], jnp.arange(ts), axis=0)
         out = apply_transformer(params["transformer"], tcfg, emb)
         if cfg.stable:
@@ -598,7 +598,7 @@ def _generate_texts_cached(
 
     def embed(ids, start):
         e = jnp.take(table, ids, axis=0, mode="clip")
-        if not cfg.rotary_emb:
+        if cfg.learned_positions:
             pos = jnp.take(
                 params["text_pos"]["table"],
                 start + jnp.arange(ids.shape[1]),

@@ -60,7 +60,7 @@ import jax.numpy as jnp
 
 from dalle_pytorch_tpu.models import dalle as dalle_mod
 from dalle_pytorch_tpu.models import sampling as sampling_mod
-from dalle_pytorch_tpu.models.transformer import decode_step, paged_decode_step
+from dalle_pytorch_tpu.models.transformer import decode_step, paged_decode_step, refuse_hybrid
 from dalle_pytorch_tpu.ops.sampling import gumbel_noise, gumbel_sample, top_k_filter
 from dalle_pytorch_tpu.ops.stable import divide_max
 from dalle_pytorch_tpu.quantization import maybe_dequant_weight
@@ -84,6 +84,9 @@ def validate_spec(tcfg, spec_k: int, spec_draft_layers: Optional[int]):
     """Validate (k, d) against the transformer config; returns the resolved
     pair.  Raises ValueError for configurations speculation cannot run on."""
     k = int(spec_k)  # host-sync-ok: static python config int
+    # a rejected token is rolled back by masking its K/V column; a recurrent
+    # state has no column to mask (it would have to be kept per drafted token)
+    refuse_hybrid(tcfg, f"speculative decoding (spec_k={k})", recurrent_state=False)
     if k < 1:
         raise ValueError(f"spec_k={k} must be >= 1 (0 disables speculation)")
     if tcfg.depth < 2:
@@ -215,12 +218,13 @@ def lane_sample_pipeline(params, cfg, out, key_index, state,
 
     def sample_one(lg_row, kk, t):
         # the fused sampler's batch-1 draw, (1, V): a threefry draw at
-        # another shape is another draw
-        noise = gumbel_noise(kk, (1, V), lg_row.dtype)[0, ntp:]
-        return jnp.argmax(lg_row / t + noise, axis=-1)
+        # another shape is another draw; float32 whatever the logits' type
+        # (ops/sampling.gumbel_sample)
+        noise = gumbel_noise(kk, (1, V), jnp.float32)[0, ntp:]
+        return jnp.argmax(lg_row.astype(jnp.float32) / t + noise, axis=-1)
 
     code = jax.vmap(sample_one)(filtered, keys_t,
-                                state["temp"].astype(logits.dtype))
+                                state["temp"].astype(jnp.float32))
     code = jnp.take(code.astype(jnp.int32), state["feed_src"], axis=0)
     return code, bad
 

@@ -146,6 +146,17 @@ class TransformerConfig:
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
     gdn_conv_kernel: int = 4
+    # beta = 2 sigmoid(b) [`linear_allow_neg_eigval`]: the rule's transition
+    # I - beta k k^T has eigenvalues in (-1, 1]
+    gdn_neg_eigval: bool = False
+    # the Olmo family's block: `pre_norm` False puts no norm on a branch's
+    # INPUT (with `sandwich_norm` the one norm sits on its output, inside the
+    # residual: h = x + N(mix(x))); `qk_norm`: the pattern layers' q and k
+    # pass an RMSNorm over the WHOLE inner width before the split into heads;
+    # `attn_bias` [`attention_bias`]: the pattern layers' out-projection bias
+    pre_norm: bool = True
+    qk_norm: bool = False
+    attn_bias: bool = True
     # routed feed-forward (models/moe.py): 0 experts = the dense GEGLU.  The
     # router is `moe_experts` wide; the layer holds `moe_experts_held` of them
     # (None = all) from `moe_first_expert` on: one rank's share of an
@@ -201,6 +212,26 @@ class TransformerConfig:
         return (self.moe_experts > 0 or self.norm != "layernorm" or self.dense_layers > 0
                 or any(t in HYBRID_ATTN_TYPES for t in self.attn_types))
 
+    @property
+    def unserved(self) -> bool:
+        """What of the block no cached or paged entry point computes (see
+        `refuse_hybrid`): `gated_full` / `mla` layers and routed experts.
+        `gated_delta` layers (a recurrent state and convolution taps per
+        sequence beside the K/V cache), dense SwiGLU layers and the RMSNorms
+        are served."""
+        return self.moe_experts > 0 or any(t in ("gated_full", "mla") for t in self.attn_types)
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer keeps a per-sequence state where the others keep keys."""
+        return "gated_delta" in self.attn_types
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep keys and values (blocks of the paged pool)."""
+        return sum(self.attn_types[i % len(self.attn_types)] != "gated_delta"
+                   for i in range(self.depth))
+
     def ff_type(self, index: int) -> str:
         """What layer `index`'s feed-forward IS: 'swiglu' (a leading dense
         layer), 'moe' (routed experts) or 'geglu' (the DALL-E block's)."""
@@ -247,21 +278,27 @@ def derive_layer_specs(cfg: TransformerConfig) -> List[LayerSpec]:
     return specs
 
 
-def refuse_hybrid(cfg: TransformerConfig, what: str) -> None:
-    """The one error of every entry point that cannot run a hybrid block
-    (`gated_delta` / `gated_full` / `mla` layers, routed experts, leading
-    dense SwiGLU layers, RMSNorm): they are computed by the full-sequence
-    training path alone.  Serving them needs a recurrent state beside the K/V
-    cache and a one-token form of the delta rule, a latent cache and the
-    absorbed decode form of `mla` (ROADMAP.md, Queue 2); a wrong picture is
-    worse than none."""
-    if cfg.hybrid:
-        raise NotImplementedError(
-            f"{what} does not support this block (attn_types {cfg.attn_types}, "
-            f"norm {cfg.norm!r}, {cfg.moe_experts} routed experts, {cfg.dense_layers} "
-            "leading dense layers): gated_delta / gated_full / mla layers, routed "
-            "experts, dense SwiGLU layers and RMSNorm run on the training path "
-            "only (execution 'sequential' or 'remat', scan_layers off, no pipeline)")
+def hybrid_refusal(cfg: TransformerConfig, what: str) -> NotImplementedError:
+    """The one error of every entry point that cannot run a hybrid block."""
+    return NotImplementedError(
+        f"{what} does not support this block (attn_types {cfg.attn_types}, "
+        f"norm {cfg.norm!r}, {cfg.moe_experts} routed experts, {cfg.dense_layers} "
+        "leading dense layers): gated_full / mla layers and routed experts run on "
+        "the training path only (execution 'sequential' or 'remat', scan_layers off, "
+        "no pipeline); gated_delta layers, dense SwiGLU layers and RMSNorm are also "
+        "served (prefill, decode_step, the paged pool, GenerationEngine), with no "
+        "speculation, int8 pool or handed-over prefill of a recurrent state")
+
+
+def refuse_hybrid(cfg: TransformerConfig, what: str, recurrent_state: bool = True) -> None:
+    """Raise `hybrid_refusal` from a cached or paged entry point for what it
+    cannot serve: `gated_full` / `mla` layers (a gated or latent cache and
+    the absorbed decode form of `mla`) and routed experts (ROADMAP.md, Queue
+    2); a wrong picture is worse than none.  `recurrent_state` False: the
+    caller cannot carry `gated_delta`'s per-sequence state either (it rolls
+    tokens back, quantizes the pool or hands a prefill over)."""
+    if cfg.unserved or (cfg.recurrent and not recurrent_state):
+        raise hybrid_refusal(cfg, what)
 
 
 def _note_hybrid_layers(cfg: TransformerConfig, specs, gmm_paths: Dict[str, int],
@@ -379,8 +416,15 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
             # collective-permutes on every layer)
             shared_attn[spec.attn_id] = {
                 "qkv": linear_init(keys.next(), cfg.dim, cfg.inner_dim * 3, bias=False),
-                "out": linear_init(keys.next(), cfg.inner_dim, cfg.dim),
+                "out": linear_init(keys.next(), cfg.inner_dim, cfg.dim, bias=cfg.attn_bias),
             }
+            if cfg.qk_norm:
+                from dalle_pytorch_tpu.models.gated_layers import rms_norm_init
+
+                # over the whole inner width, head-major like the qkv columns
+                for name in ("q_norm", "k_norm"):
+                    shared_attn[spec.attn_id][name] = rms_norm_init(
+                        cfg.inner_dim, zero_centered=False)
         if spec.ff_id not in shared_ff:
             # GEGLU as two column-parallel projections (values / gates) — the
             # fused [a|g] layout splits across tp shards (same exchange
@@ -391,7 +435,7 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
                 "w2": linear_init(keys.next(), cfg.dim * cfg.ff_mult, cfg.dim),
             }
         eps = _layerscale_eps(spec.index + 1)
-        layer = {"attn_norm": norm_init(cfg), "ff_norm": norm_init(cfg)}
+        layer = {"attn_norm": norm_init(cfg), "ff_norm": norm_init(cfg)} if cfg.pre_norm else {}
         if cfg.layer_scale:
             layer["attn_scale"] = jnp.full((1, 1, cfg.dim), eps, jnp.float32)
             layer["ff_scale"] = jnp.full((1, 1, cfg.dim), eps, jnp.float32)
@@ -544,7 +588,20 @@ def _qkv_heads(shared, cfg, x, ang, checkpoint: bool = False):
     qkv = qkv.reshape(b, n_x, cfg.heads, 3, cfg.dim_head).transpose(0, 2, 3, 1, 4)
     if ang is not None:
         qkv = apply_rotary(ang, qkv)
-    return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q, k = _whole_width_norm(shared["q_norm"], cfg, q), _whole_width_norm(shared["k_norm"], cfg, k)
+    return q, k, v
+
+
+def _whole_width_norm(params, cfg, t):
+    """RMSNorm of a token's q (or k) over ALL heads' channels at once: t
+    (b, h, n, dh), the weight (h * dh,) head-major.  Float32 statistics."""
+    t32 = t.astype(jnp.float32)
+    ms = jnp.mean(t32 * t32, axis=(1, 3), keepdims=True)
+    w = params["w"].astype(jnp.float32).reshape(1, cfg.heads, 1, cfg.dim_head)
+    return (t32 * jax.lax.rsqrt(ms + cfg.norm_eps) * w).astype(t.dtype)
 
 
 def _use_flash(cfg, n: int, key_mask) -> bool:
@@ -735,8 +792,10 @@ def _residual_branch(
     single-token cached decode (the reference re-implements this composition
     per wrapper; here every mode runs the one definition).  Returns
     (branch output, updated layer cache or None)."""
-    with jax.named_scope("norm"):
-        h = apply_norm(cfg, wrap[f"{kind}_norm"], x)
+    h = x
+    if cfg.pre_norm:
+        with jax.named_scope("norm"):
+            h = apply_norm(cfg, wrap[f"{kind}_norm"], x)
     if cfg.shift_tokens:
         if mode == "decode":
             if text_mode:
@@ -756,7 +815,9 @@ def _residual_branch(
                 layer_cache[f"shift_{kind}"] = _fill_ring(cfg, layer_cache[f"shift_{kind}"], h)
             with jax.named_scope("token_shift"):
                 h = token_shift(h, cfg.seq_len, cfg.image_fmap_size)
-    if kind == "attn" and attn_type in HYBRID_ATTN_TYPES:
+    if kind == "attn" and attn_type == "gated_delta" and mode != "full":
+        h, layer_cache = _gated_delta_cached(attn_params, cfg, layer_cache, h, mode)
+    elif kind == "attn" and attn_type in HYBRID_ATTN_TYPES:
         h = _hybrid_mixer(attn_params, cfg, h, attn_type)
     elif kind == "ff" and ff_type == "swiglu":
         h = _dense_swiglu(ff_params, h)
@@ -801,6 +862,21 @@ def _hybrid_mixer(shared, cfg, x, attn_type: str):
 
         return mla_attention(shared, cfg, x, use_flash=use_flash, mesh=mesh)
     return gated_layers.gated_full_attention(shared, cfg, x, use_flash=use_flash, mesh=mesh)
+
+
+@jax.named_scope("attn")
+def _gated_delta_cached(shared, cfg, layer_cache, x, mode: str):
+    """`gated_delta` on a cache entry {"state", "taps"}: a prefill computes the
+    full sequence and leaves both behind, a decode step advances them by one
+    token of every row.  Returns (out, the new cache entry)."""
+    from dalle_pytorch_tpu.models import gated_layers
+
+    if mode == "prefill":
+        out, carried = gated_layers.gated_delta_net(shared, cfg, x, return_state=True)
+    else:
+        out, carried = gated_layers.gated_delta_step(shared, cfg, x, layer_cache)
+    return out, dict(layer_cache, state=carried["state"],
+                     taps=carried["taps"].astype(layer_cache["taps"].dtype))
 
 
 @jax.named_scope("ff")
@@ -863,9 +939,9 @@ def apply_transformer(
     if cfg.hybrid and (cfg.scan_layers or cfg.pipeline_axis is not None
                        or cfg.seq_shard_axis is not None
                        or cfg.execution not in ("sequential", "remat")):
-        refuse_hybrid(cfg, f"apply_transformer(execution={cfg.execution!r}, "
-                           f"scan_layers={cfg.scan_layers}, pipeline_axis={cfg.pipeline_axis!r}, "
-                           f"seq_shard_axis={cfg.seq_shard_axis!r})")
+        raise hybrid_refusal(cfg, f"apply_transformer(execution={cfg.execution!r}, "
+                                  f"scan_layers={cfg.scan_layers}, pipeline_axis={cfg.pipeline_axis!r}, "
+                                  f"seq_shard_axis={cfg.seq_shard_axis!r})")
     if cfg.pipeline_axis is not None and not cfg.scan_layers:
         raise ValueError(
             "pipeline_axis requires scan_layers=True (pipeline stages shard "
@@ -1182,11 +1258,14 @@ def init_cache(cfg: TransformerConfig, batch: int, dtype=jnp.float32) -> dict:
     `offset` is the number of positions already consumed."""
     refuse_hybrid(cfg, "init_cache")
 
-    def entry():
-        e = {
-            "k": jnp.zeros((batch, cfg.heads, cfg.seq_len, cfg.dim_head), dtype),
-            "v": jnp.zeros((batch, cfg.heads, cfg.seq_len, cfg.dim_head), dtype),
-        }
+    def entry(spec):
+        if spec.attn_type == "gated_delta":
+            e = gated_delta_carried(cfg, batch, dtype)
+        else:
+            e = {
+                "k": jnp.zeros((batch, cfg.heads, cfg.seq_len, cfg.dim_head), dtype),
+                "v": jnp.zeros((batch, cfg.heads, cfg.seq_len, cfg.dim_head), dtype),
+            }
         if cfg.shift_tokens:
             q = cfg.dim // 4
             fmap = cfg.image_fmap_size
@@ -1194,8 +1273,21 @@ def init_cache(cfg: TransformerConfig, batch: int, dtype=jnp.float32) -> dict:
             e["shift_ff"] = jnp.zeros((batch, fmap, 2, q), dtype)
         return e
 
-    layers = [entry() for _ in derive_layer_specs(cfg)]
+    layers = [entry(spec) for spec in derive_layer_specs(cfg)]
     return {"offset": jnp.zeros((), jnp.int32), "layers": layers}
+
+
+def gated_delta_carried(cfg: TransformerConfig, rows: int, dtype) -> dict:
+    """What a `gated_delta` layer keeps per sequence (a batch row of the dense
+    cache, a slot of the paged pool) where the other layers keep keys: the
+    rule's state, float32 whatever `dtype` is, zero at a sequence's start, and
+    the convolution's last taps - 1 inputs in `dtype`."""
+    channels = 2 * cfg.gdn_key_heads * cfg.gdn_key_dim + cfg.gdn_value_heads * cfg.gdn_value_dim
+    return {
+        "state": jnp.zeros((rows, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim),
+                           jnp.float32),
+        "taps": jnp.zeros((rows, cfg.gdn_conv_kernel - 1, channels), dtype),
+    }
 
 
 @jax.named_scope("token_shift")
@@ -1408,6 +1500,7 @@ def decode_step(
             rotary=rotary, pattern=patterns[_pattern_key(spec)],
             layer_cache=layer_cache, offset=offset, text_mode=text_only,
             decode_tab=dec_tabs.get(_pattern_key(spec)),
+            attn_type=spec.attn_type, ff_type=cfg.ff_type(spec.index),
         )
 
     out, new_layers = _run_cached_layers(cfg, specs, x, cache, branch)
@@ -1441,6 +1534,7 @@ def prefill(
             params["shared_ff"][spec.ff_id], x, kind, mode="prefill",
             rotary=rotary, pattern=patterns[_pattern_key(spec)], key_mask=key_mask,
             layer_cache=layer_cache,
+            attn_type=spec.attn_type, ff_type=cfg.ff_type(spec.index),
         )
 
     out, new_layers = _run_cached_layers(cfg, specs, x, cache, branch)
@@ -1521,23 +1615,34 @@ def paged_blocks_per_seq(cfg: TransformerConfig, block_size: int) -> int:
 
 def init_paged_pool(
     cfg: TransformerConfig, num_blocks: int, block_size: int, dtype=jnp.float32,
-    quantize: Optional[str] = None,
+    quantize: Optional[str] = None, num_slots: Optional[int] = None,
 ) -> dict:
     """One shared KV block pool: per layer, (num_blocks, heads, block_size,
     dim_head) k/v arrays.  Block 0 is conventionally reserved by the serving
     pool as the trash block inactive slots write into.
+
+    A `gated_delta` layer keeps no keys and holds no blocks: its entry is
+    `gated_delta_carried` for `num_slots` slots ({"state", "taps"}, indexed by
+    SLOT, admitted and freed with it), beside the block tables that address
+    the other layers' entries.
 
     `quantize="int8"` stores int8 k/v with PER-TOKEN bf16 scales beside the
     blocks (`k_scale`/`v_scale`, block shape minus dim_head) — per-token so
     the decode scatter of one new column never re-scales a block's existing
     tokens.  Every paged op downstream keys off the presence of the scale
     arrays, so the quantized pool threads through the same jits."""
-    refuse_hybrid(cfg, "init_paged_pool")
+    quantized = bool(quantize) and quantize != "none"
+    refuse_hybrid(cfg, "init_paged_pool", recurrent_state=not quantized)
     from dalle_pytorch_tpu.quantization import KV_SCALE_DTYPE
 
-    def entry():
+    def entry(spec):
+        if spec.attn_type == "gated_delta":
+            if num_slots is None:
+                raise ValueError("init_paged_pool: a gated_delta layer's state is per slot; "
+                                 "pass num_slots")
+            return gated_delta_carried(cfg, num_slots, dtype)
         shape = (num_blocks, cfg.heads, block_size, cfg.dim_head)
-        if quantize and quantize != "none":
+        if quantized:
             sshape = shape[:-1]
             return {
                 "k": jnp.zeros(shape, jnp.int8),
@@ -1547,7 +1652,7 @@ def init_paged_pool(
             }
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
-    return {"layers": [entry() for _ in range(cfg.depth)]}
+    return {"layers": [entry(spec) for spec in derive_layer_specs(cfg)]}
 
 
 def init_slot_rings(
@@ -1577,12 +1682,16 @@ def write_prefill_to_pool(
     cache_layers,
     n_pre: int,
     block_size: int,
+    slots: Optional[jnp.ndarray] = None,
 ) -> dict:
     """Scatter a freshly prefilled DENSE cache's first `n_pre` positions into
     the block pool — prefill itself runs the existing `prefill` (identical
     math, so parity is free) and this is pure data movement.  `block_tables`:
     (b, max_blocks) physical block ids for the b newly admitted slots;
-    `cache_layers`: the `layers` entry of the cache `prefill` returned.
+    `cache_layers`: the `layers` entry of the cache `prefill` returned;
+    `slots`: (b,) the slots admitted, where a `gated_delta` layer's state and
+    taps go WHOLE (scope `state_write`): whatever the slot's last request
+    left there is overwritten, none of it read.
 
     Quantized pools (layer entries carrying `k_scale`) accept EITHER a
     dense float cache (the fused admit: quantize at scatter) or a
@@ -1624,6 +1733,14 @@ def write_prefill_to_pool(
     tbl = block_tables[:, :nb]
     new_layers = []
     for lp, lc in zip(pool["layers"], cache_layers):
+        if "state" in lp:
+            if slots is None:
+                raise ValueError("write_prefill_to_pool: a gated_delta layer's state goes to a "
+                                 "slot; pass slots")
+            with jax.named_scope("state_write"):
+                new_layers.append({name: lp[name].at[slots].set(lc[name].astype(lp[name].dtype))
+                                   for name in ("state", "taps")})
+            continue
         pk = packed_kv(lp, lc)
         new_layers.append(dict(lp, **{
             name: lp[name].at[tbl].set(arr.astype(lp[name].dtype))
@@ -1768,18 +1885,23 @@ def _paged_shift_step(cfg, ring, x, offsets):
 
 def _paged_branch(cfg, wrap, attn_params, ff_params, x, kind, layer_pool,
                   block_tables, offsets, ring, pattern, rotary, block_size,
-                  decode_tab=None, use_kernel=False):
+                  decode_tab=None, use_kernel=False, attn_type="full", ff_type="geglu"):
     """Decode-mode residual branch over paged per-slot state — the same
     composition as `_residual_branch(mode='decode')` with vectors where that
     path has scalars.  Returns (branch out, new ring, layer pool): an attn
     branch hands back the pool with the new K/V column written (in the
-    kernel, or by `_paged_scatter_cols`), an ff branch the pool it got."""
-    with jax.named_scope("norm"):
-        h = layer_norm(wrap[f"{kind}_norm"], x)
+    kernel, or by `_paged_scatter_cols`) or, from a `gated_delta` layer, with
+    every slot's state and taps advanced; an ff branch the pool it got."""
+    h = x
+    if cfg.pre_norm:
+        with jax.named_scope("norm"):
+            h = apply_norm(cfg, wrap[f"{kind}_norm"], x)
     new_ring = ring
     if cfg.shift_tokens:
         h, new_ring = _paged_shift_step(cfg, ring, h, offsets)
-    if kind == "attn" and use_kernel:
+    if kind == "attn" and attn_type == "gated_delta":
+        h, layer_pool = _gated_delta_cached(attn_params, cfg, layer_pool, h, "decode")
+    elif kind == "attn" and use_kernel:
         h, layer_pool = _paged_attention_kernel_step(
             attn_params, cfg, layer_pool, block_tables, offsets, h, pattern,
             rotary,
@@ -1791,12 +1913,16 @@ def _paged_branch(cfg, wrap, attn_params, ff_params, x, kind, layer_pool,
         )
         layer_pool = _paged_scatter_cols(
             layer_pool, block_tables, offsets, cols, block_size)
+    elif ff_type == "swiglu":
+        h = _dense_swiglu(ff_params, h)
     else:
         h = _feed_forward(ff_params, cfg, h, None)
     with jax.named_scope("norm"):
         if cfg.sandwich_norm:
-            h = layer_norm(wrap[f"{kind}_norm_out"], h)
-        return h * wrap[f"{kind}_scale"].astype(h.dtype), new_ring, layer_pool
+            h = apply_norm(cfg, wrap[f"{kind}_norm_out"], h)
+        if cfg.layer_scale:
+            h = h * wrap[f"{kind}_scale"].astype(h.dtype)
+        return h, new_ring, layer_pool
 
 
 def paged_decode_step(
@@ -1824,7 +1950,10 @@ def paged_decode_step(
 
     `path_tally`: a dict of the caller's; while the step is TRACED it gains
     the number of attention layers that took the Pallas paged kernel
-    ("kernel") and that ran the gather path ("fallback")."""
+    ("kernel"), that ran the gather path ("fallback") and that advanced a
+    recurrent state instead ("state": the `gated_delta` layers, which hold no
+    blocks and read neither `block_tables` nor `offsets`; "state_kernel": those
+    of them whose one-token rule took `kernels/delta_step.py`)."""
     refuse_hybrid(cfg, "paged_decode_step")
     specs = derive_layer_specs(cfg)
     specs, partial = _resolve_layer_range(cfg, specs, layer_start, layer_stop)
@@ -1837,6 +1966,15 @@ def paged_decode_step(
     patterns = spec_patterns(cfg, specs)
     use_kernel = {}
     for spec in specs:
+        if spec.attn_type == "gated_delta":  # no keys: neither path, and no gather table (its pattern is None)
+            use_kernel[spec.index] = False
+            if path_tally is not None:
+                from dalle_pytorch_tpu.models import gated_layers
+
+                path_tally["state"] = path_tally.get("state", 0) + 1
+                path_tally["state_kernel"] = path_tally.get("state_kernel", 0) + int(
+                    gated_layers._use_delta_kernel(cfg))
+            continue
         use_kernel[spec.index] = _use_paged_kernel(
             cfg, pool["layers"][spec.index], patterns[_pattern_key(spec)],
             block_size)
@@ -1854,6 +1992,7 @@ def paged_decode_step(
             offsets, ring, patterns[_pattern_key(spec)], rotary, block_size,
             decode_tab=dec_tabs.get(_pattern_key(spec)),
             use_kernel=use_kernel[spec.index],
+            attn_type=spec.attn_type, ff_type=cfg.ff_type(spec.index),
         )
 
     new_pool_layers, new_ring_layers = [], []

@@ -17,9 +17,18 @@ normalised.  `jax.grad` differentiates it as written (the backward is the
 transposed scan over chunks), but for the triangular inverse, which brings its
 own backward.
 
-The plain recurrence it must equal is written apart from this package, in the
-benchmark's reference (`benchmark/reference/qwen3_next_reference.py`,
-`delta_rule_recurrence`); tests/test_hybrid_trunk.py holds the two together.
+`gated_delta_rule` also returns the state after the last position: what a
+serving prefill hands to the decode step, which advances it one token at a
+time with `gated_delta_step` (the three lines above as they stand, on a state
+per slot).  beta ranges over (0, 2): with beta = 2 sigmoid(b) the transition
+I - beta k k^T has eigenvalues in (-1, 1] (`gdn_neg_eigval`), and nothing here
+relies on beta < 1 (the triangular system is unit lower-triangular whatever
+beta is).
+
+The plain recurrence both must equal is written apart from this package, in
+the benchmark's references (`benchmark/reference/qwen3_next_reference.py` and
+`olmo_hybrid_reference.py`, `delta_rule_recurrence`); tests/test_hybrid_trunk.py
+and tests/test_olmo_hybrid_serving.py hold them together.
 """
 from __future__ import annotations
 
@@ -67,9 +76,11 @@ _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 def gated_delta_rule(q, k, v, log_decay, beta, chunk: int = CHUNK):
     """q, k: (b, h, n, dk), L2-normalised per head, q scaled; v: (b, h, n, dv);
-    log_decay = log(alpha) <= 0 and beta in (0, 1): (b, h, n).  Returns the
-    outputs o: (b, h, n, dv), float32.  Any n: a tail that does not fill a
-    chunk is padded with positions that neither write (beta 0) nor decay."""
+    log_decay = log(alpha) <= 0 and beta in (0, 2): (b, h, n).  Returns (the
+    outputs o: (b, h, n, dv), the state after position n - 1: (b, h, dk, dv)),
+    float32.  Any n: a tail that does not fill a chunk is padded with
+    positions that neither write (beta 0, k 0) nor decay (log_decay 0), so the
+    state after the pad is the state after position n - 1."""
     b, h, n, dk = q.shape
     dv = v.shape[-1]
     f32 = jnp.float32
@@ -111,6 +122,25 @@ def gated_delta_rule(q, k, v, log_decay, beta, chunk: int = CHUNK):
         return state, out
 
     chunks_first = lambda a: jnp.moveaxis(a, 2, 0)
-    _, out = jax.lax.scan(one_chunk, jnp.zeros((b, h, dk, dv), f32),
-                          tuple(map(chunks_first, (q, k, writes, k_seen, qk, g))))
-    return jnp.moveaxis(out, 0, 2).reshape(b, h, nc * chunk, dv)[:, :, :n]
+    state, out = jax.lax.scan(one_chunk, jnp.zeros((b, h, dk, dv), f32),
+                              tuple(map(chunks_first, (q, k, writes, k_seen, qk, g))))
+    return jnp.moveaxis(out, 0, 2).reshape(b, h, nc * chunk, dv)[:, :, :n], state
+
+
+def gated_delta_step(q, k, v, log_decay, beta, state):
+    """The rule for ONE position of every row: q, k: (s, h, dk); v: (s, h, dv);
+    log_decay, beta: (s, h); state: (s, h, dk, dv) float32.  Returns (o:
+    (s, h, dv), the new state), float32.  Elementwise products and sums over
+    dk, no matrix unit (a rank-1 update has nothing for it, and its float32
+    products would be rounded to bfloat16 there).  S'^T k and S'^T q are read
+    in ONE pass over the state (o = S^T q = S'^T q + (k . q) u with u = beta
+    (v - S'^T k), so the output needs no pass over the new state), and the
+    write is the second and last."""
+    f32 = jnp.float32
+    q, k, v, beta = (a.astype(f32) for a in (q, k, v, beta))
+    decayed = state * jnp.exp(log_decay.astype(f32))[..., None, None]
+    read_k = jnp.sum(decayed * k[..., None], axis=-2)
+    read_q = jnp.sum(decayed * q[..., None], axis=-2)
+    u = beta[..., None] * (v - read_k)
+    out = read_q + jnp.sum(k * q, axis=-1, keepdims=True) * u
+    return out, decayed + k[..., None] * u[..., None, :]

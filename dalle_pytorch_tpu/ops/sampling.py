@@ -17,8 +17,12 @@ def gumbel_noise(key: jax.Array, shape, dtype=jnp.float32) -> jnp.ndarray:
 
 def gumbel_sample(key: jax.Array, logits: jnp.ndarray, temperature: float = 1.0, axis: int = -1):
     """argmax(logits / temperature + G); with -inf-filtered logits the noise
-    leaves masked entries at -inf, so this samples from the softmax."""
-    return jnp.argmax(logits / temperature + gumbel_noise(key, logits.shape, logits.dtype), axis=axis)
+    leaves masked entries at -inf, so this samples from the softmax.  The
+    noise and the sum are float32 whatever the logits' type: a bfloat16
+    uniform has 128 values, and an argmax over logits + that noise never
+    reaches the tail of the kept logits, so it is no draw from their softmax."""
+    return jnp.argmax(logits.astype(jnp.float32) / temperature
+                      + gumbel_noise(key, logits.shape, jnp.float32), axis=axis)
 
 
 def top_k_filter(logits: jnp.ndarray, thres: float = 0.5) -> jnp.ndarray:

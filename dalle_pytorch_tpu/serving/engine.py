@@ -142,6 +142,10 @@ class GenerationEngine:
     ):
         assert cfg.image_seq_len >= 2, "engine needs at least 2 image tokens"
         refuse_hybrid(cfg.transformer_config(), "GenerationEngine")
+        if engine_cfg.quantize_kv not in (None, "none"):
+            refuse_hybrid(cfg.transformer_config(),
+                          f"GenerationEngine(quantize_kv={engine_cfg.quantize_kv!r})",
+                          recurrent_state=False)
         self.params = params
         self.cfg = cfg
         self.tcfg = cfg.transformer_config()
@@ -165,7 +169,9 @@ class GenerationEngine:
             engine_cfg.block_size,
             dtype=ldtype,
             quant=kv_quant,
+            num_slots=engine_cfg.num_slots,
         )
+        obs_metrics.gauge("serving/state_bytes").set(self.pool.state_bytes())
         # KV-pool flight recorder + live gauges (observability/pool.py):
         # block-lifecycle events at the existing admission/eviction syncs,
         # flushed as kind:"pool" records at the telemetry-window cadence
@@ -395,13 +401,16 @@ class GenerationEngine:
 
     def _note_paged_paths(self, paths: Dict[str, int]) -> None:
         """Runs while the decode program is TRACED: how many of its attention
-        layers took the Pallas paged kernel and how many the XLA gather, and
-        that its head and lookup read the table laid out at build, into the
-        registry (and the status file).  A retrace counts nothing new."""
+        layers took the Pallas paged kernel, how many the XLA gather and how
+        many advance a recurrent state instead, and that its head and lookup
+        read the table laid out at build, into the registry (and the status
+        file).  A retrace counts nothing new."""
         if self._paged_paths is None:
             self._paged_paths = dict(paths)
             obs_metrics.counter("serving/paged_attn_kernel_layers").inc(paths["kernel"])
             obs_metrics.counter("serving/paged_attn_fallback_layers").inc(paths["fallback"])
+            obs_metrics.counter("serving/gdn_state_layers").inc(paths.get("state", 0))
+            obs_metrics.counter("serving/gdn_step_kernel_layers").inc(paths.get("state_kernel", 0))
             obs_metrics.counter("serving/decode_head_prepared").inc()
 
     def _prefill_sample_impl(self, params, text, k0, temperature,
@@ -418,7 +427,7 @@ class GenerationEngine:
         bit-identical."""
         pool = write_prefill_to_pool(
             state["pool"], bt_rows, cache_layers,
-            self.n_pre, self.ecfg.block_size,
+            self.n_pre, self.ecfg.block_size, slots=lane_idx,
         )
         rings = state["rings"]
         if rings is not None:
@@ -1337,6 +1346,7 @@ class GenerationEngine:
                 **spec_fields,
                 **self.quantization_state(),
                 **self.paged_path_state(),
+                **self.recurrent_state_info(),
                 **self.decode_head_state(),
             )
         # flight-recorder drain rides the same cadence: pending block-
@@ -1369,6 +1379,7 @@ class GenerationEngine:
             "pool_occupancy_frac": self.pool.occupancy_frac,
             "pool_free_blocks": self.pool.free_blocks,
             **self.paged_path_state(),
+            **self.recurrent_state_info(),
             **self.decode_head_state(),
         }
         payload["pool"] = self.pool_observability()
@@ -1383,6 +1394,37 @@ class GenerationEngine:
         paths = self._paged_paths or {}
         return {"paged_attn_kernel_layers": paths.get("kernel"),
                 "paged_attn_fallback_layers": paths.get("fallback")}
+
+    def recurrent_state_info(self) -> Dict[str, Optional[int]]:
+        """How many layers of the decode program advance a per-slot recurrent
+        state where the others read blocks, how many of those took the
+        one-token rule's kernel (fewer: the XLA form ran, two reads and a write
+        of the state; both None until the program is traced), and the bytes of
+        that state and its taps: the registry's `serving/gdn_state_layers`,
+        `serving/gdn_step_kernel_layers` and `serving/state_bytes`, carried
+        beside the path counts."""
+        paths = self._paged_paths
+        return {"gdn_state_layers": None if paths is None else paths.get("state", 0),
+                "gdn_step_kernel_layers": None if paths is None else paths.get("state_kernel", 0),
+                "state_bytes": self.pool.state_bytes()}
+
+    def recurrent_snapshot(self) -> List[Dict[str, Any]]:
+        """What every in-flight request's first lane holds of a recurrent
+        state, after every step dispatched so far (one sync): the request, the
+        `positions` of [<bos>, text, codes] its state has taken in, the `codes`
+        sampled so far (positions - n_pre + 1 of them: the last one is not fed
+        yet) and `states`, one (heads, dk, dv) float32 array a `gated_delta`
+        layer, in layer order.  Empty for a trunk that keeps none."""
+        if self.tcfg.depth == self.tcfg.kv_layers or not self._inflight:
+            return []
+        lanes = jnp.asarray([req.lanes[0] for req in self._inflight], jnp.int32)
+        st = self._state
+        offsets, codes, states = jax.device_get((  # host-sync-ok: a snapshot is a sync
+            st["offsets"][lanes], st["codes"][lanes],
+            [layer["state"][lanes] for layer in st["pool"]["layers"] if "state" in layer]))
+        return [{"request": req, "positions": at, "codes": codes[i, :at - self.n_pre + 1],
+                 "states": [s[i] for s in states]}
+                for i, (req, at) in enumerate(zip(self._inflight, offsets.tolist()))]
 
     def decode_head_state(self) -> Dict[str, Optional[int]]:
         """Whether the decode program was traced on the table laid out at

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dalle_pytorch_tpu.models.transformer import refuse_hybrid
 from dalle_pytorch_tpu.observability import comms as comms_mod
 from dalle_pytorch_tpu.observability import metrics as obs_metrics
 from dalle_pytorch_tpu.observability import tracing
@@ -100,6 +101,8 @@ class PrefillWorker:
         self.params = params
         self.cfg = cfg
         self.tcfg = cfg.transformer_config()
+        # the handoff's wire format and its comms row price K/V columns alone
+        refuse_hybrid(self.tcfg, "PrefillWorker (disaggregated prefill)", recurrent_state=False)
         self.filter_thres = filter_thres
         self.quantize_kv = None if quantize_kv == "none" else quantize_kv
         self.n_pre = cfg.text_seq_len + 1

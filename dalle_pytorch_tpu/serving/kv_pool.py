@@ -16,6 +16,15 @@ Block 0 is the TRASH block: inactive engine slots keep all-zero block
 tables, so their masked decode lanes scatter into block 0 and can only
 clobber garbage.  It is never handed out.
 
+Two kinds of cache, one manager.  A `gated_delta` layer keeps no keys: per
+SLOT it keeps the rule's float32 state and the convolution's last inputs
+(`transformer.gated_delta_carried`), in the same device pool, as that layer's
+entry.  A slot's state is admitted and freed with the slot: the ingest
+program overwrites it whole at admission (nothing of the slot's last request
+is read), so eviction has nothing to release and the free list counts blocks
+of the layers that hold them (`cfg.kv_layers`) alone; `state_bytes` prices
+the rest.
+
 Flight recorder: attach a `PoolFlightRecorder` (`pool.recorder = ...`) and
 every alloc_table / free_table / truncate_slot leaves a block-lifecycle
 event — owner, block ids, occupancy/high-water at that instant, monotonic
@@ -38,6 +47,7 @@ import numpy as np
 
 from dalle_pytorch_tpu.models.transformer import (
     TransformerConfig,
+    gated_delta_carried,
     init_paged_pool,
     paged_blocks_per_seq,
 )
@@ -127,6 +137,7 @@ class BlockPool:
     block_size: int
     dtype: Any = None
     quant: Optional[str] = None  # "int8" for a quantized pool, else None
+    num_slots: int = 0  # rows of the per-slot state of `gated_delta` layers
 
     def __post_init__(self):
         assert self.block_size > 0 and self.num_blocks > 0
@@ -147,7 +158,7 @@ class BlockPool:
 
         dt = dtype if dtype is not None else (self.dtype or jnp.float32)
         return init_paged_pool(self.cfg, self.num_blocks + 1, self.block_size,
-                               dt, quantize=self.quant)
+                               dt, quantize=self.quant, num_slots=self.num_slots)
 
     def bytes(self, itemsize: int = 4) -> float:
         """At-rest bytes of the device pool (k + v, every layer).  On a
@@ -157,10 +168,22 @@ class BlockPool:
         from dalle_pytorch_tpu.quantization import kv_bytes_per_elem
 
         return (
-            2.0 * self.cfg.depth * (self.num_blocks + 1) * self.cfg.heads
+            2.0 * self.cfg.kv_layers * (self.num_blocks + 1) * self.cfg.heads
             * self.block_size * self.cfg.dim_head
             * kv_bytes_per_elem(self.quant, itemsize, self.cfg.dim_head)
         )
+
+    def state_bytes(self) -> int:
+        """At-rest bytes of the per-slot recurrent state (float32) and
+        convolution taps (the pool's type) of every `gated_delta` layer."""
+        import jax
+
+        state_layers = self.cfg.depth - self.cfg.kv_layers
+        if not state_layers:
+            return 0
+        carried = jax.eval_shape(
+            lambda: gated_delta_carried(self.cfg, self.num_slots, self.dtype or np.float32))
+        return state_layers * sum(a.size * a.dtype.itemsize for a in carried.values())
 
     def prefix_bytes(self, n_tokens: int,
                      itemsize: Optional[int] = None) -> float:
@@ -175,7 +198,7 @@ class BlockPool:
         if itemsize is None:
             itemsize = (np.dtype(self.dtype).itemsize
                         if self.dtype is not None else 4)
-        return (2.0 * self.cfg.depth * self.cfg.heads * n_tokens
+        return (2.0 * self.cfg.kv_layers * self.cfg.heads * n_tokens
                 * self.cfg.dim_head
                 * kv_bytes_per_elem(self.quant, itemsize, self.cfg.dim_head))
 
@@ -310,7 +333,7 @@ def blocks_within_bytes(cfg: TransformerConfig, budget_bytes: float,
     what lets admission pass at 2x the slot count."""
     from dalle_pytorch_tpu.quantization import kv_bytes_per_elem
 
-    per_block = (2.0 * cfg.depth * cfg.heads * block_size * cfg.dim_head
+    per_block = (2.0 * cfg.kv_layers * cfg.heads * block_size * cfg.dim_head
                  * kv_bytes_per_elem(kv_quant, itemsize, cfg.dim_head))
     return max(int(budget_bytes // per_block) - 1, 0)  # -1: the trash block
 

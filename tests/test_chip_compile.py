@@ -392,6 +392,100 @@ def test_decode_program_reads_the_image_table_where_it_lies(decode_program):
     assert re.search(r"\{\d+\}: \(%s, \{\}, may-alias\)" % param, text.splitlines()[0]), param
 
 
+@pytest.fixture(scope="module")
+def olmoh_decode_program(one_chip):
+    """(engine, text of its decode program compiled for the chip) of the cell
+    `serve_olmoh_s32` as it is: the configuration's file, 32 slots, bfloat16.
+    Nothing runs and nothing of that size is allocated: the engine is built
+    under `jax.eval_shape` (its weights, pool and state are shapes) and only
+    its decode function and the shapes of its state are used."""
+    import json
+    from pathlib import Path
+
+    from benchmark.harness import build
+    from dalle_pytorch_tpu.core.pytree import cast_floating
+    from dalle_pytorch_tpu.models import dalle as dalle_mod
+    from dalle_pytorch_tpu.models import gated_layers
+    from dalle_pytorch_tpu.serving.engine import EngineConfig, GenerationEngine
+
+    root = Path(__file__).resolve().parents[1]
+    sizes = json.loads((root / "benchmark" / "configs" / "olmo_hybrid_7b_p1.json").read_text())
+    traffic = json.loads((root / "benchmark" / "traffic" / "closed_batch_s32_c32.json").read_text())
+    cfg = build.dalle_config(sizes, execution="sequential", scan_layers=False)
+    params = jax.eval_shape(lambda k: cast_floating(dalle_mod.init_dalle(k, cfg), jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    held = {}
+
+    def make(p):
+        held["engine"] = GenerationEngine(p, cfg, engine_cfg=EngineConfig(
+            num_slots=traffic["slots"], block_size=traffic["block_size"],
+            filter_thres=traffic["filter_thres"]))
+        return held["engine"]._state
+
+    state = jax.eval_shape(make, params)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    # the layer asks jax.default_backend(), which is the CPU here: its TPU answer
+    steered = gated_layers._use_delta_kernel
+    gated_layers._use_delta_kernel = lambda cfg: True
+    try:
+        text = held["engine"]._decode_fn.lower(described(params), described(state)).compile().as_text()
+    finally:
+        gated_layers._use_delta_kernel = steered
+    return held["engine"], state, text
+
+
+def test_olmoh_decode_program_holds_one_paged_kernel_and_three_state_kernels(olmoh_decode_program):
+    """`serve_decode_step` of `serve_olmoh_s32` compiled for the chip: the one
+    `full` layer takes `paged_decode_attn` on the bfloat16 pool (no gather-path
+    layer), the three `gated_delta` layers one `gdn_step` each, and the
+    counters say 3 state layers, 1 kernel layer."""
+    eng, _, text = olmoh_decode_program
+    assert eng._paged_paths == {"kernel": 1, "fallback": 0, "state": 3, "state_kernel": 3}
+    # every state layer took the kernel: a silent fall to the XLA form would read fewer
+    info = eng.recurrent_state_info()
+    assert info["gdn_state_layers"] == info["gdn_step_kernel_layers"] == 3
+    assert eng.recurrent_state_info()["state_bytes"] == 32 * 3 * (30 * 96 * 192 * 4 + 3 * 11520 * 2)
+    entry = text[text.index("ENTRY "):]
+    calls = [ln for ln in entry.splitlines() if _op_of(ln) == "custom-call" and "tpu_custom_call" in ln]
+    named = lambda name: [ln for ln in calls if f'kernel_name = \\"{name}\\"' in ln or f"/{name}" in ln]
+    assert len(named("paged_decode_attn")) == 1 and len(named("gdn_step")) == 3, len(calls)
+
+
+def test_olmoh_decode_program_moves_no_state_pool_or_table_sized_array(olmoh_decode_program):
+    """The state goes in as a parameter, through ONE operation (its layer's
+    kernel, which reads it once and writes it once) and out aliased to the
+    parameter: no XLA pass over it, no `copy` or `transpose` of a state-, pool-
+    or table-sized array, no staging through VMEM (`slice-start`,
+    `copy-start`).  The XLA form of the rule, which this replaced, showed two
+    reads and a write a layer here."""
+    _, state, text = olmoh_decode_program
+    entry = text[text.index("ENTRY "):]
+    layers = state["pool"]["layers"]
+
+    def shape_of(a):
+        return {"float32": "f32", "bfloat16": "bf16"}[a.dtype.name] + "[" + ",".join(map(str, a.shape)) + "]"
+
+    ops = {}
+    for line in entry.splitlines()[1:]:
+        if shape_of(layers[0]["state"]) in line:
+            ops[_op_of(line)] = ops.get(_op_of(line), 0) + 1
+    assert ops == {"parameter": 3, "custom-call": 3, "get-tuple-element": 3, "tuple": 1}, ops
+    big = [shape_of(layers[0]["state"]), shape_of(layers[3]["k"]), shape_of(state["head"]["table"]),
+           "bf16[8192,3840]", "bf16[3840,8192]"]
+    moved = [ln[:160] for ln in entry.splitlines()
+             if _op_of(ln) in ("copy", "transpose", "copy-start", "slice-start") and any(b in ln for b in big)]
+    assert moved == [], moved
+    head = entry.splitlines()[0] if "may-alias" in entry.splitlines()[0] else text.splitlines()[0]
+    for i in range(3):
+        (param,) = re.findall(
+            r"parameter\((\d+)\)[^\n]*op_name=\"state\[\\'pool\\'\]\[\\'layers\\'\]\[%d\]\[\\'state\\'\]\"" % i, text)
+        assert re.search(r"\{\d+\}: \(%s, \{\}, may-alias\)" % param, head), (i, param)
+
+
 @pytest.mark.parametrize("lookup", ["flops", "hbm", "ici"])
 def test_chip_table_knows_v5e_and_refuses_unknown(lookup):
     """Every consumer of the one hardware table (MFU peak, HBM capacity, ICI

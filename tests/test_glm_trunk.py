@@ -424,10 +424,15 @@ def test_sampling_and_the_engine_refuse_the_block(model):
 
 
 def test_a_dense_layer_alone_makes_a_trunk_hybrid():
+    """Hybrid for the training path's refusals (scan, pipeline, reversible), and
+    served since PR 33: what the cached entry points refuse is narrower."""
     cfg = tr.TransformerConfig(dim=8, depth=2, seq_len=4, dense_layers=1, dense_ff_dim=16)
-    assert cfg.hybrid
+    assert cfg.hybrid and not cfg.unserved
+    tr.refuse_hybrid(cfg, "init_cache")  # a dense SwiGLU layer is served
     with pytest.raises(NotImplementedError, match="1 leading dense layers"):
-        tr.refuse_hybrid(cfg, "init_cache")
+        tr.apply_transformer({}, dataclasses.replace(cfg, scan_layers=True), jnp.zeros((1, 4, 8)))
+    with pytest.raises(NotImplementedError, match="1 leading dense layers"):
+        tr.refuse_hybrid(dataclasses.replace(cfg, moe_experts=4), "init_cache")
 
 
 # --------------------------------------------------- the configuration's file

@@ -132,7 +132,7 @@ def _recurrence(q, k, v, g, beta):
 @pytest.mark.parametrize("n,chunk", [(150, 64), (64, 64), (37, 16), (5, 64)])
 def test_chunked_delta_rule_equals_the_recurrence(n, chunk):
     args = _rule_inputs(n)
-    got = gated_delta_rule(*args, chunk=chunk)
+    got, _ = gated_delta_rule(*args, chunk=chunk)
     want = _recurrence(*args)
     assert got.shape == want.shape == (2, 3, n, 24)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=RULE_ATOL)
@@ -140,7 +140,7 @@ def test_chunked_delta_rule_equals_the_recurrence(n, chunk):
 
 def test_chunked_delta_rule_gradients_equal_the_recurrences():
     args = _rule_inputs(100)
-    g_chunk = jax.jit(jax.grad(lambda *a: (gated_delta_rule(*a) ** 2).sum(),
+    g_chunk = jax.jit(jax.grad(lambda *a: (gated_delta_rule(*a)[0] ** 2).sum(),
                                argnums=(0, 1, 2, 3, 4)))(*args)
     g_rec = jax.jit(jax.grad(lambda *a: (_recurrence(*a) ** 2).sum(),
                              argnums=(0, 1, 2, 3, 4)))(*args)
