@@ -23,6 +23,17 @@ its probabilities.  float32 in: float32 operands.  Row maxima, sums, `lse`,
 but `q.dtype`; `flash_attention` counts the calls of each kind while it is
 traced (`kernels/flash_calls_16bit_operands`, `kernels/flash_calls_32bit_operands`).
 
+Tiles: `block_q` / `block_k` are CAPS, and `resolve_block` gives each side the
+largest multiple of the 128-lane width under its cap that divides the
+sequence (default cap 384: 384 x 384 tiles at 1,152 and 4,224 positions,
+256 x 256 at 1,280 and 4,352, 128 x 128 at 640).  The kernels' time is the
+grid step's, not the matrix unit's, so the largest tile that divides the
+sequence is the fastest known; at 1,152 positions and fmap 32 it also leaves
+no image pattern a dead tile inside the causal triangle, so `grid="auto"`
+runs every such layer on the dense grid with its mask applied in the tile.
+`flash_attention` counts the tile each call resolved while it is traced
+(`kernels/flash_tile_<bq>x<bk>`).
+
 On CPU (tests) kernels run in interpret mode; any platform other than cpu or
 tpu is an error, never an interpreter.
 """
@@ -41,12 +52,18 @@ from jax.experimental.pallas import tpu as pltpu
 from dalle_pytorch_tpu.observability import health as health_mod
 from dalle_pytorch_tpu.observability import metrics as obs_metrics
 
-# 256x256 tiles measured ~5% faster per train step than 128x128 at seq 1280 on
-# v5e.  `resolve_block` halves a block until it divides n, so every sequence
-# the benchmark's cells train on runs 128x128 tiles: 1,152 = 9 x 128 and
-# 4,224 = 33 x 128 have no divisor 256.
-DEFAULT_BLOCK_Q = 256
-DEFAULT_BLOCK_K = 256
+# The default is a CAP on the tile, and `resolve_block` picks the largest
+# multiple of the 128-lane width under it that divides the sequence: 384 x 384
+# where 384 divides it (1,152 = 3 x 384 and 4,224 = 11 x 384: every sequence
+# the benchmark's cells train on), 256 x 256 where only 256 does (1,280,
+# 4,352), 128 x 128 otherwise.  The kernels are bound by the grid step, not by
+# the matrix unit: a 384 x 384 x 256 forward tile takes 1.94 us where nine
+# 128 x 128 ones take 6.3, so the same three kernels run x 3.2-3.5 faster (one
+# v5e, PERF.md section 6).  384 is the largest tile that is known to compile
+# for a v5e at head widths 128 and 256 in all seven bodies, mask tile included
+# (tests/test_chip_compile.py).
+DEFAULT_BLOCK_Q = 384
+DEFAULT_BLOCK_K = 384
 _LANES = 128  # TPU lane width; lse/delta rows are stored broadcast over lanes
 _NEG = -1e30
 
@@ -62,16 +79,23 @@ def _interpret() -> bool:
 
 
 def resolve_block(n: int, block: int) -> int:
-    """The block size actually used for sequence length n: capped at n,
-    halved until it divides n, and — when halving bottoms out below 8 —
-    falling back through plain divisors of n (largest first, preferring
-    sublane-aligned multiples of 8) before raising.  The fallback is what
-    lets odd-factor sequence lengths (e.g. n = 270 = 2*3^3*5 -> 135) reach
-    the kernel path at all; lengths with no divisor in [8, block] (e.g. the
-    fmap-48 layout length 2305 = 5*461) still fail loudly.  Shared with the
-    scan-layers path, whose tile-liveness tables must be built at exactly
-    this granularity."""
+    """The block size actually used for sequence length n under the cap
+    `block`: the LARGEST multiple of the 128-lane width that divides n and
+    does not exceed the cap (1,152 and 4,224 -> 384 under the default cap,
+    1,280 -> 256, 640 -> 128; a caller's `block=128` stays 128).  Only where
+    there is none: the cap (at most n) halved until it divides n, and, when
+    halving bottoms out below 8, plain divisors of n (largest first,
+    preferring sublane-aligned multiples of 8) before raising.  The fallback
+    is what lets odd-factor sequence lengths (e.g. n = 270 = 2*3^3*5 -> 135
+    under a cap of 256, 129 -> 43 under one of 128) reach the kernel path at
+    all; lengths with no divisor in [8, block] (e.g. the fmap-48 layout length
+    2305 = 5*461) still fail loudly.  Everything that must agree with the
+    kernels on the tile (the scan-layers path's liveness and compacted
+    tables, the profiler's tile density) calls this with the same cap."""
     cap = min(block, n)
+    for b in range(cap - cap % _LANES, 0, -_LANES):
+        if n % b == 0:
+            return b
     b = cap
     while b and n % b:
         b //= 2
@@ -883,9 +907,9 @@ def _flash_bwd_compact(q, k, v, do, out, lse, mask, kmask, tabs, h, causal,
 def _dense_recompute_grads(q, k, v, mask, kmask, h, causal, scale, lse, do):
     """Backward in XLA ops with exact probabilities from the saved logsumexp.
     Materializes (bh, n, n) transients (fused/streamed by XLA).  At 128x128
-    tiles this beat the Pallas backward at seq ~1280 on v5e; at the current
-    256x256 default the Pallas backward is both faster and O(n) memory, so
-    this path is the fallback ('xla')."""
+    tiles this beat the Pallas backward at seq ~1280 on v5e; at the 256- and
+    384-tiles `resolve_block` gives now the Pallas backward is both faster
+    and O(n) memory, so this path is the fallback ('xla')."""
     f32 = jnp.float32
     s = jnp.einsum("bid,bjd->bij", q.astype(f32) * scale, k.astype(f32))
     n = q.shape[1]
@@ -974,8 +998,9 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    # 'pallas' (two-pass kernels, O(n) memory — also the fastest at 256x256
-    # tiles on v5e) | 'xla' (dense recompute; was faster at 128x128 tiles)
+    # 'pallas' (two-pass kernels, O(n) memory; what every cell runs, and the
+    # faster the larger the resolved tile) | 'xla' (dense recompute from the
+    # saved logsumexp: (n, n) scores in HBM; no tile, kept as a reference)
     bwd_impl: str = "pallas",
     live: Optional[jnp.ndarray] = None,
     key_mask: Optional[jnp.ndarray] = None,
@@ -1019,6 +1044,8 @@ def flash_attention(
     obs_metrics.counter(f"kernels/flash_calls_{bits}bit_operands").inc()
     block_q = resolve_block(n, block_q)
     block_k = resolve_block(n, block_k)
+    # ... and which tile the sequence's divisors gave it, counted the same way
+    obs_metrics.counter(f"kernels/flash_tile_{block_q}x{block_k}").inc()
     if grid not in ("auto", "dense", "compact"):
         raise ValueError(f"grid must be auto|dense|compact, got {grid!r}")
     if live is not None:

@@ -4,12 +4,14 @@ The TPU compiler is installed here and compiles for a DESCRIBED `v5e:2x2`
 topology (on-chip-measurement guide §2, rehearsal 3): interpret mode cannot
 see what it refuses — a block not aligned to the tiling, a kernel over its
 VMEM budget.  Shapes are chip_smoke.py's real ones (b4, h16, n1280, d128,
-bf16, 256-tiles).  Nothing runs, so nothing here is a result or a time; a
+bf16, 256-tiles) and the train cells' (1,152 and 4,224 positions, widths 128
+and 256, 384-tiles, every kernel body with a mask tile and without).  Nothing runs, so nothing here is a result or a time; a
 compile that passes is not a chip run.  Every test that compiles skips where
 the topology cannot be described.  Plus: each train cell's step traced at its
 real sizes for the operands its flash calls take, and the hardware table,
 which finds the kind the v5e reports and refuses a kind it does not know."""
 import base64
+import functools
 import json
 import os
 import re
@@ -51,9 +53,10 @@ def one_chip():
     mp.undo()
 
 
-def _pattern(kind, heads=H, per_head=False):
-    cfg = TransformerConfig(dim=heads * D, depth=1, seq_len=N, heads=heads,
-                            dim_head=D, image_fmap_size=32,
+@functools.lru_cache(maxsize=None)
+def _pattern(kind, heads=H, per_head=False, seq_len=N, fmap=32):
+    cfg = TransformerConfig(dim=heads * D, depth=1, seq_len=seq_len, heads=heads,
+                            dim_head=D, image_fmap_size=fmap,
                             sparse_per_head=per_head)
     return np.asarray(_pattern_for(cfg, kind), bool)
 
@@ -207,14 +210,69 @@ CASES["moe_grouped_down_proj_glm"] = (
                     ((8,), jnp.int32)], 2)
 
 
+# The train cells' attention at the tile `resolve_block` gives their sequences
+# (384 x 384: 1,152 = 3 x 384, 4,224 = 11 x 384), all seven kernel bodies at
+# both head widths, with a (384, 384) mask tile and without: the three dense
+# bodies, the three compacted ones, and the compacted forward behind its
+# max-only first pass.  d8 runs (8, 16, 1152, 128), d24 (4, 16, 1152, 128) with
+# the mask its scan selects (traced, with its liveness table), the hybrid
+# trunks (1, 16, 4224, 256) and (1, 20, 4224, 256) without a pattern; a
+# pattern at 4,224 is the fmap-64 layout's (text 128), which no cell trains
+# yet.  Kernels only: no whole step compiles here.
+def _vfa_fwd(**kw):
+    return lambda q, k, v: fa.flash_attention(q, k, v, grid="compact", vfa=True, **kw)
+
+
+def _scan_selected_mask():
+    """What a `scan_layers` body hands the kernel: a TRACED (n, n) mask with
+    its liveness table at the resolved granularity."""
+    def loss(q, k, v, mask, live):
+        return fa.flash_attention(q, k, v, mask=mask, live=live).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+TILE_384 = {
+    "d128_b8_seq1152": ([((8, 16, 1152, 128), jnp.bfloat16)] * 3,
+                        lambda: _pattern("conv_like", seq_len=1152)),
+    "d256_h20_seq4224": ([((1, 20, 4224, 256), jnp.bfloat16)] * 3,
+                         lambda: _pattern("axial_row", heads=20, seq_len=4224, fmap=64)),
+}
+for _shape, (_qkv, _mask) in TILE_384.items():
+    CASES[f"dense_full_{_shape}"] = (lambda: _grad_of(grid="dense"), _qkv, 3)
+    CASES[f"compact_full_{_shape}"] = (lambda: _grad_of(grid="compact"), _qkv, 3)
+    CASES[f"compact_full_vfa_fwd_{_shape}"] = (lambda: _vfa_fwd(), _qkv, 2)
+    CASES[f"dense_pattern_{_shape}"] = (
+        lambda _mask=_mask: _grad_of(mask=_mask(), grid="dense"), _qkv, 3)
+    CASES[f"compact_pattern_{_shape}"] = (
+        lambda _mask=_mask: _grad_of(mask=_mask(), grid="compact"), _qkv, 3)
+    CASES[f"compact_pattern_vfa_fwd_{_shape}"] = (
+        lambda _mask=_mask: _vfa_fwd(mask=_mask()), _qkv, 2)
+CASES["compact_full_d256_seq4224"] = (
+    lambda: _grad_of(grid="compact"), [((1, 16, 4224, 256), jnp.bfloat16)] * 3, 3)
+CASES["dense_scan_mask_d128_b4_seq1152"] = (
+    _scan_selected_mask,
+    [((4, 16, 1152, 128), jnp.bfloat16)] * 3 + [((1152, 1152), jnp.bool_), ((3, 3), jnp.int32)], 3)
+
+# the tile each flash case's sequence resolves under the default cap
+TILE_OF_SEQ = {1280: "256x256", 1152: "384x384", 4224: "384x384"}
+
+
 @pytest.mark.parametrize("name", list(CASES))
 def test_kernel_compiles_for_v5e(one_chip, name):
+    from dalle_pytorch_tpu.observability import metrics as obs_metrics
+
     build, shapes, n_kernels = CASES[name]
+    flash = name.startswith(("dense_", "compact_"))  # the flash cases on bfloat16 inputs
+    if flash:
+        tile = obs_metrics.counter(f"kernels/flash_tile_{TILE_OF_SEQ[shapes[0][0][2]]}")
+        before = tile.value
     text = _compile(build(), one_chip, *shapes)
     assert text.count("tpu_custom_call") >= n_kernels, (
         f"{name}: expected >= {n_kernels} Pallas custom calls in the compiled program")
-    if name.startswith(("dense_", "compact_")):  # the flash cases on bfloat16 inputs
+    if flash:
         assert shapes[0][1] == jnp.bfloat16
+        assert tile.value == before + 1, name
         _check_16bit_products(text, n_kernels)
 
 
@@ -301,24 +359,72 @@ def test_glm_cell_step_fits_the_chip_at_microbatch_1_and_not_at_2(one_chip, micr
                 lowered.compile()
 
 
+def _flash_counts():
+    """{counter: total} of every `kernels/flash_*` counter the registry holds."""
+    from dalle_pytorch_tpu.observability import metrics as obs_metrics
+
+    return {name[len("kernels/"):]: rec["total"]
+            for name, rec in obs_metrics.REGISTRY.snapshot(reset_window=False).items()
+            if name.startswith("kernels/flash_")}
+
+
+def _flash_counted(fn, *args):
+    """What tracing `fn(*args)` added to the `kernels/flash_*` counters, as
+    a function of a name's prefix: `counted("flash_tile_")`."""
+    before = _flash_counts()
+    fn(*args)
+    grew = {n: c - before.get(n, 0) for n, c in _flash_counts().items() if c != before.get(n, 0)}
+    return lambda prefix: {n: c for n, c in grew.items() if n.startswith(prefix)}
+
+
+@functools.lru_cache(maxsize=None)
+def _cell_step_flash_counts(workload):
+    """Each train cell's step, TRACED once at its real sizes (`eval_shape`:
+    nothing compiles, runs or is allocated, and no chip is described)."""
+    cfg, step_fn, state, batch, key = _cell_step(workload, jax.ShapeDtypeStruct)
+    return _flash_counted(jax.eval_shape, step_fn, state, batch, key)
+
+
 # flash_attention calls a traced step holds: d8's eight layers, d24's one
 # scanned layer body, the hybrid period's one `gated_full` layer, the latent
 # trunk's five blocks and its prediction module's
-@pytest.mark.parametrize("workload,calls", [("train_d8", 8), ("train_d24", 1),
-                                            ("train_q3n_ep16", 1), ("train_glm47_ep8", 6)])
-def test_train_cells_feed_the_flash_kernels_16bit_operands(workload, calls):
-    """Each train cell's step, TRACED at its real sizes (`eval_shape`: nothing
-    compiles, runs or is allocated, and no chip is described): every
-    `flash_attention` call it holds takes 16-bit operands,
-    `kernels/flash_calls_32bit_operands` stays where it was."""
-    from dalle_pytorch_tpu.observability import metrics as obs_metrics
+CELL_FLASH_CALLS = [("train_d8", 8), ("train_d24", 1), ("train_q3n_ep16", 1), ("train_glm47_ep8", 6)]
 
-    names = ("kernels/flash_calls_16bit_operands", "kernels/flash_calls_32bit_operands")
-    cfg, step_fn, state, batch, key = _cell_step(workload, jax.ShapeDtypeStruct)
-    before = [obs_metrics.counter(n).value for n in names]
-    jax.eval_shape(step_fn, state, batch, key)
-    calls16, calls32 = (obs_metrics.counter(n).value - b for n, b in zip(names, before))
-    assert (calls16, calls32) == (calls, 0)
+
+@pytest.mark.parametrize("workload,calls", CELL_FLASH_CALLS)
+def test_train_cells_feed_the_flash_kernels_16bit_operands(workload, calls):
+    """Every `flash_attention` call a train cell's traced step holds takes
+    16-bit operands: `kernels/flash_calls_32bit_operands` stays where it was."""
+    assert _cell_step_flash_counts(workload)("flash_calls_") == {"flash_calls_16bit_operands": calls}
+
+
+@pytest.mark.parametrize("workload,calls", CELL_FLASH_CALLS)
+def test_train_cells_run_the_flash_kernels_at_384_tiles(workload, calls):
+    """The same traced steps, read for the tile: every call of every train
+    cell resolves 384 x 384 (1,152 = 3 x 384, 4,224 = 11 x 384) and no other
+    tile is counted; under `scan_layers` the one scanned body carries the one
+    tile all 24 layers run."""
+    assert _cell_step_flash_counts(workload)("flash_tile_") == {"flash_tile_384x384": calls}
+
+
+@pytest.mark.parametrize("positions,tiles", [(128, {"flash_tile_128x128": 2}), (129, {})])
+def test_serving_prefill_counts_the_tile_it_counted_before(positions, tiles):
+    """Admission's prefill is not moved by the rule: at 128 positions (the
+    DALL-E serving cells) one 128-tile a layer, as under the old default; at
+    129 (`serve_olmoh_s32`) no multiple of 128 divides the sequence and
+    `_use_flash` keeps the dense path, so no kernel and no tile is counted.
+    Traced, nothing runs."""
+    from dalle_pytorch_tpu.models import transformer as tr
+
+    cfg = TransformerConfig(dim=2 * D, depth=2, seq_len=positions - 1 + 32 * 32, heads=2,
+                            dim_head=D, image_fmap_size=32, attn_kernel="flash",
+                            attn_types=("full", "axial_row"))
+    params = jax.eval_shape(lambda k: tr.init_transformer(k, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: tr.init_cache(cfg, 1))
+    x = jax.ShapeDtypeStruct((1, positions, cfg.dim), jnp.float32)
+    counted = _flash_counted(jax.eval_shape, lambda p, x, c: tr.prefill(p, cfg, x, c),
+                             params, x, cache)
+    assert counted("flash_tile_") == tiles
 
 
 @pytest.fixture(scope="module")

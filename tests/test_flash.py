@@ -1,4 +1,6 @@
 """Pallas flash attention vs dense oracle (interpret mode on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -110,11 +112,15 @@ def test_flash_pallas_backward_with_pattern_mask():
     np.testing.assert_allclose(np.asarray(g_p), np.asarray(g_d), atol=5e-5)
 
 
-def test_flash_block_size_halves_to_divide_seq():
-    """Default 256 blocks shrink by halving until they divide n (e.g. n=384
-    -> 128); results must still match dense, fwd and bwd."""
-    n, d = 384, 64
-    q, k, v = qkv(n=n, d=d)
+@pytest.mark.parametrize("n,tile", [(384, 384), (640, 128), (192, 192)])
+def test_flash_default_block_follows_the_sequences_divisors(n, tile):
+    """Nothing passed in: the largest multiple of 128 under the default cap of
+    384 that divides n (384 -> one 384-tile; 640 = 5 x 128 -> 128), and a
+    sequence shorter than the cap that no multiple of 128 divides falls back
+    to the cap halved (192 -> 192).  The tile is counted while the call is
+    traced, and results must still match dense, fwd and bwd."""
+    d = 64
+    q, k, v = qkv(b=1, n=n, d=d)
     cm = causal_mask(n)
 
     def f_flash(q, k, v):
@@ -123,6 +129,7 @@ def test_flash_block_size_halves_to_divide_seq():
     def f_dense(q, k, v):
         return jnp.sum(attend(q * d ** -0.5, k, v, mask=cm) ** 2)
 
+    assert _grew([f"kernels/flash_tile_{tile}x{tile}"], lambda: float(f_flash(q, k, v))) == [1]
     assert float(f_flash(q, k, v)) == pytest.approx(float(f_dense(q, k, v)), rel=1e-5)
     g_f = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
     g_d = jax.grad(f_dense, argnums=(0, 1, 2))(q, k, v)
@@ -350,18 +357,21 @@ def test_flash_bf16_forward_and_gradients(d, pattern):
             assert err < 1e-2, (which, name, err)
 
 
+def _grew(names, fn, *xs):
+    """What calling `fn(*xs)` added to each of the counters `names`."""
+    from dalle_pytorch_tpu.observability import metrics as obs_metrics
+
+    before = [obs_metrics.counter(n).value for n in names]
+    fn(*xs)
+    return [obs_metrics.counter(n).value - b for n, b in zip(names, before)]
+
+
 def test_flash_counts_its_calls_by_operand_type():
     """`kernels/flash_calls_{16,32}bit_operands`: one count a `flash_attention`
     call, made while the call is traced (a jitted function counts once, not
     once a run), by the input's type alone."""
-    from dalle_pytorch_tpu.observability import metrics as obs_metrics
-
-    names = ("kernels/flash_calls_16bit_operands", "kernels/flash_calls_32bit_operands")
-
-    def grew(fn, *xs):
-        before = [obs_metrics.counter(n).value for n in names]
-        fn(*xs)
-        return [obs_metrics.counter(n).value - b for n, b in zip(names, before)]
+    grew = functools.partial(
+        _grew, ("kernels/flash_calls_16bit_operands", "kernels/flash_calls_32bit_operands"))
 
     q, k, v = qkv(b=1, h=1, n=64, d=64)
     step = jax.jit(lambda q, k, v: flash_attention(q, k, v) + flash_attention(q, v, k))
@@ -372,3 +382,19 @@ def test_flash_counts_its_calls_by_operand_type():
     assert grew(jax.grad(lambda q: flash_attention(q, h16[1], h16[2]).astype(jnp.float32).sum()),
                 h16[0]) == [1, 0]
     assert grew(flash_attention, *(t.astype(jnp.float16) for t in (q, k, v))) == [1, 0]
+
+
+def test_flash_counts_the_tile_it_resolved():
+    """`kernels/flash_tile_<bq>x<bk>`: beside the operand counter and in the
+    same way (one count a traced call), under the name of the RESOLVED tile,
+    so that a program's counters say which tile the sequence's divisors or the
+    caller's cap gave it."""
+    grew = functools.partial(_grew, [f"kernels/flash_tile_{t}" for t in (
+        "384x384", "256x256", "128x128", "128x384", "64x64")])
+    x = jax.ShapeDtypeStruct((1, 1, 768, 16), jnp.float32)
+    trace = lambda **kw: jax.eval_shape(lambda q: flash_attention(q, q, q, **kw), x)
+    assert grew(trace) == [1, 0, 0, 0, 0]
+    assert grew(lambda: trace(block_q=256, block_k=256)) == [0, 1, 0, 0, 0]
+    assert grew(lambda: trace(block_q=128, block_k=128)) == [0, 0, 1, 0, 0]
+    assert grew(lambda: trace(block_q=128)) == [0, 0, 0, 1, 0]  # each side has its cap
+    assert grew(lambda: trace(block_q=64, block_k=64)) == [0, 0, 0, 0, 1]

@@ -11,7 +11,9 @@ placeholder/padding semantics, decode gather tables vs brute force),
 per-head sparse layouts, key-mask interaction, the VFA two-pass forward
 (allclose by design — fixed-max accumulation reorders the sums),
 scan_layers stacked tables, sparse-aware cached decode, resolve_block's
-divisor fallback, and the seq-4096 axial scenario (tile-count speedup
+rule (the largest lane-aligned divisor under the cap, then the divisor
+fallback) with everything that must agree on it, parity at the 384 x 384 tile
+the train cells run, and the seq-4096 axial scenario (tile-count speedup
 ratio asserted on CPU; ledger verdict + decode gather width).
 """
 import dataclasses
@@ -22,6 +24,7 @@ import numpy as np
 import pytest
 
 from dalle_pytorch_tpu.kernels.flash_attention import (
+    DEFAULT_BLOCK_K,
     DEFAULT_BLOCK_Q,
     flash_attention,
     resolve_block,
@@ -62,12 +65,12 @@ def qkv(b=1, h=1, n=N, d=DIM, seed=0, dtype=jnp.float32):
     return q, k, v, do
 
 
-def _run(grid, mask, q, k, v, do, **kw):
+def _run(grid, mask, q, k, v, do, block=BLOCK, **kw):
     """(out, dq, dk, dv) for one grid choice; the loss contracts with a fixed
     random cotangent so every output element influences every grad."""
 
     def loss(q, k, v):
-        out = flash_attention(q, k, v, mask=mask, block_q=BLOCK, block_k=BLOCK,
+        out = flash_attention(q, k, v, mask=mask, block_q=block, block_k=block,
                               grid=grid, **kw)
         return jnp.sum(out * do), out
 
@@ -235,29 +238,115 @@ def test_decode_tables_match_brute_force():
             counts[h], si.decode_kv_counts(ph[h]))
 
 
-# --- resolve_block fallback (satellite 2) ------------------------------------
+# --- resolve_block: the tile rule ----------------------------------------------
+
+# (n, cap) -> block.  Under the default cap of 384 the LARGEST multiple of the
+# 128-lane width that divides n: 384 where 384 divides (both sequences the
+# train cells run, 1,152 and 4,224), 256 where only 256 does (the package's
+# default 1,280; fmap 64's 4,352), else 128.  A caller's smaller cap still
+# caps.  Where no multiple of 128 under the cap divides n, as before: the cap
+# halved, then divisors (aligned ones first), then the error.
+RESOLVE_TABLE = [
+    (1152, 384, 384), (4224, 384, 384), (768, 384, 384), (384, 384, 384),
+    (1280, 384, 256), (4352, 384, 256), (1024, 384, 256), (256, 384, 256),
+    (640, 384, 128), (128, 384, 128),
+    (1152, 128, 128), (4224, 128, 128),      # block_q=128 passed in stays 128
+    (1152, 256, 128), (4224, 256, 128),      # no divisor 256: what every cell ran before
+    (640, 256, 128), (256, 256, 256), (1280, 256, 256),
+    (4224, 512, 384),                        # the largest, not the first that fits
+    (129, 384, 129), (129, 128, 43),         # a 129-position prefill: one tile; 43 = 129 / 3
+    (64, 384, 64), (96, 384, 96),            # shorter than a lane tile: the cap is n
+    # 270 = 2*3^3*5: halving bottoms out at 2 (<8); the largest divisor <= cap
+    (270, 384, 270), (270, 256, 135), (270, 135, 135),
+]
+
+
+@pytest.mark.parametrize("n,cap,want", RESOLVE_TABLE,
+                         ids=[f"{n}_cap{cap}" for n, cap, _ in RESOLVE_TABLE])
+def test_resolve_block_rule(n, cap, want):
+    assert resolve_block(n, cap) == want
+    assert n % want == 0 and want <= cap
 
 
 def test_resolve_block_divisor_fallback():
-    assert resolve_block(640, 256) == 128  # halving path, unchanged
-    assert resolve_block(256, 256) == 256
-    # 270 = 2*3^3*5: halving bottoms out at 2 (<8); largest divisor <= cap
-    # is 135 — previously a ValueError, now a working (if unaligned) block
-    assert resolve_block(270, 256) == 135
-    assert resolve_block(270, 135) == 135
-    # 2305 = 5*461: no divisor in [8, 256] exists — the error must say so
+    assert DEFAULT_BLOCK_Q == DEFAULT_BLOCK_K == 384
+    # 2305 = 5*461: no divisor in [8, cap] exists — the error must say so
     with pytest.raises(ValueError, match="no divisor"):
         resolve_block(2305, DEFAULT_BLOCK_Q)
+    with pytest.raises(ValueError, match="no divisor"):
+        resolve_block(2305, 256)
+
+
+@pytest.mark.parametrize("n,fmap,want", [(1152, 32, 384), (1280, 32, 256), (4224, 64, 384)])
+def test_scan_path_profiler_and_kernel_resolve_one_block(n, fmap, want, monkeypatch):
+    """Three places must agree on the tile: `flash_attention` (the grid it
+    runs), `transformer._apply_scan` (the liveness and compacted tables it
+    builds for the traced per-layer select) and `profiling._attn_tile_density`
+    (what it prices as executed).  Each is watched where it USES its blocks,
+    with the model only traced (`eval_shape`): the kernel's tile counter, the
+    blocks `_stacked_flash_tables` is handed and the shape of the liveness
+    table that reaches the kernel, and the blocks the profiler asks the causal
+    tile table for."""
+    from dalle_pytorch_tpu.kernels import flash_attention as fa
+    from dalle_pytorch_tpu.models import transformer as tr
+    from dalle_pytorch_tpu.observability import metrics as obs_metrics
+    from dalle_pytorch_tpu.training import profiling
+
+    cfg = _tcfg(seq_len=n, image_fmap_size=fmap, depth=2, dim=16, heads=1, dim_head=16,
+                attn_types=("full", "axial_row"), scan_layers=True, attn_kernel="flash")
+    seen = {"scan": [], "live": [], "profiler": []}
+    stacked, flash, causal_live = tr._stacked_flash_tables, fa.flash_attention, si.block_causal_live_np
+
+    def spy_stacked(cfg, masks_np, n, bq, bk, causal):
+        seen["scan"].append((bq, bk))
+        return stacked(cfg, masks_np, n, bq, bk, causal)
+
+    def spy_flash(q, k, v, **kw):
+        seen["live"].append(tuple(kw["live"].shape))
+        return flash(q, k, v, **kw)
+
+    monkeypatch.setattr(tr, "_stacked_flash_tables", spy_stacked)
+    monkeypatch.setattr(fa, "flash_attention", spy_flash)
+    tile = obs_metrics.counter(f"kernels/flash_tile_{want}x{want}")
+    before = tile.value
+    params = jax.eval_shape(lambda k: init_transformer(k, cfg), jax.random.PRNGKey(0))
+    jax.eval_shape(lambda p, x: apply_transformer(p, cfg, x), params,
+                   jax.ShapeDtypeStruct((1, n, cfg.dim), jnp.float32))
+    assert tile.value == before + 1  # one scanned layer body, one call, THIS tile
+    assert seen["scan"] == [(want, want)]
+    assert seen["live"] == [(n // want, n // want)]
+
+    def spy_causal(nq, nk, bq, bk):
+        seen["profiler"].append((nq, nk, bq, bk))
+        return causal_live(nq, nk, bq, bk)
+
+    monkeypatch.setattr(si, "block_causal_live_np", spy_causal)
+    density = profiling._attn_tile_density(cfg)
+    assert seen["profiler"] == [(n // want, n // want, want, want)]
+    assert 0 < density <= (n // want + 1) / (2 * (n // want))  # at most the causal tiles
 
 
 # --- transformer integration -------------------------------------------------
 
 
+# The model path passes no block: the sequence's divisors choose it.  640 =
+# 5 x 128 (text 65 + 24 x 24 - 1) has no divisor 384 or 256, so these stacks keep
+# a 5 x 5 grid of 128-tiles in which both patterns kill tiles inside the
+# causal triangle (at N = 384 the default cap now gives ONE 384-tile, and the
+# stacked tables would be `None`).
+N_SCAN = 640
+
+
 def _scan_cfg():
-    return _tcfg(
-        depth=2, dim_head=16, attn_types=("axial_row", "conv_like"),
-        shift_tokens=True, scan_layers=True, attn_kernel="flash",
+    cfg = _tcfg(
+        depth=2, dim_head=16, attn_types=("axial_row", "conv_like"), seq_len=N_SCAN,
+        image_fmap_size=24, shift_tokens=True, scan_layers=True, attn_kernel="flash",
     )
+    assert resolve_block(N_SCAN, DEFAULT_BLOCK_Q) == resolve_block(N_SCAN, DEFAULT_BLOCK_K) == BLOCK
+    for t in cfg.attn_types:  # the premise: each pattern has dead tiles to compact away
+        live = block_live_np(np.asarray(_pattern_for(cfg, t), bool), BLOCK, BLOCK)
+        assert not (live | ~si.block_causal_live_np(5, 5, BLOCK, BLOCK)).all(), t
+    return cfg
 
 
 def test_scan_layers_stacked_tables_bitexact():
@@ -267,7 +356,7 @@ def test_scan_layers_stacked_tables_bitexact():
     the unrolled cross-check live in the slow companion below.)"""
     cfg = _scan_cfg()
     params = init_transformer(jax.random.PRNGKey(0), cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (1, N, cfg.dim), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, N_SCAN, cfg.dim), jnp.float32)
     o_dense = apply_transformer(params, dataclasses.replace(cfg, attn_grid="dense"), x)
     o_comp = apply_transformer(params, dataclasses.replace(cfg, attn_grid="compact"), x)
     np.testing.assert_array_equal(np.asarray(o_dense), np.asarray(o_comp))
@@ -280,7 +369,7 @@ def test_scan_layers_stacked_tables_grads_bitexact():
     traced table select), and the unrolled compact path is allclose."""
     cfg = _scan_cfg()
     params = init_transformer(jax.random.PRNGKey(0), cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (1, N, cfg.dim), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, N_SCAN, cfg.dim), jnp.float32)
 
     def run(c):
         f = lambda x: jnp.sum(jnp.sin(apply_transformer(params, c, x)))
@@ -331,6 +420,110 @@ def test_sparse_decode_matches_full_cache(kw):
     sparse = _decode_roll(cfg, params, x, 4)
     full = _decode_roll(dataclasses.replace(cfg, sparse_decode=False), params, x, 4)
     np.testing.assert_allclose(sparse, full, atol=2e-6, rtol=2e-6)
+
+
+# --- the 384 x 384 tile the train cells run -------------------------------------
+
+N384, FMAP384, TILE = 1152, 32, 384  # the DALL-E cells' sequence: text 128 + 32 x 32
+
+
+def _tcfg384(**kw):
+    return _tcfg(seq_len=N384, image_fmap_size=FMAP384, **kw)
+
+
+def _masks384(case):
+    """(mask for the kernel, key_mask or None, dense bool mask for `attend`)."""
+    causal = np.tril(np.ones((N384, N384), bool))
+    if case == "key_mask":
+        km = np.arange(N384)[None] < np.asarray([N384 - 53])[:, None]
+        return None, jnp.asarray(km), jnp.asarray(causal[None, None] & km[:, None, None, :])
+    if case == "per_head":
+        pm = np.asarray(_pattern_for(_tcfg384(sparse_per_head=True), "sparse"), bool)
+        assert pm.ndim == 3
+        return jnp.asarray(pm), None, jnp.asarray(causal[None, None] & pm[None])
+    pm = _pattern_for(_tcfg384(), case)
+    if pm is None:
+        return None, None, jnp.asarray(causal)
+    pm = np.asarray(pm, bool)
+    return jnp.asarray(pm), None, jnp.asarray(causal & pm)
+
+
+@pytest.mark.parametrize("case", ["full", "axial_row", "axial_col", "conv_like",
+                                  "per_head", "key_mask"])
+def test_tile_384_matches_dense_attention(case):
+    """Forward and all three gradients at the tile the default now resolves for
+    1,152 positions (a 3 x 3 grid of 384 x 384 tiles, under the default cap),
+    against `ops.attention.attend` on the same float32 inputs: plain causal,
+    the three image patterns at fmap 32, a per-head layout and a key-padding
+    row.  And the compacted grid at the same tile equals the dense one bit for
+    bit (where nothing is dead inside the triangle its tables list every
+    causal tile)."""
+    from dalle_pytorch_tpu.observability import metrics as obs_metrics
+    from dalle_pytorch_tpu.ops.attention import attend
+
+    mask, key_mask, dense_mask = _masks384(case)
+    h = 2 if case == "per_head" else 1
+    d = 16
+    q, k, v, do = qkv(h=h, n=N384, d=d, seed=11)
+    kw = {} if key_mask is None else {"key_mask": key_mask}
+    tile = obs_metrics.counter(f"kernels/flash_tile_{TILE}x{TILE}")
+    before = tile.value
+    dense = _run("dense", mask, q, k, v, do, block=DEFAULT_BLOCK_Q, **kw)
+    assert tile.value == before + 1
+
+    def loss(q, k, v):
+        out = attend(q * d ** -0.5, k, v, mask=dense_mask)
+        return jnp.sum(out * do), out
+
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), dense, (out, *grads)):
+        np.testing.assert_allclose(a, np.asarray(b), atol=5e-5, err_msg=name)
+    compact = _run("compact", mask, q, k, v, do, block=DEFAULT_BLOCK_Q, **kw)
+    for a, b in zip(dense, compact):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_tile_384_leaves_nothing_dead_inside_the_triangle_at_1152():
+    """Why every layer of the DALL-E cells takes the dense grid now: at 1,152
+    positions and fmap 32 the three patterns leave 30, 45 and 35 of the 45
+    causal 128-tiles live, and all 6 of the 6 causal 384-tiles, so
+    `grid="auto"` builds no tables for any of them."""
+    from dalle_pytorch_tpu.kernels.flash_attention import _resolve_tables
+
+    live_at = {}
+    for kind in ("axial_row", "axial_col", "conv_like"):
+        pm = np.asarray(_pattern_for(_tcfg384(), kind), bool)
+        for b in (128, TILE):
+            cl = si.block_causal_live_np(N384 // b, N384 // b, b, b)
+            live_at[kind, b] = (int((block_live_np(pm, b, b) & cl).sum()), int(cl.sum()))
+        assert _resolve_tables("auto", None, pm, 1, N384, True, TILE, TILE) is None
+        assert (_resolve_tables("auto", None, pm, 1, N384, True, 128, 128) is None) == (kind == "axial_col")
+    assert live_at == {("axial_row", 128): (30, 45), ("axial_row", TILE): (6, 6),
+                       ("axial_col", 128): (45, 45), ("axial_col", TILE): (6, 6),
+                       ("conv_like", 128): (35, 45), ("conv_like", TILE): (6, 6)}
+
+
+def test_scan_remat_stack_at_1152_matches_unrolled():
+    """The d24 cell's path at its own sequence, small widths: `scan_layers` +
+    remat `full` over the four-pattern cycle (one traced mask a layer, its
+    (3, 3) liveness table, no compacted tables) against the unrolled stack,
+    output and input gradient.  allclose, not equal: the scan reorders float
+    operations OUTSIDE attention (stacked parameters)."""
+    cfg = _tcfg384(depth=4, dim=16, heads=1, dim_head=16, shift_tokens=True,
+                   attn_types=("full", "axial_row", "axial_col", "conv_like"),
+                   scan_layers=True, execution="remat", remat_policy="full",
+                   attn_kernel="flash")
+    params = init_transformer(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, N384, cfg.dim), jnp.float32)
+
+    def run(c):
+        f = lambda x: jnp.sum(jnp.sin(apply_transformer(params, c, x)))
+        return np.asarray(apply_transformer(params, c, x)), np.asarray(jax.grad(f)(x))
+
+    o_scan, g_scan = run(cfg)
+    o_unrl, g_unrl = run(dataclasses.replace(cfg, scan_layers=False, execution="sequential"))
+    np.testing.assert_allclose(o_scan, o_unrl, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(g_scan, g_unrl, atol=1e-5, rtol=1e-5)
 
 
 # --- seq-4096 scenario -------------------------------------------------------
