@@ -59,6 +59,7 @@ from dalle_pytorch_tpu.observability.metrics import (
     counter,
     gauge,
     histogram,
+    series,
 )
 from dalle_pytorch_tpu.observability.slo import (
     SloMonitor,
@@ -117,6 +118,7 @@ __all__ = [
     "parse_profile_steps",
     "record_memory_gauges",
     "sampling_memory_ledger",
+    "series",
     "span",
     "step_comms_ledger",
     "step_cost_analysis",

@@ -1,10 +1,11 @@
 """Process-wide runtime metrics registry.
 
 Counters (monotonic: steps, loss-scale skips, host→device bytes), gauges
-(point-in-time: data-queue depth, tokens/sec, device memory peak) and
-histograms (distributions: checkpoint save latency, per-sample decode time).
-Instrumented code calls the module-level `counter()/gauge()/histogram()`
-helpers — no plumbing through call stacks — and the training loop flushes a
+(point-in-time: data-queue depth, tokens/sec, device memory peak),
+histograms (distributions: checkpoint save latency, per-sample decode time)
+and series (the last N rows of named columns: one row a serving poll).
+Instrumented code calls the module-level
+`counter()/gauge()/histogram()/series()` helpers — no plumbing through call stacks — and the training loop flushes a
 snapshot through the existing `MetricLogger` JSONL sink (and/or the
 telemetry directory) at its logging cadence.
 
@@ -16,7 +17,9 @@ from __future__ import annotations
 
 import math
 import threading
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence
+
+import numpy as np
 
 
 class Counter:
@@ -206,6 +209,54 @@ class HistogramWindow:
         return out
 
 
+class Series:
+    """The last `capacity` rows of named float64 columns, in one block
+    allocated when the series is made.  `append(*row)` writes a row in place
+    (the block never grows and nothing is kept per row, so a recorder on a
+    latency path is no suspect of its own); once full it overwrites the oldest
+    row and counts it in `dropped`, as `PoolFlightRecorder` does.  A row's
+    sequence number is its place among all rows ever appended: `total` is the
+    next one, `rows(since=n)` hands back, oldest first and as copies, the held
+    rows numbered n and later.  The snapshot carries the length and `dropped`
+    only: the rows are read from the object, not flushed."""
+
+    __slots__ = ("name", "columns", "capacity", "total", "_data", "_lock")
+
+    def __init__(self, name: str, lock: threading.Lock, columns: Sequence[str],
+                 capacity: int = 65536):
+        if capacity <= 0 or not columns or len(set(columns)) != len(columns):
+            raise ValueError(f"series {name!r}: capacity {capacity}, columns {columns}")
+        self.name = name
+        self.columns = tuple(columns)
+        self.capacity = int(capacity)
+        self.total = 0
+        self._data = np.zeros((self.capacity, len(self.columns)), np.float64)
+        self._lock = lock
+
+    def append(self, *row: float):
+        with self._lock:
+            self._data[self.total % self.capacity] = row
+            self.total += 1
+
+    def __len__(self) -> int:
+        return min(self.total, self.capacity)
+
+    @property
+    def dropped(self) -> int:
+        return max(self.total - self.capacity, 0)
+
+    def rows(self, since: int = 0) -> Dict[str, np.ndarray]:
+        """{column: values} of the held rows numbered `since` and later."""
+        with self._lock:
+            first = max(int(since), self.dropped)
+            at = np.arange(first, max(self.total, first)) % self.capacity
+            block = self._data[at]
+        return {c: block[:, j] for j, c in enumerate(self.columns)}
+
+    def _snapshot(self, reset_window: bool) -> Dict[str, Any]:
+        return {"rows": len(self), "dropped": self.dropped}
+
+
 class MetricsRegistry:
     """Create-or-get named instruments.  A name is bound to one instrument
     kind for the life of the process; asking for the same name with a
@@ -235,6 +286,29 @@ class MetricsRegistry:
 
     def histogram(self, name: str) -> Histogram:
         return self._get(name, Histogram)
+
+    def series(self, name: str, columns: Optional[Sequence[str]] = None,
+               capacity: int = 65536, fresh: bool = False) -> Optional[Series]:
+        """The series bound to `name`; made from `columns` and `capacity` where
+        there is none (None where no columns are given: a reader asks for what
+        a writer may have left).  `fresh` binds a new, empty one whatever was
+        there: a writer that owns its series takes the name over."""
+        with self._lock:
+            inst = self._instruments.get(name)
+            if inst is not None and not isinstance(inst, Series):
+                raise TypeError(
+                    f"metric {name!r} already registered as "
+                    f"{type(inst).__name__}, requested Series")
+            if columns is None:
+                return inst
+            if inst is None or fresh:
+                inst = self._instruments[name] = Series(
+                    name, self._lock, columns, capacity)
+            elif inst.columns != tuple(columns):
+                raise ValueError(
+                    f"series {name!r} holds columns {inst.columns}, "
+                    f"requested {tuple(columns)}")
+            return inst
 
     def snapshot(self, reset_window: bool = True) -> Dict[str, Dict[str, Any]]:
         """{name: {kind, ...stats}} for every registered instrument.
@@ -281,3 +355,8 @@ def gauge(name: str) -> Gauge:
 
 def histogram(name: str) -> Histogram:
     return REGISTRY.histogram(name)
+
+
+def series(name: str, columns: Optional[Sequence[str]] = None,
+           capacity: int = 65536, fresh: bool = False) -> Optional[Series]:
+    return REGISTRY.series(name, columns, capacity, fresh)

@@ -52,10 +52,34 @@ carrying `iter=` and, where it belongs to one request, `req=<Request.id>`:
                                                            -> phases["vae_decode"]
         serve/evict.pixels_pull   per request
 
-The same readings feed the admit/dispatch/block/evict split of the
-`serving_window` event and a goodput figure (lane-tokens actually decoded vs
-the ideal slots x steps).  Every span closes where the code already returns
-or already blocks: telemetry-off poll() performs ZERO additional device syncs
+The same readings, per POLL, are one row of the series `serving/polls`
+(`engine.polls`: an `observability.metrics.Series`, the last 65,536 polls in
+one block allocated at build; always on, a row being two clock reads and a
+write in place beside a decode step of milliseconds; `serving/polls.r<id>` for
+a fleet replica; an engine built later in a process takes the name over):
+
+    iter                      the `iter=` of the spans above
+    t0_s, dur_s               poll entry (`time.perf_counter`, the clock
+                              `timed_span` reads) to the end of serve/poll: the
+                              row and the span of one `iter` are one interval
+                              on two clocks, so a poll is found in a trace
+    admit_s, dispatch_s       the serve/admit spans; serve/decode.dispatch
+    block_s, evict_s          the eviction's device waits (flag_sync,
+                              codes_pull, vae_decode); the rest of serve/evict.
+                              The four never exceed dur_s
+    admitted, evicted, lanes  requests; lane-tokens decoded, as
+                              `serving/decode_lane_tokens` counts them
+
+`t0_s[i+1] - (t0_s[i] + dur_s[i])` is time outside the poll: the caller's loop
+(and the window event, every `telemetry_every` polls).  A wedged poll writes
+no row, as it advances no `iter`.  The `serving_window` event's admit/dispatch/
+block/evict split, its `decode_steps` and its goodput figure (lane-tokens
+actually decoded vs the ideal slots x steps) are sums over the rows since the
+last event, and the status file's `serving.worst_poll` is that window's
+longest poll (`iter`, `dur_s`, its largest part as `phase` and `phase_s`;
+`other` is what no span covers): after a latency alarm look there first, then
+at `engine.polls.rows()`.  Every span closes where the code already returns or
+already blocks: telemetry-off poll() performs ZERO additional device syncs
 (tools/lint_host_sync.py keeps that mechanical).  The jitted programs carry
 stable names (`serve_decode_step`, `serve_admit`, `serve_ingest`,
 `serve_vae_decode`, `serve_spec_draft`, `serve_spec_verify`), which is how a
@@ -97,6 +121,13 @@ from dalle_pytorch_tpu.serving.scheduler import (
     RequestQueue,
 )
 from dalle_pytorch_tpu.training import resilience
+
+# one row a poll() in the series `serving/polls` (module docstring)
+POLL_SERIES = "serving/polls"
+POLL_PHASES = ("admit", "dispatch", "block", "evict")
+POLL_COLUMNS = ("iter", "t0_s", "dur_s") + tuple(f"{p}_s" for p in POLL_PHASES) + (
+    "admitted", "evicted", "lanes")
+POLL_CAPACITY = 65536  # a 48 s window at a 1 ms poll still fits; ~5 MB
 
 
 @dataclasses.dataclass(frozen=True)
@@ -253,7 +284,7 @@ class GenerationEngine:
         # replica id; a disaggregated fleet installs a prefill worker here
         # (serving/fleet.PrefillWorker), and _do_admit ingests its handoff
         # instead of running prefill in-engine
-        self.replica_id: Optional[int] = None
+        self.replica_id = None  # also binds `self.polls` under its name
         self.prefill_backend = None
         # durability hooks: a RequestJournal (serving/journal.py) makes
         # accepted requests crash-replayable; a DegradeLadder
@@ -274,11 +305,7 @@ class GenerationEngine:
         self._status_path: Optional[str] = None
         self._capture = None        # observability.capture.TraceTrigger
         self._phase = "idle"        # live poll phase, for hang-dump context
-        self._phase_acc = {"admit": 0.0, "dispatch": 0.0,
-                           "block": 0.0, "evict": 0.0}
-        self._win_decode_steps = 0
-        self._win_lane_tokens = 0
-        self._win_t = time.monotonic()
+        self._worst_poll: Optional[Dict[str, Any]] = None  # of that window
         # prefix-redundancy profiler (the measured case for a prefix cache):
         # content-hash of each admitted prompt prefix plus byte accounting
         # for the two duplication sources — the CFG null lane (its prefix KV
@@ -349,6 +376,20 @@ class GenerationEngine:
                 return vae_registry.decode_indices(vae_params, vae_cfg, codes)
 
             self._vae_decode = jax.jit(serve_vae_decode)
+
+    @property
+    def replica_id(self) -> Optional[int]:
+        return self._replica_id
+
+    @replica_id.setter
+    def replica_id(self, value: Optional[int]) -> None:
+        """A router names its replicas after they are built: the poll series
+        goes into the registry again under the replica's name, empty."""
+        self._replica_id = value
+        name = POLL_SERIES if value is None else f"{POLL_SERIES}.r{value}"
+        self.polls = obs_metrics.series(name, POLL_COLUMNS, POLL_CAPACITY,
+                                        fresh=True)
+        self._win_row = 0  # `polls.total` at the last window event
 
     # ------------------------------------------------------------------ jits
     def _decode_step_impl(self, params, state):
@@ -700,10 +741,10 @@ class GenerationEngine:
         iteration (codes — and images when a VAE is attached — populated).
 
         Phase attribution: every phase is a `serve/...` span (the tree is in
-        the module docstring), and the telemetry window's split is fed from
-        the same readings: admit (the `serve/admit` spans, which contain the
-        deliberate TTFT sync), dispatch (`serve/decode.dispatch`), block (the
-        eviction's device waits: flag sync, codes pulls, VAE decodes) and
+        the module docstring), and the poll's row in `self.polls` is written
+        from the same readings: admit (the `serve/admit` spans, which contain
+        the deliberate TTFT sync), dispatch (`serve/decode.dispatch`), block
+        (the eviction's device waits: flag sync, codes pulls, VAE decodes) and
         evict (the rest of `serve/evict`).  No device syncs added."""
         if self._stall_until:
             if time.monotonic() < self._stall_until:
@@ -713,6 +754,7 @@ class GenerationEngine:
                 # without the replica ever dying.
                 return []
             self._stall_until = 0.0
+        t0 = time.perf_counter()
         self._iter += 1
         if self._capture is not None:
             self._capture.on_step_start(self._iter)
@@ -735,17 +777,21 @@ class GenerationEngine:
                   "NaN decode logits until its retry budget burns", flush=True)
         with telemetry.span("serve/poll", iter=self._iter):
             self._phase = "admit"
-            self._admit_ready()
+            admit_s, admitted = self._admit_ready()
             self._track_poison_lane()
+            dispatch_s, lanes = 0.0, 0
             if self._inflight:
                 self._phase = "dispatch"
                 with telemetry.timed_span("serve/decode.dispatch",
                                           iter=self._iter) as t:
-                    self._decode_once()
-                self._phase_acc["dispatch"] += t.s
+                    lanes = self._decode_once()
+                dispatch_s = t.s
             self._phase = "evict"
-            done = self._evict_finished()
+            done, evicted, block_s, evict_s = self._evict_finished()
             self._phase = "idle"
+        self.polls.append(self._iter, t0, time.perf_counter() - t0, admit_s,
+                          dispatch_s, block_s, evict_s, admitted, evicted,
+                          lanes)
         if self.ecfg.telemetry_every and self._iter % self.ecfg.telemetry_every == 0:
             self._window_event()
         if self._capture is not None:
@@ -901,11 +947,14 @@ class GenerationEngine:
                 except AdmissionRefused:
                     pass  # refusal IS the drill's success mode (counted in submit)
 
-    def _admit_ready(self) -> None:
+    def _admit_ready(self) -> tuple:
+        """Admit what the queue's head and the pool allow.  Returns (the
+        seconds under `serve/admit` spans, the requests admitted)."""
+        admit_s, admitted = 0.0, 0
         while True:
             req = self.queue.peek()
             if req is None:
-                return
+                return admit_s, admitted
             reason, kind = self.admission.may_admit_ex(
                 req, free_lanes=len(self._free_lanes),
                 in_flight=len(self._inflight))
@@ -925,16 +974,17 @@ class GenerationEngine:
                         free=self.pool.free_blocks,
                         free_lanes=len(self._free_lanes),
                         replica=self.replica_id)
-                return
-            self._do_admit(self.queue.pop())
+                return admit_s, admitted
+            admit_s += self._do_admit(self.queue.pop())
+            admitted += 1
             self.admission.note_flow()
 
-    def _do_admit(self, req: Request) -> None:
+    def _do_admit(self, req: Request) -> float:
         """One admission, as the `serve/admit` span and its four children:
         alloc (tables, RNG split), dispatch (the admit / ingest jit),
         lane_meta (the eager per-lane scatters) and ttft_sync (the first
-        token must exist).  `Request.phases` and the window's admit time are
-        fed from the spans' own readings."""
+        token must exist).  `Request.phases` and the poll's admit time (the
+        span's seconds, returned) are fed from the spans' own readings."""
         req.phases["queue_wait"] = time.monotonic() - req.arrival_t
         ids = {"iter": self._iter, "req": req.id}
         with telemetry.timed_span("serve/admit", lanes=req.lanes_needed,
@@ -1044,7 +1094,7 @@ class GenerationEngine:
                           else "fused"),
                     prefix_hash=prefix_hash, prefix_repeat=prefix_repeat,
                 )
-        self._phase_acc["admit"] += t_admit.s
+        return t_admit.s
 
     def _note_prefix(self, req: Request, h: str) -> tuple:
         """Prefix-redundancy accounting for one admission: price the
@@ -1090,27 +1140,26 @@ class GenerationEngine:
             "duplicate_frac": dup / total if total else 0.0,
         }
 
-    def _decode_once(self) -> None:
+    def _decode_once(self) -> int:
+        """One decode dispatch.  Returns the lane-tokens it decoded."""
         if self._spec is not None and not (
                 self.degrade is not None and self.degrade.suppress_spec):
-            self._spec_decode_once()
-            return
+            return self._spec_decode_once()
         with (self._suspend_compiles() if not self._warm_decode
               else contextlib.nullcontext()):
             self._state = self._decode_fn(self.params, self._state)
         self._warm_decode = True
         obs_metrics.counter("serving/decode_steps").inc()
         obs_metrics.counter("serving/decode_lane_tokens").inc(len(self._inflight))
-        self._win_decode_steps += 1
-        self._win_lane_tokens += len(self._inflight)
         for req in self._inflight:
             req.codes_done += 1
             if (self.journal is not None
                     and req.codes_done % self.journal.progress_every == 0):
                 # host-held counter only — journaling progress adds no sync
                 self.journal.progress(req)
+        return len(self._inflight)
 
-    def _spec_decode_once(self) -> None:
+    def _spec_decode_once(self) -> int:
         """One speculative round: draft k tokens through the shallow prefix,
         verify them all in one full-model dispatch, advance each lane by its
         accepted length.  The per-round host pull of the accepted-length
@@ -1160,8 +1209,6 @@ class GenerationEngine:
         obs_metrics.counter("serving/spec_accepted_tokens").inc(accepted)
         obs_metrics.counter("serving/spec_rejected_tokens").inc(
             max((k + 1) * len(self._inflight) - accepted, 0))
-        self._win_decode_steps += 1
-        self._win_lane_tokens += lane_tokens
         # request-rounds, so the window gauge is mean accepted/step/request
         self._win_spec_rounds += len(self._inflight)
         self._win_spec_accepted += accepted
@@ -1177,19 +1224,21 @@ class GenerationEngine:
                 draft_s=round(t_draft.s, 6), verify_s=round(t_verify.s, 6),
                 hops=round_hops,
             )
+        return lane_tokens
 
-    def _evict_finished(self) -> List[Request]:
+    def _evict_finished(self) -> tuple:
+        """Evict the requests whose last code is out.  Returns (the healthy
+        completions, the requests evicted, the seconds of `serve/evict` spent
+        waiting for the device, its other seconds)."""
         done = [r for r in self._inflight if r.codes_done >= self.n_gen]
         if not done:
-            return done
+            return done, 0, 0.0, 0.0
         with telemetry.timed_span("serve/evict", iter=self._iter,
                                   n=len(done)) as t_evict:
-            done, blocked_s = self._evict(done)
-        # evict window = host bookkeeping only; the device waits inside it
+            healthy, blocked_s = self._evict(done)
+        # evict = host bookkeeping only; the device waits inside the span
         # (flag sync, codes pulls, VAE decodes) are the "block" share
-        self._phase_acc["block"] += blocked_s
-        self._phase_acc["evict"] += t_evict.s - blocked_s
-        return done
+        return healthy, len(done), blocked_s, t_evict.s - blocked_s
 
     def _evict(self, done: List[Request]) -> tuple:
         """The eviction under its `serve/evict` span.  Returns (the healthy
@@ -1301,15 +1350,30 @@ class GenerationEngine:
         """Close one telemetry window: emit the serving_window event with the
         poll-phase split and the goodput figure and flush the window's spans
         (when telemetry is on), run the SLO monitor, and refresh the
-        status_json scrape file."""
-        now = time.monotonic()
-        elapsed = max(now - self._win_t, 1e-9)
-        steps = self._win_decode_steps
-        lane_tokens = self._win_lane_tokens
+        status_json scrape file.  The window is the rows `self.polls` took
+        since the last call: its seconds run from its first poll's entry to
+        its last poll's end."""
+        rows = self.polls.rows(since=self._win_row)
+        self._win_row = self.polls.total
+        dur = rows["dur_s"]
+        elapsed = (max(rows["t0_s"][-1] + dur[-1] - rows["t0_s"][0], 1e-9)
+                   if len(dur) else 1e-9)
+        steps = int(np.count_nonzero(rows["dispatch_s"]))
+        lane_tokens = int(rows["lanes"].sum())
         ideal = steps * self.ecfg.num_slots
         # goodput: lane-tokens actually decoded vs every slot busy every step
         goodput = lane_tokens / ideal if ideal else None
-        phases = {k: round(v, 6) for k, v in self._phase_acc.items()}
+        phases = {p: round(float(rows[f"{p}_s"].sum()), 6) for p in POLL_PHASES}
+        self._worst_poll = None
+        if len(dur):
+            i = int(dur.argmax())
+            row = {c: v[i].tolist() for c, v in rows.items()}
+            parts = {p: row[f"{p}_s"] for p in POLL_PHASES}
+            parts["other"] = row["dur_s"] - sum(parts.values())
+            phase = max(parts, key=parts.get)
+            self._worst_poll = {
+                "iter": round(row["iter"]), "dur_s": round(row["dur_s"], 6),
+                "phase": phase, "phase_s": round(parts[phase], 6)}
         spec_accept = None
         spec_draft_frac = None
         if self._win_spec_rounds:
@@ -1318,14 +1382,10 @@ class GenerationEngine:
             if self._win_spec_total_s > 0:
                 spec_draft_frac = self._win_spec_draft_s / self._win_spec_total_s
                 obs_metrics.gauge("spec/draft_time_frac").set(spec_draft_frac)
-        self._phase_acc = {k: 0.0 for k in self._phase_acc}
-        self._win_decode_steps = 0
-        self._win_lane_tokens = 0
         self._win_spec_rounds = 0
         self._win_spec_accepted = 0
         self._win_spec_draft_s = 0.0
         self._win_spec_total_s = 0.0
-        self._win_t = now
         tele = telemetry.active()
         if tele is not None:
             spec_fields = {}
@@ -1378,6 +1438,7 @@ class GenerationEngine:
             "inflight": len(self._inflight),
             "pool_occupancy_frac": self.pool.occupancy_frac,
             "pool_free_blocks": self.pool.free_blocks,
+            "worst_poll": self._worst_poll,
             **self.paged_path_state(),
             **self.recurrent_state_info(),
             **self.decode_head_state(),
