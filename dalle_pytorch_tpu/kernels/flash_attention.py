@@ -28,11 +28,18 @@ largest multiple of the 128-lane width under its cap that divides the
 sequence (default cap 384: 384 x 384 tiles at 1,152 and 4,224 positions,
 256 x 256 at 1,280 and 4,352, 128 x 128 at 640).  The kernels' time is the
 grid step's, not the matrix unit's, so the largest tile that divides the
-sequence is the fastest known; at 1,152 positions and fmap 32 it also leaves
-no image pattern a dead tile inside the causal triangle, so `grid="auto"`
-runs every such layer on the dense grid with its mask applied in the tile.
-`flash_attention` counts the tile each call resolved while it is traced
-(`kernels/flash_tile_<bq>x<bk>`).
+sequence is the fastest known.  `flash_attention` counts the tile each call
+resolved while it is traced (`kernels/flash_tile_<bq>x<bk>`).
+
+Grid: `grid="auto"` compacts wherever the resolved grid has a dead step,
+whether causality or the pattern kills it (`sparse_index.grid_has_dead_step`):
+a dead step on the dense grid still takes its slot and fetches its K/V (and
+mask) tiles.  At 384-tiles no image pattern at fmap 32 kills a tile inside
+the causal triangle, but causality kills 3 of 9 steps at 1,152 positions and
+55 of 121 at 4,224, so every causal training call runs the compacted grid;
+a 1 x 1 grid (a 128-position prefill) and a non-causal call without a mask
+(CLIP) have none and stay dense.  Counted like the tile
+(`kernels/flash_grid_compact`, `kernels/flash_grid_dense`).
 
 On CPU (tests) kernels run in interpret mode; any platform other than cpu or
 tpu is an error, never an interpreter.
@@ -1024,8 +1031,10 @@ def flash_attention(
     `grid`: 'dense' schedules the full (bh, nq, nk) tile grid and
     `pl.when`-skips dead tiles; 'compact' runs the compacted (bh, T) grid over
     live tiles only, driven by scalar-prefetched index tables (bit-exact vs
-    'dense'); 'auto' picks 'compact' when the static mask actually kills
-    tiles inside the causal triangle, 'dense' otherwise.  `tables`: explicit
+    'dense'); 'auto' picks 'compact' wherever the resolved grid has a dead
+    step, killed by causality or by a static mask
+    (`sparse_index.grid_has_dead_step`), and 'dense' where every step is live
+    or the mask is traced and no `tables` came with it.  `tables`: explicit
     sparse_index.build_compacted_tables output (dict, or tuple in TABLE_KEYS
     order) — REQUIRED for the compacted grid when the mask is traced
     (scan-selected); must be built at resolve_block() granularity.  `vfa`:
@@ -1078,6 +1087,8 @@ def flash_attention(
             live = None  # traced mask without explicit live: no tile skipping
 
     tabs = _resolve_tables(grid, tables, mask, h, n, causal, block_q, block_k)
+    # ... and which grid it takes, counted the same way
+    obs_metrics.counter(f"kernels/flash_grid_{'dense' if tabs is None else 'compact'}").inc()
     km = None if key_mask is None else key_mask.astype(jnp.int32)[:, None, :]
 
     def run(q, k, v, mask, live, km, tabs):  # local (b, h) under shard_map
@@ -1129,9 +1140,11 @@ def _shard_over_mesh(run, mesh, b, h, mask, live, km, tabs):
 def _resolve_tables(grid, tables, mask, h, n, causal, block_q, block_k):
     """The compacted-grid index tables `_flash` will run with, or None for
     the dense grid.  Validates explicit tables against the resolved grid;
-    builds tables from a static mask at trace time; under 'auto', compacts
-    only when the pattern kills tiles inside the causal triangle (otherwise
-    the dense grid does the same work without the table machinery)."""
+    builds tables from a static mask (or none) at trace time; under 'auto',
+    compacts wherever the grid has a dead step, killed by causality or by
+    the pattern (`sparse_index.grid_has_dead_step`), and keeps the dense grid
+    where every step is live (a 1 x 1 grid, a non-causal call without a
+    mask) or the mask is traced and no tables came with it."""
     from dalle_pytorch_tpu.kernels import sparse_index as si
 
     nq, nk = n // block_q, n // block_k
@@ -1172,10 +1185,7 @@ def _resolve_tables(grid, tables, mask, h, n, causal, block_q, block_k):
         from dalle_pytorch_tpu.ops.masks import block_live_np
 
         bl = block_live_np(mask_np, block_q, block_k)
-    if grid == "auto":
-        cl = si.block_causal_live_np(nq, nk, block_q, block_k) if causal \
-            else np.ones((nq, nk), bool)
-        if bool(np.all(bl | ~cl)):  # host-sync-ok: static trace-time table
-            return None  # no dead tile the dense grid wouldn't also skip
+    if grid == "auto" and not si.grid_has_dead_step(bl, block_q, block_k, causal=causal):
+        return None  # every step of the grid is live: nothing to compact away
     tables = si.build_compacted_tables(bl, block_q, block_k, causal=causal)
     return tuple(jnp.asarray(tables[key]) for key in si.TABLE_KEYS)

@@ -76,8 +76,8 @@ class DALLEConfig:
     sparse_block_size: int = 16
     sparse_per_head: bool = False  # per-head random block layouts (DeepSpeed parity)
     attn_kernel: str = "auto"  # 'auto' | 'flash' | 'xla'
-    # flash-kernel grid: 'auto' compacts when the pattern kills tiles inside
-    # the causal triangle; 'dense' | 'compact' force (TransformerConfig docs)
+    # flash-kernel grid: 'auto' compacts when the tile grid has a dead step
+    # (causal or pattern); 'dense' | 'compact' force (TransformerConfig docs)
     attn_grid: str = "auto"
     attn_vfa: bool = False  # VFA global-max forward pass (allclose, not bitwise)
     # cached/paged decode gathers only pattern-permitted keys (Kmax reads per

@@ -115,9 +115,10 @@ class TransformerConfig:
     sparse_per_head: bool = False
     # flash-kernel grid selection, forwarded to kernels.flash_attention:
     # 'auto' runs the compacted (live-tiles-only, scalar-prefetch) grid when a
-    # layer's pattern actually kills tiles, the dense pl.when-skipping grid
-    # otherwise; 'compact' / 'dense' force.  Compacted and dense grids are
-    # bit-exact, so this is purely a scheduling/DMA-traffic choice.
+    # layer's tile grid has a dead step (causality or the pattern kills it),
+    # the dense pl.when-skipping grid otherwise; 'compact' / 'dense' force.
+    # Compacted and dense grids are bit-exact, so this is purely a
+    # scheduling/DMA-traffic choice.
     attn_grid: str = "auto"
     # VFA-style global-max forward on the compacted grid (precompute row
     # maxima in a max-only pass, skip the per-tile accumulator rescale).
@@ -1082,31 +1083,28 @@ def _stacked_masks(cfg, specs, n: int):
     return np.stack(masks_np), midx
 
 
-def _stacked_flash_tables(cfg, masks_np, n: int, bq: int, bk: int, causal: bool):
+def _stacked_flash_tables(cfg, masks_np, bq: int, bk: int, causal: bool):
     """Stacked compacted-grid index tables for the scan paths — one table set
     per DISTINCT pattern, padded to a common grid length (lax.scan selects a
     TRACED mask per layer, which defeats flash_attention's trace-time table
     build; the grid size must also be layer-invariant).  Returns a dict of
     (D, 1, T)/(D, 1, T2) jnp arrays keyed by sparse_index.TABLE_KEYS, or None
     when the dense grid is the right call (attn_grid='dense', or 'auto' with
-    no pattern killing tiles inside the causal triangle)."""
+    no dead step in any layer's grid: `flash_attention`'s own rule,
+    `sparse_index.grid_has_dead_step`, so a causal stack compacts)."""
     import numpy as np
 
     if cfg.attn_grid == "dense":
         return None
     from dalle_pytorch_tpu.kernels.sparse_index import (
-        TABLE_KEYS, block_causal_live_np, build_compacted_tables,
+        TABLE_KEYS, build_compacted_tables, grid_has_dead_step,
     )
     from dalle_pytorch_tpu.ops.masks import block_live_np
 
     lives = [block_live_np(m, bq, bk) for m in masks_np]
-    if cfg.attn_grid == "auto":
-        cl = (
-            block_causal_live_np(n // bq, n // bk, bq, bk)
-            if causal else np.ones((n // bq, n // bk), bool)
-        )
-        if all(bool(np.all(lv | ~cl)) for lv in lives):
-            return None
+    if cfg.attn_grid == "auto" and not any(
+            grid_has_dead_step(lv, bq, bk, causal=causal) for lv in lives):
+        return None
     per = [build_compacted_tables(lv, bq, bk, causal=causal) for lv in lives]
     pad = (
         max(t["qrow"].shape[-1] for t in per),
@@ -1168,7 +1166,7 @@ def _apply_scan(params, cfg, x, key_mask, layer_keys, seq_constraint, specs, rot
             m.reshape(n // bq, bq, n // bk, bk).any(axis=(1, 3)).astype(np.int32)
             for m in masks_np
         ]))
-        tabstk = _stacked_flash_tables(cfg, masks_np, n, bq, bk, cfg.causal)
+        tabstk = _stacked_flash_tables(cfg, masks_np, bq, bk, cfg.causal)
     except ValueError:  # no valid block: the flash path won't be taken anyway
         lives = None
         tabstk = None

@@ -254,6 +254,24 @@ CASES["dense_scan_mask_d128_b4_seq1152"] = (
     _scan_selected_mask,
     [((4, 16, 1152, 128), jnp.bfloat16)] * 3 + [((1152, 1152), jnp.bool_), ((3, 3), jnp.int32)], 3)
 
+
+def _scan_selected_tables():
+    """What `train_d24`'s scanned body hands the kernel under `grid="auto"`: the
+    traced mask and liveness table, and the layer's compacted tables selected
+    out of the stacked ones (TRACED, in `sparse_index.TABLE_KEYS` order)."""
+    def loss(q, k, v, mask, live, *tabs):
+        out = fa.flash_attention(q, k, v, mask=mask, live=live, tables=tabs)
+        return out.astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+# the 6 causal steps of the 3 x 3 grid of 384-tiles, in both traversals
+CASES["compact_scan_tables_d128_b4_seq1152"] = (
+    _scan_selected_tables,
+    [((4, 16, 1152, 128), jnp.bfloat16)] * 3 + [((1152, 1152), jnp.bool_), ((3, 3), jnp.int32)]
+    + [((1, 6), jnp.int32)] * 10, 3)
+
 # the tile each flash case's sequence resolves under the default cap
 TILE_OF_SEQ = {1280: "256x256", 1152: "384x384", 4224: "384x384"}
 
@@ -407,13 +425,19 @@ def test_train_cells_run_the_flash_kernels_at_384_tiles(workload, calls):
     assert _cell_step_flash_counts(workload)("flash_tile_") == {"flash_tile_384x384": calls}
 
 
-@pytest.mark.parametrize("positions,tiles", [(128, {"flash_tile_128x128": 2}), (129, {})])
-def test_serving_prefill_counts_the_tile_it_counted_before(positions, tiles):
-    """Admission's prefill is not moved by the rule: at 128 positions (the
-    DALL-E serving cells) one 128-tile a layer, as under the old default; at
-    129 (`serve_olmoh_s32`) no multiple of 128 divides the sequence and
-    `_use_flash` keeps the dense path, so no kernel and no tile is counted.
-    Traced, nothing runs."""
+@pytest.mark.parametrize("workload,calls", CELL_FLASH_CALLS)
+def test_train_cells_run_the_flash_kernels_on_the_compacted_grid(workload, calls):
+    """The same traced steps, read for the grid: causality kills 3 of the 9
+    steps of a 3 x 3 grid of 384-tiles (1,152) and 55 of 121 (4,224), so
+    `grid="auto"` compacts every call of every train cell, the scanned body
+    on its stacked tables included, and none takes the dense grid."""
+    assert _cell_step_flash_counts(workload)("flash_grid_") == {"flash_grid_compact": calls}
+
+
+@functools.lru_cache(maxsize=None)
+def _prefill_flash_counts(positions):
+    """Admission's prefill at the DALL-E serving widths, two layers, TRACED
+    (nothing runs), read for its `kernels/flash_*` counters."""
     from dalle_pytorch_tpu.models import transformer as tr
 
     cfg = TransformerConfig(dim=2 * D, depth=2, seq_len=positions - 1 + 32 * 32, heads=2,
@@ -422,9 +446,25 @@ def test_serving_prefill_counts_the_tile_it_counted_before(positions, tiles):
     params = jax.eval_shape(lambda k: tr.init_transformer(k, cfg), jax.random.PRNGKey(0))
     cache = jax.eval_shape(lambda: tr.init_cache(cfg, 1))
     x = jax.ShapeDtypeStruct((1, positions, cfg.dim), jnp.float32)
-    counted = _flash_counted(jax.eval_shape, lambda p, x, c: tr.prefill(p, cfg, x, c),
-                             params, x, cache)
-    assert counted("flash_tile_") == tiles
+    return _flash_counted(jax.eval_shape, lambda p, x, c: tr.prefill(p, cfg, x, c),
+                          params, x, cache)
+
+
+@pytest.mark.parametrize("positions,tiles", [(128, {"flash_tile_128x128": 2}), (129, {})])
+def test_serving_prefill_counts_the_tile_it_counted_before(positions, tiles):
+    """Admission's prefill is not moved by the rule: at 128 positions (the
+    DALL-E serving cells) one 128-tile a layer, as under the old default; at
+    129 (`serve_olmoh_s32`) no multiple of 128 divides the sequence and
+    `_use_flash` keeps the dense path, so no kernel and no tile is counted."""
+    assert _prefill_flash_counts(positions)("flash_tile_") == tiles
+
+
+@pytest.mark.parametrize("positions,grids", [(128, {"flash_grid_dense": 2}), (129, {})])
+def test_serving_prefill_keeps_the_dense_grid(positions, grids):
+    """Nor by the grid rule: a 128-position prefill is a 1 x 1 grid of
+    128-tiles, whose one step is live, so each layer's call stays on the
+    dense grid (its program lowers to the parent's text)."""
+    assert _prefill_flash_counts(positions)("flash_grid_") == grids
 
 
 @pytest.fixture(scope="module")

@@ -398,3 +398,24 @@ def test_flash_counts_the_tile_it_resolved():
     assert grew(lambda: trace(block_q=128, block_k=128)) == [0, 0, 1, 0, 0]
     assert grew(lambda: trace(block_q=128)) == [0, 0, 0, 1, 0]  # each side has its cap
     assert grew(lambda: trace(block_q=64, block_k=64)) == [0, 0, 0, 0, 1]
+
+
+def test_flash_counts_the_grid_it_took():
+    """`kernels/flash_grid_compact` / `kernels/flash_grid_dense`: beside the
+    tile counter and in the same way (one count a traced call), by the grid
+    the call resolved.  `auto` compacts wherever a step is dead: causality
+    kills one of a 2 x 2 grid's steps; a 1 x 1 grid and a non-causal call
+    without a mask have none; a traced mask without tables stays dense."""
+    grew = functools.partial(_grew, ["kernels/flash_grid_compact", "kernels/flash_grid_dense"])
+    x = jax.ShapeDtypeStruct((1, 1, 768, 16), jnp.float32)
+    trace = lambda x=x, **kw: jax.eval_shape(lambda q: flash_attention(q, q, q, **kw), x)
+    assert grew(trace) == [1, 0]
+    assert grew(lambda: trace(causal=False)) == [0, 1]
+    assert grew(lambda: trace(grid="dense")) == [0, 1]
+    assert grew(lambda: trace(causal=False, grid="compact")) == [1, 0]
+    assert grew(lambda: trace(jax.ShapeDtypeStruct((1, 1, 384, 16), jnp.float32))) == [0, 1]
+    traced_mask = lambda q, m: flash_attention(q, q, q, mask=m)
+    assert grew(jax.eval_shape, traced_mask, x,
+                jax.ShapeDtypeStruct((768, 768), jnp.bool_)) == [0, 1]
+    q = jnp.zeros((1, 1, 768, 16), jnp.float32)
+    assert grew(jax.jit(lambda q: flash_attention(q, q, q) + flash_attention(q, q, q)), q) == [2, 0]

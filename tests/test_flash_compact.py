@@ -155,18 +155,43 @@ def test_vfa_forward_allclose():
         np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4)
 
 
-def test_auto_grid_compacts_sparse_keeps_full_dense():
-    """'auto' == 'compact' for a tile-killing pattern (same bits out), and
-    falls back to the dense grid for mask=None without building tables."""
+def test_auto_grid_compacts_wherever_a_step_is_dead():
+    """'auto' == 'compact' for a tile-killing pattern (same bits out), and for
+    mask=None too: causality alone kills the 3 x 3 grid's 3 steps above the
+    diagonal, so 'auto' builds the causal tables and the output is still the
+    dense grid's, bit for bit."""
+    from dalle_pytorch_tpu.kernels.flash_attention import _resolve_tables
+
     mask = jnp.asarray(_pattern_for(_tcfg(), "axial_row"))
     q, k, v, do = qkv(seed=7)
     auto = _run("auto", mask, q, k, v, do)
     compact = _run("compact", mask, q, k, v, do)
     for a, b in zip(auto, compact):
         np.testing.assert_array_equal(a, b)
+    tabs = _resolve_tables("auto", None, None, 1, N, True, BLOCK, BLOCK)
+    assert si.live_tile_counts(dict(zip(si.TABLE_KEYS, tabs))) == (6, 6)
     out_auto = flash_attention(q, k, v, block_q=BLOCK, block_k=BLOCK, grid="auto")
     out_dense = flash_attention(q, k, v, block_q=BLOCK, block_k=BLOCK, grid="dense")
     np.testing.assert_array_equal(np.asarray(out_auto), np.asarray(out_dense))
+
+
+_CAUSAL_3x3 = si.block_causal_live_np(3, 3, BLOCK, BLOCK)
+
+
+@pytest.mark.parametrize("live,causal,dead", [
+    (np.ones((3, 3), bool), True, True),  # causality alone kills the upper triangle
+    (~np.eye(3, dtype=bool), False, True),  # the pattern alone kills the diagonal
+    (_CAUSAL_3x3 & ~np.eye(3, k=-1, dtype=bool), True, True),  # both
+    (np.ones((3, 3), bool), False, False),  # neither: every step is live
+    (np.ones((1, 1), bool), True, False),  # a 1 x 1 grid: its one step is live
+    (np.ones((2, 3, 3), bool), False, False),  # per-head, all live ...
+    (np.stack([np.ones((3, 3), bool), ~np.eye(3, dtype=bool)]), False, True),  # ... one head not
+], ids=["causal_only", "pattern_only", "both", "neither", "one_by_one",
+        "per_head_live", "per_head_one_dead"])
+def test_grid_has_dead_step(live, causal, dead):
+    """`grid="auto"`'s one rule, on hand-made tile liveness: compact exactly
+    when some step of the grid is dead, whoever kills it."""
+    assert si.grid_has_dead_step(live, BLOCK, BLOCK, causal=causal) is dead
 
 
 # --- sparse_index table builders ---------------------------------------------
@@ -297,9 +322,9 @@ def test_scan_path_profiler_and_kernel_resolve_one_block(n, fmap, want, monkeypa
     seen = {"scan": [], "live": [], "profiler": []}
     stacked, flash, causal_live = tr._stacked_flash_tables, fa.flash_attention, si.block_causal_live_np
 
-    def spy_stacked(cfg, masks_np, n, bq, bk, causal):
+    def spy_stacked(cfg, masks_np, bq, bk, causal):
         seen["scan"].append((bq, bk))
-        return stacked(cfg, masks_np, n, bq, bk, causal)
+        return stacked(cfg, masks_np, bq, bk, causal)
 
     def spy_flash(q, k, v, **kw):
         seen["live"].append(tuple(kw["live"].shape))
@@ -483,12 +508,19 @@ def test_tile_384_matches_dense_attention(case):
         np.testing.assert_array_equal(a, b)
 
 
-def test_tile_384_leaves_nothing_dead_inside_the_triangle_at_1152():
-    """Why every layer of the DALL-E cells takes the dense grid now: at 1,152
-    positions and fmap 32 the three patterns leave 30, 45 and 35 of the 45
-    causal 128-tiles live, and all 6 of the 6 causal 384-tiles, so
-    `grid="auto"` builds no tables for any of them."""
+def test_tile_384_compacts_the_causal_grid_at_1152():
+    """Why every causal layer of the DALL-E cells takes the compacted grid:
+    at 1,152 positions and fmap 32 the three patterns leave 30, 45 and 35 of
+    the 45 causal 128-tiles live, and all 6 of the 6 causal 384-tiles, so no
+    pattern kills a tile inside the triangle at 384; causality still kills 3
+    of the 9 steps, so `grid="auto"` builds tables of the 6 live ones for each
+    pattern and for `mask=None`.  It builds none where every step is live: a
+    1 x 1 grid, a non-causal call without a mask."""
     from dalle_pytorch_tpu.kernels.flash_attention import _resolve_tables
+
+    def live_steps(pm, b, causal=True):
+        tabs = _resolve_tables("auto", None, pm, 1, N384, causal, b, b)
+        return None if tabs is None else si.live_tile_counts(dict(zip(si.TABLE_KEYS, tabs)))
 
     live_at = {}
     for kind in ("axial_row", "axial_col", "conv_like"):
@@ -496,17 +528,19 @@ def test_tile_384_leaves_nothing_dead_inside_the_triangle_at_1152():
         for b in (128, TILE):
             cl = si.block_causal_live_np(N384 // b, N384 // b, b, b)
             live_at[kind, b] = (int((block_live_np(pm, b, b) & cl).sum()), int(cl.sum()))
-        assert _resolve_tables("auto", None, pm, 1, N384, True, TILE, TILE) is None
-        assert (_resolve_tables("auto", None, pm, 1, N384, True, 128, 128) is None) == (kind == "axial_col")
+            assert live_steps(pm, b) == (live_at[kind, b][0],) * 2
     assert live_at == {("axial_row", 128): (30, 45), ("axial_row", TILE): (6, 6),
                        ("axial_col", 128): (45, 45), ("axial_col", TILE): (6, 6),
                        ("conv_like", 128): (35, 45), ("conv_like", TILE): (6, 6)}
+    assert live_steps(None, TILE) == (6, 6)
+    assert live_steps(None, TILE, causal=False) is None
+    assert _resolve_tables("auto", None, None, 1, TILE, True, TILE, TILE) is None
 
 
 def test_scan_remat_stack_at_1152_matches_unrolled():
     """The d24 cell's path at its own sequence, small widths: `scan_layers` +
     remat `full` over the four-pattern cycle (one traced mask a layer, its
-    (3, 3) liveness table, no compacted tables) against the unrolled stack,
+    (3, 3) liveness table and its compacted tables) against the unrolled stack,
     output and input gradient.  allclose, not equal: the scan reorders float
     operations OUTSIDE attention (stacked parameters)."""
     cfg = _tcfg384(depth=4, dim=16, heads=1, dim_head=16, shift_tokens=True,
@@ -524,6 +558,39 @@ def test_scan_remat_stack_at_1152_matches_unrolled():
     o_unrl, g_unrl = run(dataclasses.replace(cfg, scan_layers=False, execution="sequential"))
     np.testing.assert_allclose(o_scan, o_unrl, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(g_scan, g_unrl, atol=1e-5, rtol=1e-5)
+
+
+def test_scan_remat_stack_at_1152_compacts_and_equals_the_dense_grid():
+    """`train_d24`'s path under `grid="auto"`: `_stacked_flash_tables` builds
+    the stacked tables at 1,152 positions and 384-tiles (causality kills 3 of
+    the 9 steps in every layer, though no pattern kills a tile inside the
+    triangle), and the scanned, rematted stack run on them equals the dense
+    grid's output and gradients (input and parameters) bit for bit."""
+    from dalle_pytorch_tpu.models.transformer import (
+        _stacked_flash_tables, _stacked_masks, derive_layer_specs,
+    )
+
+    cfg = _tcfg384(depth=4, dim=16, heads=1, dim_head=16, shift_tokens=True,
+                   attn_types=("full", "axial_row", "axial_col", "conv_like"),
+                   scan_layers=True, execution="remat", remat_policy="full",
+                   attn_kernel="flash")
+    masks_np, _ = _stacked_masks(cfg, derive_layer_specs(cfg), N384)
+    tabs = _stacked_flash_tables(cfg, masks_np, TILE, TILE, True)
+    assert tabs is not None and tabs["qrow"].shape == (4, 1, 6)
+    assert int(tabs["valid"].sum()) == int(tabs["validT"].sum()) == 4 * 6
+    params = init_transformer(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, N384, cfg.dim), jnp.float32)
+
+    def run(c):
+        f = lambda p, x: jnp.sum(jnp.sin(apply_transformer(p, c, x)))
+        grads = jax.grad(f, argnums=(0, 1))(params, x)
+        return [np.asarray(apply_transformer(params, c, x))] + [
+            np.asarray(g) for g in jax.tree_util.tree_leaves(grads)]
+
+    auto, dense = run(cfg), run(dataclasses.replace(cfg, attn_grid="dense"))
+    assert len(auto) == len(dense) > 2
+    for a, b in zip(auto, dense):
+        np.testing.assert_array_equal(a, b)
 
 
 # --- seq-4096 scenario -------------------------------------------------------
