@@ -36,9 +36,11 @@ carrying `iter=` and, where it belongs to one request, `req=<Request.id>`:
     serve/submit
     serve/poll
       serve/admit                 one per admitted request; lanes=
-        serve/admit.alloc         tables, RNG split        -> phases["admission"]
-        serve/admit.dispatch      the admit / ingest jit call
-        serve/admit.lane_meta     the eager .at[].set scatters
+        serve/admit.alloc         block tables, lane indices
+                                                           -> phases["admission"]
+        serve/admit.dispatch      the admit / ingest jit call: it splits the
+                                  request's key and writes the lanes' metadata
+        serve/admit.lane_meta     host bookkeeping (the in-flight list)
         serve/admit.ttft_sync     block_until_ready; with dispatch and
                                   lane_meta                -> phases["prefill"]
       serve/decode.dispatch       around it serve/spec.draft and
@@ -47,7 +49,8 @@ carrying `iter=` and, where it belongs to one request, `req=<Request.id>`:
         serve/evict.flag_sync     the poisoned-flag pull: the drain of the
                                   queued steps             -> phases["evict_sync"]
         serve/evict.codes_pull    per request              -> phases["codes_pull"]
-        serve/evict.lane_reset    the eager scatters that free the lanes
+        serve/evict.lane_reset    the dispatch of the reset program that
+                                  frees the lanes
         serve/evict.vae_decode    per request, dispatch + block
                                                            -> phases["vae_decode"]
         serve/evict.pixels_pull   per request
@@ -82,8 +85,15 @@ at `engine.polls.rows()`.  Every span closes where the code already returns or
 already blocks: telemetry-off poll() performs ZERO additional device syncs
 (tools/lint_host_sync.py keeps that mechanical).  The jitted programs carry
 stable names (`serve_decode_step`, `serve_admit`, `serve_ingest`,
-`serve_vae_decode`, `serve_spec_draft`, `serve_spec_verify`), which is how a
-trace's `XLA Modules` line is read.
+`serve_lane_reset`, `serve_vae_decode`, `serve_spec_draft`,
+`serve_spec_verify`), which is how a trace's `XLA Modules` line is read.
+
+Every write to the lanes' device state runs inside one of those programs:
+admission writes a request's lanes in `serve_admit` / `serve_ingest`, and
+eviction and `drain()` free lanes through `serve_lane_reset`, one donated
+program over a mask of all slots (so one compile serves one lane, a guided
+pair or a whole drain; `serving/lane_reset_calls` and
+`serving/lane_reset_lanes` count its dispatches and the lanes they free).
 """
 from __future__ import annotations
 
@@ -128,6 +138,11 @@ POLL_PHASES = ("admit", "dispatch", "block", "evict")
 POLL_COLUMNS = ("iter", "t0_s", "dur_s") + tuple(f"{p}_s" for p in POLL_PHASES) + (
     "admitted", "evicted", "lanes")
 POLL_CAPACITY = 65536  # a 48 s window at a 1 ms poll still fits; ~5 MB
+# the lane fields a freed lane returns to zero (`serve_lane_reset`)
+LANE_RESET_FIELDS = ("active", "block_tables", "offsets", "img_prev",
+                     "poisoned", "cand_cap")
+# the request key `serve_ingest` arms a lane with when it is given none
+_NO_KEY = np.zeros((2,), np.uint32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -367,6 +382,18 @@ class GenerationEngine:
             return self._decode_step_impl(params, state)
 
         self._decode_fn = jax.jit(serve_decode_step, donate_argnums=(1,))
+
+        def serve_lane_reset(state, mask):
+            # LANE_RESET_FIELDS back to zero on the lanes `mask` selects
+            out = dict(state)
+            for name in LANE_RESET_FIELDS:
+                x = state[name]
+                m = mask.reshape(mask.shape + (1,) * (x.ndim - 1))
+                out[name] = jnp.where(m, jnp.zeros((), x.dtype), x)
+            return out
+
+        self._lane_reset_fn = jax.jit(serve_lane_reset, donate_argnums=(0,))
+        self._warm_reset = False
         self._admit_fns: Dict[Any, Any] = {}
         self._vae_decode = None
         if vae_params is not None:
@@ -460,12 +487,13 @@ class GenerationEngine:
                               text, k0, temperature, cond_scale)
 
     def _ingest_impl(self, state, cache_layers, code, bt_rows, lane_idx,
-                     lanes: int):
+                     lanes: int, step_keys, temperature, cond_scale, cand_cap):
         """The other half of admission: scatter a prefilled KV prefix into
-        the paged pool and arm the lanes.  Pure data movement on the handoff
-        payload — shared verbatim by the fused admit jit and the
-        disaggregated ingest jit, which is what makes the two paths
-        bit-identical."""
+        the paged pool and arm the lanes — the request's step keys, its
+        temperature, guidance scale and candidate cap, the active flag and,
+        for a guided pair, the [cond] / [null] indices.  Shared verbatim by
+        the fused admit jit and the disaggregated ingest jit, which is what
+        makes the two paths bit-identical."""
         pool = write_prefill_to_pool(
             state["pool"], bt_rows, cache_layers,
             self.n_pre, self.ecfg.block_size, slots=lane_idx,
@@ -485,7 +513,8 @@ class GenerationEngine:
 
         with jax.named_scope("codes_write"):
             codeb = jnp.broadcast_to(code, (lanes,))
-            return dict(
+            cond = lane_idx[0]
+            st = dict(
                 state,
                 pool=pool,
                 rings=rings,
@@ -494,38 +523,69 @@ class GenerationEngine:
                 prev_code=state["prev_code"].at[lane_idx].set(codeb),
                 offsets=state["offsets"].at[lane_idx].set(self.n_pre),
                 img_prev=state["img_prev"].at[lane_idx].set(0),
+                keys=state["keys"].at[cond].set(step_keys),
+                temp=state["temp"].at[lane_idx].set(temperature),
+                cscale=state["cscale"].at[lane_idx].set(cond_scale),
+                active=state["active"].at[lane_idx].set(True),
+                cand_cap=state["cand_cap"].at[lane_idx].set(cand_cap),
+            )
+            if lanes == 2:
+                # the [null] lane takes its partner's logits and token
+                null = lane_idx[1]
+                return dict(
+                    st,
+                    guided=st["guided"].at[cond].set(True).at[null].set(False),
+                    partner=st["partner"].at[cond].set(null).at[null].set(null),
+                    feed_src=st["feed_src"].at[cond].set(cond).at[null].set(cond),
+                )
+            return dict(
+                st,
+                guided=st["guided"].at[cond].set(False),
+                partner=st["partner"].at[cond].set(cond),
+                feed_src=st["feed_src"].at[cond].set(cond),
             )
 
     def _admit_fn_for(self, cond_scale: float, lanes: int):
-        key = (float(cond_scale), lanes)  # host-sync-ok: python jit-cache key
-        fn = self._admit_fns.get(key)
+        fn_key = (float(cond_scale), lanes)  # host-sync-ok: python jit-cache key
+        fn = self._admit_fns.get(fn_key)
         if fn is not None:
             return fn
 
-        def serve_admit(params, state, text, k0, temperature, bt_rows, lane_idx):
+        def serve_admit(params, state, text, key, temperature, bt_rows,
+                        lane_idx, cand_cap=False):
+            with jax.named_scope("sample"):
+                step_keys, k0 = request_keys(key, state["keys"].shape[1])
             cache_layers, code = self._prefill_sample_impl(
                 params, text, k0, temperature, cond_scale)
             return self._ingest_impl(
-                state, cache_layers, code, bt_rows, lane_idx, lanes)
+                state, cache_layers, code, bt_rows, lane_idx, lanes,
+                step_keys, temperature, cond_scale, cand_cap)
 
         fn = jax.jit(serve_admit, donate_argnums=(1,))
-        self._admit_fns[key] = fn
+        self._admit_fns[fn_key] = fn
         return fn
 
     def _ingest_fn_for(self, lanes: int):
         """Jitted pool-write for a handoff produced elsewhere (the decode
-        side of prefill/decode disaggregation)."""
-        key = ("ingest", lanes)
-        fn = self._admit_fns.get(key)
+        side of prefill/decode disaggregation).  The request's key and
+        sampling knobs arm its lanes as in `serve_admit`; left out, they are
+        those of a request that asks for nothing (key zero)."""
+        fn_key = ("ingest", lanes)
+        fn = self._admit_fns.get(fn_key)
         if fn is not None:
             return fn
 
-        def serve_ingest(state, cache_layers, code, bt_rows, lane_idx):
+        def serve_ingest(state, cache_layers, code, bt_rows, lane_idx,
+                         key=_NO_KEY, temperature=1.0, cond_scale=1.0,
+                         cand_cap=False):
+            with jax.named_scope("sample"):
+                step_keys, _ = request_keys(key, state["keys"].shape[1])
             return self._ingest_impl(
-                state, cache_layers, code, bt_rows, lane_idx, lanes)
+                state, cache_layers, code, bt_rows, lane_idx, lanes,
+                step_keys, temperature, cond_scale, cand_cap)
 
         fn = jax.jit(serve_ingest, donate_argnums=(0,))
-        self._admit_fns[key] = fn
+        self._admit_fns[fn_key] = fn
         return fn
 
     # ------------------------------------------------------------- lifecycle
@@ -720,17 +780,7 @@ class GenerationEngine:
             self._free_lanes.extend(req.lanes)
         self._inflight = []
         if all_lanes:
-            li = jnp.asarray(all_lanes, jnp.int32)
-            st = self._state
-            self._state = dict(
-                st,
-                active=st["active"].at[li].set(False),
-                block_tables=st["block_tables"].at[li].set(0),
-                offsets=st["offsets"].at[li].set(0),
-                img_prev=st["img_prev"].at[li].set(0),
-                poisoned=st["poisoned"].at[li].set(False),
-                cand_cap=st["cand_cap"].at[li].set(False),
-            )
+            self._reset_lanes(all_lanes)
         obs_metrics.counter("serving/drained").inc(len(exports))
         self._window_event()
         return exports
@@ -981,10 +1031,12 @@ class GenerationEngine:
 
     def _do_admit(self, req: Request) -> float:
         """One admission, as the `serve/admit` span and its four children:
-        alloc (tables, RNG split), dispatch (the admit / ingest jit),
-        lane_meta (the eager per-lane scatters) and ttft_sync (the first
-        token must exist).  `Request.phases` and the poll's admit time (the
-        span's seconds, returned) are fed from the spans' own readings."""
+        alloc (block tables and lane indices, on the host), dispatch (the
+        admit / ingest jit, which also splits the request's key and writes
+        every lane field the decode step reads), lane_meta (the host's
+        in-flight bookkeeping) and ttft_sync (the first token must exist).
+        `Request.phases` and the poll's admit time (the span's seconds,
+        returned) are fed from the spans' own readings."""
         req.phases["queue_wait"] = time.monotonic() - req.arrival_t
         ids = {"iter": self._iter, "req": req.id}
         with telemetry.timed_span("serve/admit", lanes=req.lanes_needed,
@@ -1009,63 +1061,36 @@ class GenerationEngine:
                 ])
                 if rec is not None:
                     rec.ctx = None
-                # the request's RNG stream, derived exactly as _decode_phase does
-                key, k0 = jax.random.split(jnp.asarray(req.key, jnp.uint32))
-                step_keys = jax.random.split(key, max(self.n_gen - 1, 1))
-
-                text = jnp.asarray(req.text[None], jnp.int32)
-                lane_idx = jnp.asarray(lanes, jnp.int32)
+                lane_idx = np.asarray(lanes, np.int32)  # host-sync-ok: host lane ids
+                # the request's knobs go to the program that arms its lanes
+                # as host scalars; its key is split there, on the device
+                temperature = np.float32(req.temperature)
+                cand_cap = np.bool_(req.degrade_rung >= 2)
             req.phases["admission"] = t_alloc.s
             with telemetry.timed_span("serve/admit.dispatch", **ids) as t_dispatch:
                 if self.prefill_backend is not None:
                     # disaggregated: the prefill worker ran _prefill_sample_impl
                     # on ITS mesh (deriving the same k0 from req.key) and hands
-                    # the KV prefix + first code over; this side only scatters it
-                    # into the pool — the ingest jit is the identical graph the
-                    # fused admit traces, so the two paths stay bit-identical
+                    # the KV prefix + first code over; this side scatters it
+                    # into the pool and arms the lanes — the ingest jit is the
+                    # identical graph the fused admit traces, so the two paths
+                    # stay bit-identical
                     handoff = self.prefill_backend.prefill(req)
                     ingest_fn = self._ingest_fn_for(len(lanes))
                     with self._suspend_compiles():
                         self._state = ingest_fn(
                             self._state, handoff["layers"], handoff["code"],
-                            jnp.asarray(tables, jnp.int32), lane_idx,
+                            tables, lane_idx, req.key, temperature,
+                            np.float32(req.cond_scale), cand_cap,
                         )
                 else:
                     admit_fn = self._admit_fn_for(req.cond_scale, len(lanes))
                     with self._suspend_compiles():
                         self._state = admit_fn(
-                            self.params, self._state, text, k0,
-                            jnp.asarray(req.temperature, jnp.float32),
-                            jnp.asarray(tables, jnp.int32), lane_idx,
+                            self.params, self._state, req.text[None],
+                            req.key, temperature, tables, lane_idx, cand_cap,
                         )
             with telemetry.timed_span("serve/admit.lane_meta", **ids) as t_meta:
-                # host-owned lane metadata (small per-admission device updates)
-                st = self._state
-                cond = lanes[0]
-                st = dict(
-                    st,
-                    keys=st["keys"].at[cond].set(step_keys.astype(jnp.uint32)),
-                    temp=st["temp"].at[lane_idx].set(req.temperature),
-                    cscale=st["cscale"].at[lane_idx].set(req.cond_scale),
-                    active=st["active"].at[lane_idx].set(True),
-                    cand_cap=st["cand_cap"].at[lane_idx].set(req.degrade_rung >= 2),
-                )
-                if len(lanes) == 2:
-                    null = lanes[1]
-                    st = dict(
-                        st,
-                        guided=st["guided"].at[cond].set(True).at[null].set(False),
-                        partner=st["partner"].at[cond].set(null).at[null].set(null),
-                        feed_src=st["feed_src"].at[cond].set(cond).at[null].set(cond),
-                    )
-                else:
-                    st = dict(
-                        st,
-                        guided=st["guided"].at[cond].set(False),
-                        partner=st["partner"].at[cond].set(cond),
-                        feed_src=st["feed_src"].at[cond].set(cond),
-                    )
-                self._state = st
                 self._inflight.append(req)
                 req.codes_done = 1  # the first image token came out of prefill
             with telemetry.timed_span("serve/admit.ttft_sync", **ids) as t_sync:
@@ -1226,6 +1251,19 @@ class GenerationEngine:
             )
         return lane_tokens
 
+    def _reset_lanes(self, lanes: List[int]) -> None:
+        """Free `lanes` on the device: one dispatch of `serve_lane_reset`,
+        whose mask spans every slot, so one lane, a guided pair and a whole
+        drain share its one compile."""
+        mask = np.zeros((self.ecfg.num_slots,), bool)
+        mask[lanes] = True
+        with (self._suspend_compiles() if not self._warm_reset
+              else contextlib.nullcontext()):
+            self._state = self._lane_reset_fn(self._state, mask)
+        self._warm_reset = True
+        obs_metrics.counter("serving/lane_reset_calls").inc()
+        obs_metrics.counter("serving/lane_reset_lanes").inc(len(lanes))
+
     def _evict_finished(self) -> tuple:
         """Evict the requests whose last code is out.  Returns (the healthy
         completions, the requests evicted, the seconds of `serve/evict` spent
@@ -1283,17 +1321,7 @@ class GenerationEngine:
             self._free_lanes.extend(req.lanes)
             req.latency_s = time.monotonic() - req.arrival_t
         with telemetry.span("serve/evict.lane_reset", iter=it):
-            li = jnp.asarray(all_lanes, jnp.int32)
-            st = self._state
-            self._state = dict(
-                st,
-                active=st["active"].at[li].set(False),
-                block_tables=st["block_tables"].at[li].set(0),
-                offsets=st["offsets"].at[li].set(0),
-                img_prev=st["img_prev"].at[li].set(0),
-                poisoned=st["poisoned"].at[li].set(False),
-                cand_cap=st["cand_cap"].at[li].set(False),
-            )
+            self._reset_lanes(all_lanes)
         for req in retry:
             # nonfinite lane: evict, free, and re-decode from scratch (same
             # key, same RNG stream) — a transient NaN won't recur; a truly
@@ -1555,6 +1583,14 @@ def _blocks_per_seq(tcfg, block_size: int) -> int:
     from dalle_pytorch_tpu.models.transformer import paged_blocks_per_seq
 
     return paged_blocks_per_seq(tcfg, block_size)
+
+
+def request_keys(key, n_steps: int):
+    """A request's RNG stream from its (2,) uint32 key, split exactly as
+    `_decode_phase` splits a batch-1 call's: (the (n_steps, 2) step keys,
+    the first token's key k0)."""
+    key, k0 = jax.random.split(jnp.asarray(key, jnp.uint32))
+    return jax.random.split(key, n_steps), k0
 
 
 def prefill_sample(params, cfg, filter_thres: float, text, k0, temperature,
