@@ -124,7 +124,8 @@ def build_parser() -> argparse.ArgumentParser:
                         help="comma-separated cycle of full,axial_row,axial_col,conv_like,sparse")
     parser.add_argument("--block_json", type=str, default=None,
                         help="a JSON file whose keys that name DALLEConfig fields describe the "
-                             "block (norm, kv_heads, gdn_*, moe_*, mla_*, dense_*, mtp_*, "
+                             "block (norm, kv_heads, window*, rope_yarn_*, attn_gate, gdn_*, moe_*, "
+                             "mla_*, dense_*, mtp_*, "
                              "attn_types, ...): a hybrid trunk from the normal entry point; "
                              "a benchmark configuration file is one.  Sizes the command line "
                              "also sets (dim, depth, heads, ...) are the file's")

@@ -1015,6 +1015,7 @@ def flash_attention(
     tables=None,
     vfa: bool = False,
     mesh=None,
+    count_as: Optional[str] = None,
 ) -> jnp.ndarray:
     """(b, h, n, d) attention.  `mask`: optional static (n, n) — or
     per-head (h, n, n) — bool pattern (True = may attend), combined with
@@ -1043,7 +1044,11 @@ def flash_attention(
     bit-identical, to the online-softmax forward); ignored on the dense
     grid.  `mesh`: the multi-device mesh the enclosing jit is partitioned
     over, if any — the kernels then run under `jax.shard_map`, batch split
-    over the data axes and heads over `tp` (see `_shard_over_mesh`)."""
+    over the data axes and heads over `tp` (see `_shard_over_mesh`).
+    `count_as`: a name under which the call counts itself while traced,
+    `kernels/flash_<name>_calls`, and the forward grid steps it runs,
+    `kernels/flash_<name>_live_steps` (the live entries of the compacted grid
+    it built from a static mask, or every step of the dense grid)."""
     b, h, n, d = q.shape
     if scale is None:
         scale = d ** -0.5
@@ -1089,6 +1094,16 @@ def flash_attention(
     tabs = _resolve_tables(grid, tables, mask, h, n, causal, block_q, block_k)
     # ... and which grid it takes, counted the same way
     obs_metrics.counter(f"kernels/flash_grid_{'dense' if tabs is None else 'compact'}").inc()
+    if count_as:
+        from dalle_pytorch_tpu.kernels.sparse_index import TABLE_KEYS
+
+        valid = None if tabs is None else tabs[TABLE_KEYS.index("valid")]
+        if valid is None or isinstance(valid, np.ndarray):  # tables built here are static
+            steps = (n // block_q) * (n // block_k) if valid is None else int(valid.sum())
+            obs_metrics.counter(f"kernels/flash_{count_as}_live_steps").inc(steps)
+        obs_metrics.counter(f"kernels/flash_{count_as}_calls").inc()
+    if tabs is not None:
+        tabs = tuple(jnp.asarray(t) for t in tabs)
     km = None if key_mask is None else key_mask.astype(jnp.int32)[:, None, :]
 
     def run(q, k, v, mask, live, km, tabs):  # local (b, h) under shard_map
@@ -1188,4 +1203,4 @@ def _resolve_tables(grid, tables, mask, h, n, causal, block_q, block_k):
     if grid == "auto" and not si.grid_has_dead_step(bl, block_q, block_k, causal=causal):
         return None  # every step of the grid is live: nothing to compact away
     tables = si.build_compacted_tables(bl, block_q, block_k, causal=causal)
-    return tuple(jnp.asarray(tables[key]) for key in si.TABLE_KEYS)
+    return tuple(np.asarray(tables[key]) for key in si.TABLE_KEYS)  # host-sync-ok: static tables

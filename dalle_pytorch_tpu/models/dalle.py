@@ -32,6 +32,8 @@ from dalle_pytorch_tpu.ops.stable import divide_max
 # the fields that describe the block, handed to TransformerConfig as they are
 _BLOCK_FIELDS = (
     "norm", "norm_eps", "layer_scale", "kv_heads", "partial_rotary_factor", "rotary_theta",
+    "rope_yarn_factor", "rope_yarn_original_max", "rope_yarn_beta_fast", "rope_yarn_beta_slow",
+    "window_heads", "window_rotary_theta", "window", "attn_gate",
     "gdn_key_heads", "gdn_value_heads", "gdn_key_dim", "gdn_value_dim", "gdn_conv_kernel",
     "gdn_neg_eigval", "pre_norm", "qk_norm", "attn_bias",
     "moe_experts", "moe_top_k", "moe_ff_dim", "moe_shared_ff_dim",
@@ -89,16 +91,24 @@ class DALLEConfig:
     pp_interleave: int = 1  # circular pipeline chunks per device (bubble / v)
     pp_num_micro: Optional[int] = None  # GPipe microbatches (None = auto)
     # the block as a parameter (TransformerConfig has each field's meaning):
-    # `attn_types` may cycle `gated_delta` / `gated_full` / `mla`; a hybrid trunk
-    # is trained through forward(), and served where `transformer.refuse_hybrid`
-    # lets it (`gated_delta` beside pattern layers: yes; `gated_full`, `mla`,
-    # routed experts: no)
+    # `attn_types` may cycle `gated_delta` / `gated_full` / `gated_window` /
+    # `mla`; a hybrid trunk is trained through forward(), and served where
+    # `transformer.refuse_hybrid` lets it (`gated_delta` beside pattern layers:
+    # yes; `gated_full`, `gated_window`, `mla`, routed experts: no)
     norm: str = "layernorm"
     norm_eps: float = 1e-6
     layer_scale: bool = True
     kv_heads: Optional[int] = None
     partial_rotary_factor: float = 1.0
     rotary_theta: float = 10000.0
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_max: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    window_heads: Optional[int] = None
+    window_rotary_theta: float = 10000.0
+    window: int = 0
+    attn_gate: str = "elementwise"
     gdn_key_heads: int = 0
     gdn_value_heads: int = 0
     gdn_key_dim: int = 0

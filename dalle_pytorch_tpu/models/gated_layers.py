@@ -6,13 +6,23 @@ Layer equations (x a token's hidden vector, no bias anywhere):
 
   N(x) = x / sqrt(mean(x^2) + eps) * (1 + w)               zero-centred RMSNorm
 
-  gated_full:   [q, gate] = W_q x (heads x dim_head each, q first);
+  gated_full:   [q, gate] = W_q x (heads x dim_head each, q first; under
+                `attn_gate` 'per_head' the gate is one column a head);
                 k = W_k x, v = W_v x (kv_heads x dim_head);  q, k pass a
-                per-head N;  rotary (rotate-half) on the first
-                partial_rotary_factor * dim_head channels, by stream position;
+                per-head N (plain, x / rms * w, under `norm` 'rmsnorm');
+                rotary (rotate-half) on the first
+                partial_rotary_factor * dim_head channels, by stream position
+                (YaRN frequencies and cos, sin x its attention factor where
+                `rope_yarn_factor` is set);
                 causal softmax(q k^T / sqrt(dim_head)) v, each key/value head
                 serving heads / kv_heads query heads;
-                out = W_o (attn * sigmoid(gate))
+                out = W_o (attn * sigmoid(gate)), a head's gate scalar over
+                its dim_head channels under 'per_head'
+
+  gated_window: gated_full with `window_heads` query heads, the default
+                rotary of `window_rotary_theta` on the whole of dim_head, and
+                a causal band: query i sees keys
+                i - window + 1 .. i [`sliding_window`]
 
   gated_delta:  [q, k, v, z] = W_qkvz x;  [b, a] = W_ba x;
                 (q, k, v) <- silu(causal depthwise conv(q, k, v));
@@ -30,8 +40,8 @@ rule's state) are float32 whatever the compute type.  `gated_delta` has two
 forms of one layer: the full sequence (training, and a serving prefill, which
 also takes the state after the last position and the last taps - 1 inputs of
 the convolution) and `gated_delta_step`, one token of every slot on that
-state and those taps (the serving decode step).  `gated_full` runs on the
-training path only (models/transformer.refuse_hybrid).
+state and those taps (the serving decode step).  `gated_full` and
+`gated_window` run on the training path only (models/transformer.refuse_hybrid).
 """
 from __future__ import annotations
 
@@ -64,20 +74,56 @@ def rms_norm(params, x, eps: float, zero_centered: bool = True):
 
 
 # --------------------------------------------------------------------- rotary
-def partial_rotary_angles(cfg, n: int) -> np.ndarray:
+def _yarn_inv_freq(cfg, rot: int) -> np.ndarray:
+    """YaRN's frequencies (arXiv:2309.00071; the `rope_type` 'yarn' of the
+    published configs): theta^(-2i/rot) extrapolated below channel pair `low`,
+    divided by the factor above `high`, a linear ramp between, where
+    low = floor(c(beta_fast)), high = ceil(c(beta_slow)) and
+    c(b) = rot ln(original_max / (2 pi b)) / (2 ln theta)."""
+    theta, factor = cfg.rotary_theta, cfg.rope_yarn_factor
+    extrap = 1.0 / theta ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
+
+    def c(b):
+        return rot * math.log(cfg.rope_yarn_original_max / (2 * math.pi * b)) / (2 * math.log(theta))
+
+    low = max(math.floor(c(cfg.rope_yarn_beta_fast)), 0)
+    high = min(math.ceil(c(cfg.rope_yarn_beta_slow)), rot - 1)
+    ramp = np.clip((np.arange(rot // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return extrap * (1.0 - ramp) + extrap / factor * ramp
+
+
+def rotary_attention_factor(cfg, kind: str = "gated_full") -> float:
+    """What cos and sin are multiplied by: YaRN's `attention_factor`, 0.1 ln
+    factor + 1, on a `gated_full` layer under `rope_yarn_factor`, else 1."""
+    if kind != "gated_full" or not cfg.rope_yarn_factor:
+        return 1.0
+    return 0.1 * math.log(cfg.rope_yarn_factor) + 1.0
+
+
+def partial_rotary_angles(cfg, n: int, kind: str = "gated_full") -> np.ndarray:
     """(n, rot / 2) angles of stream positions 0..n-1; rot = the rotated share
-    of dim_head."""
-    rot = int(cfg.dim_head * cfg.partial_rotary_factor)
-    inv_freq = 1.0 / (cfg.rotary_theta ** (np.arange(0, rot, 2, dtype=np.float64) / rot))
+    of dim_head.  A `gated_window` layer rotates all of it, by its own base."""
+    if kind == "gated_window":
+        factor, theta = 1.0, cfg.window_rotary_theta
+    else:
+        factor, theta = cfg.partial_rotary_factor, cfg.rotary_theta
+    rot = int(cfg.dim_head * factor)
+    if kind == "gated_full" and cfg.rope_yarn_factor:
+        inv_freq = _yarn_inv_freq(cfg, rot)
+    else:
+        inv_freq = 1.0 / (theta ** (np.arange(0, rot, 2, dtype=np.float64) / rot))
     return (np.arange(n, dtype=np.float64)[:, None] * inv_freq[None, :]).astype(np.float32)
 
 
-def apply_partial_rotary(angles, t):
+def apply_partial_rotary(angles, t, factor: float = 1.0):
     """t: (b, n, heads, dim_head) float32; rotate-half on the first
     2 * angles.shape[-1] channels: (x1, x2) -> (x1 cos - x2 sin, x2 cos + x1 sin)
-    with x1, x2 the two HALVES of the rotated channels."""
+    with x1, x2 the two HALVES of the rotated channels, cos and sin times
+    `factor` (`rotary_attention_factor`)."""
     half = angles.shape[-1]
     cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2, rest = t[..., :half], t[..., half:2 * half], t[..., 2 * half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
@@ -89,45 +135,84 @@ def expand_kv_heads(t, heads: int):
 
 
 # ----------------------------------------------------------------- gated_full
-def init_gated_full(key, cfg) -> dict:
+def attn_heads(cfg, kind: str = "gated_full") -> int:
+    """Query heads of a `gated_full` or `gated_window` layer."""
+    if kind == "gated_window" and cfg.window_heads:
+        return cfg.window_heads
+    return cfg.heads
+
+
+def _gate_width(cfg, heads: int) -> int:
+    return heads if cfg.attn_gate == "per_head" else heads * cfg.dim_head
+
+
+def init_gated_full(key, cfg, kind: str = "gated_full") -> dict:
+    if cfg.attn_gate not in ("elementwise", "per_head"):
+        raise ValueError(f"attn_gate {cfg.attn_gate!r} is not valid; choose 'elementwise' or 'per_head'")
+    if kind == "gated_window" and cfg.window < 1:
+        raise ValueError(f"a gated_window layer needs window >= 1 keys (got {cfg.window})")
     keys = KeyChain(key)
-    inner, kv = cfg.heads * cfg.dim_head, cfg.kv_heads_resolved * cfg.dim_head
+    heads = attn_heads(cfg, kind)
+    inner, kv = heads * cfg.dim_head, cfg.kv_heads_resolved * cfg.dim_head
     return {
-        "q": linear_init(keys.next(), cfg.dim, 2 * inner, bias=False),
+        "q": linear_init(keys.next(), cfg.dim, inner + _gate_width(cfg, heads), bias=False),
         "k": linear_init(keys.next(), cfg.dim, kv, bias=False),
         "v": linear_init(keys.next(), cfg.dim, kv, bias=False),
         "out": linear_init(keys.next(), inner, cfg.dim, bias=False),
-        "q_norm": rms_norm_init(cfg.dim_head),
-        "k_norm": rms_norm_init(cfg.dim_head),
+        "q_norm": rms_norm_init(cfg.dim_head, _qk_norm_zero_centered(cfg)),
+        "k_norm": rms_norm_init(cfg.dim_head, _qk_norm_zero_centered(cfg)),
     }
 
 
-def gated_full_attention(p, cfg, x, use_flash: bool = False, mesh=None):
+def _qk_norm_zero_centered(cfg) -> bool:
+    """The per-head q / k norm takes the block's form: plain (weight 1 at
+    init) under `norm` 'rmsnorm', zero-centred otherwise."""
+    return cfg.norm != "rmsnorm"
+
+
+def gated_full_attention(p, cfg, x, use_flash: bool = False, mesh=None, kind: str = "gated_full"):
     """x: (b, n, dim) -> (b, n, dim).  `use_flash`: the caller's choice of
-    kernels/flash_attention.py over the dense score matrix."""
+    kernels/flash_attention.py over the dense score matrix.  `kind`
+    'gated_window': the window layer's heads, rotary and band."""
     b, n, _ = x.shape
-    heads, dh = cfg.heads, cfg.dim_head
+    heads, dh = attn_heads(cfg, kind), cfg.dim_head
     inner = heads * dh
     qg = linear(p["q"], x)
     q, gate = qg[..., :inner].reshape(b, n, heads, dh), qg[..., inner:]
     k = linear(p["k"], x).reshape(b, n, -1, dh)
     v = linear(p["v"], x).reshape(b, n, -1, dh)
-    angles = jnp.asarray(partial_rotary_angles(cfg, n))
-    q = apply_partial_rotary(angles, rms_norm(p["q_norm"], q.astype(F32), cfg.norm_eps))
-    k = apply_partial_rotary(angles, rms_norm(p["k_norm"], k.astype(F32), cfg.norm_eps))
+    angles = jnp.asarray(partial_rotary_angles(cfg, n, kind))
+    factor = rotary_attention_factor(cfg, kind)
+    zc = _qk_norm_zero_centered(cfg)
+    q = apply_partial_rotary(angles, rms_norm(p["q_norm"], q.astype(F32), cfg.norm_eps, zc), factor)
+    k = apply_partial_rotary(angles, rms_norm(p["k_norm"], k.astype(F32), cfg.norm_eps, zc), factor)
     q = q.astype(x.dtype).transpose(0, 2, 1, 3)
     k = expand_kv_heads(k.astype(x.dtype), heads).transpose(0, 2, 1, 3)
     v = expand_kv_heads(v, heads).transpose(0, 2, 1, 3)
-    if use_flash:
-        from dalle_pytorch_tpu.kernels.flash_attention import flash_attention
+    window = cfg.window if kind == "gated_window" else 0
+    band = None
+    if window:
+        from dalle_pytorch_tpu.ops.masks import window_mask_np
 
-        out = flash_attention(q, k, v, causal=True, scale=dh ** -0.5, mesh=mesh)
+        band = window_mask_np(n, window)
+    with jax.named_scope("swa_core" if window else "full_core"):
+        if use_flash:
+            from dalle_pytorch_tpu.kernels.flash_attention import flash_attention
+
+            out = flash_attention(q, k, v, mask=band, causal=True, scale=dh ** -0.5, mesh=mesh,
+                                  count_as="window" if window else None)
+        else:
+            allowed = band if window else jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
+            out = attend(q * (dh ** -0.5), k, v, mask=allowed[None, None])
+    out = out.transpose(0, 2, 1, 3)
+    if cfg.attn_gate == "per_head":  # one scalar a head, over its dim_head channels
+        with jax.named_scope("attn_gate"):
+            out = out * jax.nn.sigmoid(gate.astype(F32))[..., None].astype(out.dtype)
+        out = out.reshape(b, n, inner)
     else:
-        causal = jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
-        out = attend(q * (dh ** -0.5), k, v, mask=causal[None, None])
-    out = out.transpose(0, 2, 1, 3).reshape(b, n, inner)
-    with jax.named_scope("attn_gate"):
-        out = out * jax.nn.sigmoid(gate.astype(F32)).astype(out.dtype)
+        out = out.reshape(b, n, inner)
+        with jax.named_scope("attn_gate"):
+            out = out * jax.nn.sigmoid(gate.astype(F32)).astype(out.dtype)
     return linear(p["out"], out)
 
 

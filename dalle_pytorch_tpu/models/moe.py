@@ -1,7 +1,8 @@
 """A routed feed-forward that is told which experts it holds.
 
     p = softmax(W_r x) over ALL `moe_experts` (float32);  the `moe_top_k`
-    largest, renormalised to sum 1;  E(x) = W_d (silu(W_g x) * (W_u x));
+    largest, renormalised to sum 1, times `moe_routed_scale`;
+    E(x) = W_d (silu(W_g x) * (W_u x));
     MoE(x) = sum_{e in top-k, e held here} p_e E_e(x) + sigmoid(w_s . x) E_shared(x)
 
 With `moe_router` 'sigmoid_bias': s = sigmoid(W_r x); the experts are the
@@ -102,6 +103,8 @@ def route(router: dict, cfg, x2: jnp.ndarray):
     probs = jax.nn.softmax(logits, axis=-1)
     weights, ids = jax.lax.top_k(probs, cfg.moe_top_k)  # ties: the lower id first
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if cfg.moe_routed_scale != 1.0:  # [`moe_routed_scaling_factor`]
+        weights = weights * cfg.moe_routed_scale
     return weights, ids.astype(jnp.int32)
 
 

@@ -22,6 +22,7 @@ variants), redesigned TPU-first:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -44,7 +45,7 @@ from dalle_pytorch_tpu.ops.shift import token_shift
 
 
 PATTERN_ATTN_TYPES = ("full", "axial_row", "axial_col", "conv_like", "sparse")
-HYBRID_ATTN_TYPES = ("gated_full", "gated_delta", "mla")
+HYBRID_ATTN_TYPES = ("gated_full", "gated_window", "gated_delta", "mla")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,6 +141,22 @@ class TransformerConfig:
     kv_heads: Optional[int] = None
     partial_rotary_factor: float = 1.0
     rotary_theta: float = 10000.0
+    # ... and its YaRN rotary [`rope_type` 'yarn']: the factor (0 = the default
+    # rotary), `original_max_position_embeddings`, beta_fast and beta_slow;
+    # cos and sin take the attention factor 0.1 ln factor + 1
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_max: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    # `gated_window` layers (a `gated_full` layer over a causal band): query
+    # heads (None = heads), the base of their rotary (the default one, on the
+    # whole of dim_head), and the keys a query sees, itself included [`sliding_window`]
+    window_heads: Optional[int] = None
+    window_rotary_theta: float = 10000.0
+    window: int = 0
+    # the output gate of both: 'elementwise' (heads x dim_head) | 'per_head'
+    # (one scalar a head) [`gating`]
+    attn_gate: str = "elementwise"
     # `gated_delta` layers (models/gated_layers.py): key heads, value heads,
     # their widths, and the causal depthwise convolution's taps
     gdn_key_heads: int = 0
@@ -208,19 +225,22 @@ class TransformerConfig:
     @property
     def hybrid(self) -> bool:
         """Anything of the block that only the full-sequence training path
-        computes (see `refuse_hybrid`): `gated_delta` / `gated_full` / `mla`
-        layers, routed experts, leading dense SwiGLU layers, an RMSNorm."""
+        computes (see `refuse_hybrid`): `gated_delta` / `gated_full` /
+        `gated_window` / `mla` layers, routed experts, leading dense SwiGLU
+        layers, an RMSNorm."""
         return (self.moe_experts > 0 or self.norm != "layernorm" or self.dense_layers > 0
                 or any(t in HYBRID_ATTN_TYPES for t in self.attn_types))
 
     @property
     def unserved(self) -> bool:
         """What of the block no cached or paged entry point computes (see
-        `refuse_hybrid`): `gated_full` / `mla` layers and routed experts.
+        `refuse_hybrid`): `gated_full` / `gated_window` / `mla` layers and
+        routed experts.
         `gated_delta` layers (a recurrent state and convolution taps per
         sequence beside the K/V cache), dense SwiGLU layers and the RMSNorms
         are served."""
-        return self.moe_experts > 0 or any(t in ("gated_full", "mla") for t in self.attn_types)
+        return self.moe_experts > 0 or any(
+            t in ("gated_full", "gated_window", "mla") for t in self.attn_types)
 
     @property
     def recurrent(self) -> bool:
@@ -284,7 +304,7 @@ def hybrid_refusal(cfg: TransformerConfig, what: str) -> NotImplementedError:
     return NotImplementedError(
         f"{what} does not support this block (attn_types {cfg.attn_types}, "
         f"norm {cfg.norm!r}, {cfg.moe_experts} routed experts, {cfg.dense_layers} "
-        "leading dense layers): gated_full / mla layers and routed experts run on "
+        "leading dense layers): gated_full / gated_window / mla layers and routed experts run on "
         "the training path only (execution 'sequential' or 'remat', scan_layers off, "
         "no pipeline); gated_delta layers, dense SwiGLU layers and RMSNorm are also "
         "served (prefill, decode_step, the paged pool, GenerationEngine), with no "
@@ -293,9 +313,10 @@ def hybrid_refusal(cfg: TransformerConfig, what: str) -> NotImplementedError:
 
 def refuse_hybrid(cfg: TransformerConfig, what: str, recurrent_state: bool = True) -> None:
     """Raise `hybrid_refusal` from a cached or paged entry point for what it
-    cannot serve: `gated_full` / `mla` layers (a gated or latent cache and
-    the absorbed decode form of `mla`) and routed experts (ROADMAP.md, Queue
-    2); a wrong picture is worse than none.  `recurrent_state` False: the
+    cannot serve: `gated_full` / `gated_window` / `mla` layers (a gated,
+    windowed or latent cache and the absorbed decode form of `mla`) and
+    routed experts (ROADMAP.md, Queue 2); a wrong picture is worse than
+    none.  `recurrent_state` False: the
     caller cannot carry `gated_delta`'s per-sequence state either (it rolls
     tokens back, quantizes the pool or hands a prefill over)."""
     if cfg.unserved or (cfg.recurrent and not recurrent_state):
@@ -313,6 +334,8 @@ def _note_hybrid_layers(cfg: TransformerConfig, specs, gmm_paths: Dict[str, int]
     obs_metrics.counter("train/gdn_layers").inc(
         sum(s.attn_type == "gated_delta" for s in specs))
     obs_metrics.counter("train/mla_layers").inc(sum(s.attn_type == "mla" for s in specs))
+    obs_metrics.counter("train/window_layers").inc(sum(s.attn_type == "gated_window" for s in specs))
+    obs_metrics.counter("train/full_attn_layers").inc(sum(s.attn_type == "gated_full" for s in specs))
     obs_metrics.counter("train/dense_ff_layers").inc(
         sum(cfg.ff_type(s.index) == "swiglu" for s in specs))
     routed = sum(cfg.ff_type(s.index) == "moe" for s in specs)  # by what each layer IS
@@ -396,6 +419,7 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
 
             init = {"gated_delta": gated_layers.init_gated_delta,
                     "gated_full": gated_layers.init_gated_full,
+                    "gated_window": functools.partial(gated_layers.init_gated_full, kind="gated_window"),
                     "mla": init_mla}[spec.attn_type]
             shared_attn[spec.attn_id] = init(keys.next(), cfg)
         ff_type = cfg.ff_type(spec.index)
@@ -852,7 +876,8 @@ def _residual_branch(
 
 @jax.named_scope("attn")
 def _hybrid_mixer(shared, cfg, x, attn_type: str):
-    """`gated_delta` / `gated_full` (models/gated_layers.py), full sequence."""
+    """`gated_delta` / `gated_full` / `gated_window` (models/gated_layers.py),
+    `mla` (models/latent_attention.py), full sequence."""
     from dalle_pytorch_tpu.models import gated_layers
 
     if attn_type == "gated_delta":
@@ -862,6 +887,10 @@ def _hybrid_mixer(shared, cfg, x, attn_type: str):
         from dalle_pytorch_tpu.models.latent_attention import mla_attention
 
         return mla_attention(shared, cfg, x, use_flash=use_flash, mesh=mesh)
+    if attn_type == "gated_window":
+        with jax.named_scope("swa"):
+            return gated_layers.gated_full_attention(shared, cfg, x, use_flash=use_flash, mesh=mesh,
+                                                     kind="gated_window")
     return gated_layers.gated_full_attention(shared, cfg, x, use_flash=use_flash, mesh=mesh)
 
 

@@ -164,6 +164,17 @@ def build_block_sparse_mask(
     )
 
 
+@lru_cache(maxsize=8)
+def window_mask_np(n: int, window: int) -> np.ndarray:
+    """(n, n) bool causal band of a sliding-window layer: query i sees key j
+    where 0 <= i - j < window, itself included [`sliding_window`].  Static, so
+    the flash kernels derive the band's tile liveness from it at trace time
+    and the compacted grid runs the live tiles only: at 4,224 positions and
+    384-tiles a window of 512 leaves 30 of the 121 steps, causality alone 66."""
+    d = np.arange(n)[:, None] - np.arange(n)[None, :]
+    return (d >= 0) & (d < window)
+
+
 def block_live_np(mask: np.ndarray, block_q: int, block_k: int) -> np.ndarray:
     """Tile-granular liveness of a static pattern mask: (nq, nk) bool — or
     (h, nq, nk) for per-head masks — True where the (block_q, block_k) tile

@@ -405,8 +405,10 @@ def _cell_step_flash_counts(workload):
 
 # flash_attention calls a traced step holds: d8's eight layers, d24's one
 # scanned layer body, the hybrid period's one `gated_full` layer, the latent
-# trunk's five blocks and its prediction module's
-CELL_FLASH_CALLS = [("train_d8", 8), ("train_d24", 1), ("train_q3n_ep16", 1), ("train_glm47_ep8", 6)]
+# trunk's five blocks and its prediction module's, the window trunk's three
+# `gated_window` layers (their band: 30 live steps of 121) and two `gated_full`
+CELL_FLASH_CALLS = [("train_d8", 8), ("train_d24", 1), ("train_q3n_ep16", 1), ("train_glm47_ep8", 6),
+                    ("train_laguna_ep8", 5)]
 
 
 @pytest.mark.parametrize("workload,calls", CELL_FLASH_CALLS)
